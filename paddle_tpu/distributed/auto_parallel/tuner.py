@@ -37,11 +37,14 @@ break toward plain dp (fewer axes, simpler program).
 """
 from dataclasses import dataclass, field
 
+from ...device import chip as _chip
+
 __all__ = ["ModelStats", "estimate", "tune"]
 
-# v5e-class constants — tunable via estimate()/tune() kwargs
+# planning constants for the target chip (v5e) — tunable via
+# estimate()/tune() kwargs; the peak is the peaks table's row
 ICI_BW = 90e9          # bytes/s per device, ring all-reduce effective
-PEAK = 197e12          # bf16 flops
+PEAK = _chip.peaks(_chip.V5E).bf16_flops
 EFF = 0.45             # sustained fraction of peak for a train step
 A_WORK = 30.0          # one layer's live working set, bytes/token/H
 LOGITS_LIVE = 2.0      # fwd logits + bwd dlogits live together

@@ -136,6 +136,15 @@ class PlacementPlan:
             out.append(mapped)
         return out
 
+    def kernel_partition(self):
+        """Trace-time context for the steppers that jit under this plan:
+        Pallas kernels run per shard of the mesh (batch over the batch
+        axes, heads over the tensor-parallel axis) — XLA cannot
+        partition a Mosaic kernel itself (ops/registry.py)."""
+        from ..ops import registry as kreg
+        return kreg.partitioned(self.mesh, self.batch_axes or (),
+                                self.mp_axis)
+
     def describe(self):
         return (f"PlacementPlan(mesh={dict(self.mesh.shape)}, "
                 f"batch_axes={self.batch_axes}, level={self.level})")

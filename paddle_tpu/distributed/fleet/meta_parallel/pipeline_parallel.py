@@ -198,8 +198,14 @@ class _PipelineStepper:
         shapes = [tuple(self.other_params[i].shape) for i in ot_idx] + \
             [tuple(v.shape) for v in self.stacked]
         o_sh = self._opt_shardings(self.opt_state, specs, shapes)
+
+        def traced(*args):
+            # Pallas kernels run per shard of the mesh (ops/registry.py
+            # "multi-device traces")
+            with self.plan.kernel_partition():
+                return step(*args)
         return jax.jit(
-            step, donate_argnums=(0, 2, 3, 4),
+            traced, donate_argnums=(0, 2, 3, 4),
             in_shardings=(ot_sh, of_sh, list(self._stacked_sh),
                           list(self._buf_sh), o_sh, rep, rep, x_sd, y_sd),
             out_shardings=(rep, ot_sh, list(self._stacked_sh),

@@ -15,6 +15,8 @@ These are Tensor-level and autograd-aware (jax differentiates through
 ppermute/all_to_all); they must run inside a sep-axis shard_map — the
 `RingFlashAttention` / `sep` paths of the hybrid engine arrange that.
 """
+import jax
+
 from ....framework.core import Tensor
 from ....framework.autograd import call_op
 from ....ops.ring_attention import ring_flash_attention, ulysses_attention
@@ -70,14 +72,10 @@ def sep_attention(query, key, value, is_causal=False, mode="ring",
             "an explicit shard_map, or an Engine with sep_degree > 1 "
             "(which calls set_sep_mesh)")
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map as _smap
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _smap
     batch = tuple(a for a in ("data", "sharding")
                   if a in mesh.axis_names and mesh.shape[a] > 1) or None
     spec = P(batch, sep_axis, None, None)
-    wrapped = _smap(fn, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec)
+    wrapped = jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec)
     return call_op(wrapped, q, k, v)
 
 

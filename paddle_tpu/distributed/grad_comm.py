@@ -51,7 +51,7 @@ for _qual in ("build_grad_reducer.reduce",
     register_jit_surface(__name__, _qual)
 
 _QUANT_MODES = (None, "bf16", "int8", "fp8")
-_FP8_DTYPE = getattr(jnp, "float8_e4m3fn", None)
+_FP8_DTYPE = jnp.float8_e4m3fn
 
 
 class GradCommConfig:
@@ -77,12 +77,6 @@ class GradCommConfig:
                 "weight update on the plan-based (GSPMD) path while the "
                 "reducer assumes a replicated update; enable one or the "
                 "other")
-        self.fp8_fallback = False
-        if quantize == "fp8" and _FP8_DTYPE is None:
-            # "fp8 where available": older jax has no fp8 dtype — keep
-            # the run alive on the int8 path and say so
-            quantize = "int8"
-            self.fp8_fallback = True
         self.enabled = bool(enabled)
         self.bucket_mb = float(bucket_mb)
         self.overlap = bool(overlap)

@@ -4,7 +4,10 @@ restart on failure).
 
 TPU-native: ONE process per host drives all local chips (SPMD), so
 ``--nnodes`` is the only real fan-out; per-host we spawn a single worker
-(vs the reference's one-per-GPU).  The watch loop + restart-with-resume
+(vs the reference's one-per-GPU).  A chip belongs to one process, so
+this parent never initialises a JAX backend (``import paddle_tpu`` does
+not either) and on a TPU host ``--nproc_per_node > 1`` is refused.  The
+watch loop + restart-with-resume
 survives worker crashes; rendezvous is the JAX coordinator (the reference's
 TCPStore master).  With ``--nnodes min:max`` the launcher also runs the
 elastic membership watch: the registry store listens on master_port+1 (the
@@ -19,6 +22,7 @@ import subprocess
 import sys
 import time
 
+from ...device import chip as _chip
 from ...framework import failpoints as _fp
 from ...framework.backoff import jittered_delay
 from ...framework.preemption import PREEMPTED_EXIT_CODE
@@ -199,6 +203,9 @@ def _worker_env(args, local_rank, membership):
     if getattr(args, "ckpt_root", ""):
         env["PADDLE_CKPT_ROOT"] = args.ckpt_root
         env["PADDLE_RESUME_ROOT"] = args.ckpt_root
+    # restarts and relaunches reuse compiled programs: JAX reads this
+    # variable itself (a value already set is kept as it is)
+    env["JAX_COMPILATION_CACHE_DIR"] = _chip.compile_cache_dir()
     return env
 
 
@@ -404,6 +411,13 @@ def main():
     if args.run_mode == "ps" or args.server_num > 0:
         _launch_ps(args)
         return
+    if args.nproc_per_node > 1 and _chip.child_would_claim_tpu():
+        sys.exit(
+            f"[launch] --nproc_per_node={args.nproc_per_node} on a TPU "
+            "host: a chip belongs to one process and every worker would "
+            "claim all local chips.  One process drives all local chips "
+            "(SPMD) — use --nproc_per_node 1 (assigning chips per "
+            "worker is not implemented)")
     os.makedirs(args.log_dir, exist_ok=True)
     procs = {}
     policy = _RestartPolicy(args.max_restart)

@@ -84,8 +84,16 @@ class ProcessMesh:
         self._dim_names = list(dim_names) if dim_names else \
             [f"d{i}" for i in range(arr.ndim)]
         devices = jax.devices()
-        dev_arr = np.asarray([devices[i % len(devices)]
-                              for i in arr.reshape(-1)],
+        ids = arr.reshape(-1)
+        if ids.size and (int(ids.max()) >= len(devices)
+                         or int(ids.min()) < 0):
+            # a mesh larger than the host must fail, not wrap onto the
+            # same chips (a "4-way" run on one device measures nothing)
+            raise ValueError(
+                f"ProcessMesh asks for device id {int(ids.max())} but "
+                f"only {len(devices)} {devices[0].platform} device(s) "
+                "are visible")
+        dev_arr = np.asarray([devices[i] for i in ids],
                              dtype=object).reshape(arr.shape)
         self._jax_mesh = Mesh(dev_arr, tuple(self._dim_names))
 

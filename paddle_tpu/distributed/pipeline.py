@@ -25,17 +25,11 @@ __all__ = ["spmd_pipeline", "stack_block_params", "PipelineStagedModule"]
 
 
 def _shard_map(fn, mesh, in_specs, out_specs, axis):
-    try:
-        from jax import shard_map  # jax >= 0.6 style
-        # manual only over the pipe axis: other mesh axes (data/model/...)
-        # stay under GSPMD so dp/tp compose with the pipeline
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
+    # manual only over the pipe axis: other mesh axes (data/model/...)
+    # stay under GSPMD so dp/tp compose with the pipeline
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False,
                          axis_names=frozenset({axis}))
-    except (ImportError, TypeError):
-        from jax.experimental.shard_map import shard_map as sm
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
 
 
 def stack_block_params(param_lists):

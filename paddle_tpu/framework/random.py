@@ -14,14 +14,23 @@ model-parallel ranks) lives in distributed/fleet and builds on ``fold_in``.
 import jax
 from contextlib import contextmanager
 
-_STATE = {"key": jax.random.key(0), "seed": 0}
+# the key is built on first use, never at import: creating an array
+# initialises the JAX backend, and a process that merely imported the
+# package (the launcher parent, a bench parent) must not claim the chip
+_STATE = {"key": None, "seed": 0}
 # stack of (key, counter-list) pushed by traced step functions
 _SCOPES = []
 
 
+def _global_key():
+    if _STATE["key"] is None:
+        _STATE["key"] = jax.random.key(_STATE["seed"])
+    return _STATE["key"]
+
+
 def seed(s):
-    _STATE["key"] = jax.random.key(int(s))
     _STATE["seed"] = int(s)
+    _STATE["key"] = jax.random.key(int(s))
     return _STATE["key"]
 
 
@@ -55,12 +64,12 @@ def next_key():
         scope = _SCOPES[-1]
         scope[1] += 1
         return jax.random.fold_in(scope[0], scope[1])
-    _STATE["key"], sub = jax.random.split(_STATE["key"])
+    _STATE["key"], sub = jax.random.split(_global_key())
     return sub
 
 
 def get_rng_state():
-    return [_STATE["key"]]
+    return [_global_key()]
 
 
 def set_rng_state(state, seed=None):

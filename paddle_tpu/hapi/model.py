@@ -179,6 +179,19 @@ class _CompiledStepper:
                                        jnp.float32)
         return self.fp8_state
 
+    def _under_plan(self, fn):
+        """``fn`` traced inside the plan's kernel-partition context
+        (Pallas kernels then run per shard of the mesh — XLA cannot
+        partition a Mosaic kernel); ``fn`` itself without a plan."""
+        plan = self.plan
+        if plan is None:
+            return fn
+
+        def scoped(*args):
+            with plan.kernel_partition():
+                return fn(*args)
+        return scoped
+
     def _forward_pure(self, param_vals, buffer_vals, key, inputs, training):
         """Run network on traced values; returns (outs, new_buffer_vals)."""
         olds = [t._value for t in self.params + self.buffers]
@@ -263,7 +276,6 @@ class _CompiledStepper:
         Output contract: every network output must carry the batch on
         its leading axis (out_specs shards them on 'data') — nets with
         scalar/non-batch auxiliary outputs need the GSPMD path."""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from ..distributed.grad_comm import build_grad_reducer
         opt = self.optimizer
@@ -343,12 +355,12 @@ class _CompiledStepper:
 
         rep = P()
         dat = P(axis)
-        sharded = shard_map(
+        sharded = jax.shard_map(
             shard_step, mesh=mesh,
             in_specs=(rep, rep, rep, rep, rep, rep, dat, dat),
             out_specs=(rep, rep, rep, rep, dat) +
                       ((rep,) if guard else ()),
-            check_rep=False)
+            check_vma=False)
         # batch-divisibility is validated host-side in train_step (the
         # error must fire before this executable is compiled/cached)
         return jax.jit(sharded, donate_argnums=(0, 2, 3))
@@ -361,10 +373,10 @@ class _CompiledStepper:
         # shape+dtype; with activations first, a batch-sharded logits
         # output whose global shape happens to equal a replicated
         # param's stole that param's donated buffer and the executable
-        # aborted at launch on the local-shard size mismatch (jax
-        # 0.4.x; the PR 14 "donation aliasing" quirk).  State-first
-        # ordering pairs every donated leaf with its own updated
-        # output — same sharding, always aliasable.
+        # aborted at launch on the local-shard size mismatch (the PR 14
+        # "donation aliasing" quirk).  State-first ordering pairs every
+        # donated leaf with its own updated output — same sharding,
+        # always aliasable.
         if self._use_grad_comm():
             return self._build_train_comm(n_in, n_lab)
         opt = self.optimizer
@@ -455,7 +467,7 @@ class _CompiledStepper:
         rep = plan.replicated()
         out_sh = (rep, t_sh, b_sh, o_sh, None) + ((rep,) if guard else ())
         return jax.jit(
-            step, donate_argnums=(0, 2, 3),
+            self._under_plan(step), donate_argnums=(0, 2, 3),
             in_shardings=(t_sh, f_sh, b_sh, o_sh, rep, rep,
                           self._input_shardings, self._label_shardings),
             out_shardings=out_sh)
@@ -491,7 +503,7 @@ class _CompiledStepper:
         # donation-unsafe by design: train/frozen vals must stay live
         # for the later apply step, and the trip path keeps pre-batch
         # buffers when a poisoned microbatch is dropped
-        return jax.jit(gstep)  # lint: allow(missing-donation)
+        return jax.jit(self._under_plan(gstep))  # lint: allow(missing-donation)
 
     @jit_surface
     def _build_apply(self):
@@ -515,9 +527,10 @@ class _CompiledStepper:
         if self.plan is None:
             return jax.jit(step)  # lint: allow(missing-donation)
         rep = self.plan.replicated()
-        return jax.jit(step, in_shardings=(  # lint: allow(missing-donation)
-            list(self._param_shardings), list(self._buffer_shardings), rep,
-            self._input_shardings))
+        return jax.jit(  # lint: allow(missing-donation)
+            self._under_plan(step), in_shardings=(
+                list(self._param_shardings), list(self._buffer_shardings),
+                rep, self._input_shardings))
 
     def _shape_key(self, arrays):
         return tuple((tuple(a.shape), str(a.dtype)) for a in arrays)

@@ -59,15 +59,17 @@ def flash_attention(query, key, value, dropout=0.0, causal=False,
 
 
 def _pallas_ok(q):
-    try:
-        import jax
-        dev = jax.devices()[0].platform
-        if dev == "cpu":
-            return False
-        B, S, H, D = q.shape
-        return S % 128 == 0 and D in (64, 128, 256)
-    except Exception:
+    """Pallas flash on a TPU backend when the shape fits the kernel; a
+    TPU call the shape sends to the XLA composition is a counted
+    fallback, never a silent one."""
+    if jax.default_backend() != "tpu":
         return False
+    B, S, H, D = q.shape
+    if S % 128 == 0 and D in (64, 128, 256):
+        return True
+    from ....ops import registry as kreg
+    kreg.record_fallback("attention", "incubate-shape")
+    return False
 
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,

@@ -43,7 +43,7 @@ class Config:
             prog_file = prog_file[:-len(".pdmodel")]
         self._prefix = prog_file
         self._params_file = params_file
-        self._device = "tpu"
+        self._device = None        # None = JAX's default backend
         self._device_id = 0
         self._precision = PrecisionType.Float32
         # convert_to_mixed_precision leaves a sidecar naming the dtype;
@@ -74,7 +74,7 @@ class Config:
         self._device = "cpu"
 
     def use_gpu(self):
-        return self._device != "cpu"
+        return self._device == "tpu"
 
     def gpu_device_id(self):
         return self._device_id
@@ -116,7 +116,7 @@ class Config:
         return (self._prefix or "") + ".pdiparams"
 
     def summary(self):
-        return (f"device: {self._device}:{self._device_id}\n"
+        return (f"device: {self._device or 'default'}:{self._device_id}\n"
                 f"precision: {self._precision}\n"
                 f"model: {self.prog_file()}\n"
                 f"ir_optim: {self._ir_optim}  "
@@ -171,12 +171,11 @@ class Predictor:
 
     def __init__(self, config):
         self._config = config
-        if config._device == "cpu":
-            devs = jax.devices("cpu")
-        else:
-            devs = [d for d in jax.devices() if d.platform != "cpu"] or \
-                jax.devices()
-        self._device = devs[min(config._device_id, len(devs) - 1)]
+        # an explicit device that is absent or out of range raises
+        # (framework.core._parse_device) — never a quiet CPU predictor
+        from ..framework.core import _parse_device
+        platform = config._device or jax.default_backend()
+        self._device = _parse_device(f"{platform}:{config._device_id}")
         self._layer = _jit.load(config._prefix,
                                 params_path=config.params_file())
         specs = self._layer._meta.get("input_specs", [])

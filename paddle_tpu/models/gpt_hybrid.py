@@ -22,6 +22,7 @@ from ..framework.random import rng_scope
 from .gpt import GPTConfig, GPTForPretraining
 from ..analysis import register_jit_surface
 from ..distributed.pipeline import spmd_pipeline, stack_block_params
+from ..ops import registry as kreg
 
 __all__ = ["build_hybrid_gpt", "hybrid_train_step"]
 
@@ -141,8 +142,11 @@ def build_hybrid_gpt(config, mesh, n_micro=2, lr=1e-3):
         return jnp.mean(nll)
 
     def step(other, stacked_vals, ids_val, labels_val):
-        loss, grads = jax.value_and_grad(loss_fn, argnums=(0, 1))(
-            other, stacked_vals, ids_val, labels_val)
+        # Pallas kernels run per shard of the mesh: XLA cannot partition
+        # a Mosaic kernel (ops/registry.py "multi-device traces")
+        with kreg.partitioned(mesh, ("data",), "model"):
+            loss, grads = jax.value_and_grad(loss_fn, argnums=(0, 1))(
+                other, stacked_vals, ids_val, labels_val)
         g_other, g_stacked = grads
         new_other = [p - lr * g for p, g in zip(other, g_other)]
         new_stacked = [p - lr * g for p, g in zip(stacked_vals, g_stacked)]

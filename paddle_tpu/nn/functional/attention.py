@@ -87,6 +87,56 @@ _Flash = namedtuple("_Flash", ["use", "interpret"])
 _NO_FLASH = _Flash(False, False)
 
 
+# Under a multi-device trace (kreg.partitioned) XLA cannot partition the
+# Mosaic kernels, so the three flash entries run per shard: batch over
+# the batch axes, heads over the tensor-parallel axis.  The lse residual
+# crosses the shard_map boundary as (B, H, S) — its kernel layout
+# (B*H, S) folds two differently-sharded dims into one.
+
+def _flash_specs(part, q, k):
+    P = jax.sharding.PartitionSpec
+    b = part.batch(q.shape[0])
+    h = part.heads(q.shape[2], k.shape[2])
+    return P(b, None, h, None), P(b, None), P(b, h, None)
+
+
+def _run_flash_fwd(q, k, v, bias, causal, interpret, with_lse):
+    part = kreg.current_partition()
+    entry = _flash_fwd_lse if with_lse else _flash_fwd
+    if part is None:
+        return entry(q, k, v, bias, causal=causal, interpret=interpret)
+    s4, s2, s3 = _flash_specs(part, q, k)
+
+    def local(q_, k_, v_, *b_):
+        out = entry(q_, k_, v_, b_[0] if b_ else None, causal=causal,
+                    interpret=interpret)
+        if not with_lse:
+            return out
+        o, lse = out
+        return o, lse.reshape(q_.shape[0], q_.shape[2], q_.shape[1])
+    with_bias = () if bias is None else (bias,)
+    return part.shard_map(
+        local, (s4, s4, s4) + (s2,) * len(with_bias),
+        (s4, s3) if with_lse else s4)(q, k, v, *with_bias)
+
+
+def _run_flash_bwd(q, k, v, o, lse, g, bias, causal, interpret):
+    part = kreg.current_partition()
+    if part is None:
+        return _flash_bwd(q, k, v, o, lse, g, bias, causal=causal,
+                          interpret=interpret)
+    s4, s2, s3 = _flash_specs(part, q, k)
+
+    def local(q_, k_, v_, o_, lse_, g_, *b_):
+        return _flash_bwd(q_, k_, v_, o_, lse_.reshape(-1, lse_.shape[-1]),
+                          g_, b_[0] if b_ else None, causal=causal,
+                          interpret=interpret)
+    with_bias = () if bias is None else (bias,)
+    return part.shard_map(
+        local, (s4, s4, s4, s4, s3, s4) + (s2,) * len(with_bias),
+        (s4, s4, s4))(q, k, v, o, lse, g, *with_bias)
+
+
 def _select_flash(S, Sk, D, causal, has_mask, mask_is_keybias, scale,
                   dropout_p=0.0):
     """The dispatch decision for one attention call, made on static
@@ -145,8 +195,8 @@ def _pad_qkv(q, k, v, bias, causal):
 def _attention_core(q, k, v, causal, scale, flash):
     if flash.use:
         qp, kp, vp, bias, S = _pad_qkv(q, k, v, None, causal)
-        o = _flash_fwd(qp, kp, vp, bias, causal=causal,
-                       interpret=flash.interpret)
+        o = _run_flash_fwd(qp, kp, vp, bias, causal, flash.interpret,
+                           with_lse=False)
         return o[:, :S] if o.shape[1] != S else o
     return _xla_attention(q, k, v, causal=causal, scale=scale)
 
@@ -154,8 +204,8 @@ def _attention_core(q, k, v, causal, scale, flash):
 def _attn_fwd(q, k, v, causal, scale, flash):
     if flash.use:
         qp, kp, vp, bias, S = _pad_qkv(q, k, v, None, causal)
-        o, lse = _flash_fwd_lse(qp, kp, vp, bias, causal=causal,
-                                interpret=flash.interpret)
+        o, lse = _run_flash_fwd(qp, kp, vp, bias, causal, flash.interpret,
+                                with_lse=True)
         return (o[:, :S] if o.shape[1] != S else o), \
             (qp, kp, vp, bias, o, lse)
     return _xla_attention(q, k, v, causal=causal, scale=scale), \
@@ -170,8 +220,8 @@ def _attn_bwd(causal, scale, flash, res, g):
         S = g.shape[1]
         if o.shape[1] != S:   # padded: pad the cotangent, slice grads
             g = jnp.pad(g, ((0, 0), (0, o.shape[1] - S), (0, 0), (0, 0)))
-        dq, dk, dv = _flash_bwd(q, k, v, o, lse, g, bias, causal=causal,
-                                interpret=flash.interpret)
+        dq, dk, dv = _run_flash_bwd(q, k, v, o, lse, g, bias, causal,
+                                    flash.interpret)
         return dq[:, :S], dk[:, :S], dv[:, :S]
     # recompute-based pullback at the XLA level (flash-bwd strategy)
     _, vjp = jax.vjp(lambda q_, k_, v_: _xla_attention(
@@ -190,15 +240,15 @@ def _attention_core_bias(q, k, v, bias, causal, flash):
     (masks are data, matching the dense path's detached-mask
     contract)."""
     qp, kp, vp, bp, S = _pad_qkv(q, k, v, bias, causal)
-    o = _flash_fwd(qp, kp, vp, bp, causal=causal,
-                   interpret=flash.interpret)
+    o = _run_flash_fwd(qp, kp, vp, bp, causal, flash.interpret,
+                       with_lse=False)
     return o[:, :S] if o.shape[1] != S else o
 
 
 def _attn_bias_fwd(q, k, v, bias, causal, flash):
     qp, kp, vp, bp, S = _pad_qkv(q, k, v, bias, causal)
-    o, lse = _flash_fwd_lse(qp, kp, vp, bp, causal=causal,
-                            interpret=flash.interpret)
+    o, lse = _run_flash_fwd(qp, kp, vp, bp, causal, flash.interpret,
+                            with_lse=True)
     return (o[:, :S] if o.shape[1] != S else o), \
         (qp, kp, vp, bp, o, lse, bias)
 
@@ -208,8 +258,8 @@ def _attn_bias_bwd(causal, flash, res, g):
     S = g.shape[1]
     if o.shape[1] != S:
         g = jnp.pad(g, ((0, 0), (0, o.shape[1] - S), (0, 0), (0, 0)))
-    dq, dk, dv = _flash_bwd(q, k, v, o, lse, g, bp, causal=causal,
-                            interpret=flash.interpret)
+    dq, dk, dv = _run_flash_bwd(q, k, v, o, lse, g, bp, causal,
+                                flash.interpret)
     return dq[:, :S], dk[:, :S], dv[:, :S], jnp.zeros_like(bias0)
 
 

@@ -272,6 +272,7 @@ METRICS = {
                 "a kernel contract sent to the XLA path instead: "
                 "mask | scale | dropout | cross-seq | short-seq | "
                 "pad-noncausal | mask-large | unaligned-vocab | "
+                "incubate-shape | head-dim (varlen_attention) | "
                 "fp8-unavailable (no float8_e4m3fn in this jax build; "
                 "weights degraded to int8) | fp8-weight-only (fp8 "
                 "always streams through the XLA weight-only path — "

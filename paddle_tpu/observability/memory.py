@@ -35,8 +35,8 @@ pool's analytic bookkeeping within 1% (machine-checked by
 ``tests/test_memory_ledger.py``), and the ``hbm_pressure`` watch rule
 trips on the fields :func:`census_fields` merges into flight samples.
 
-Import-light (stdlib + metrics; jax imported lazily inside the census)
-and monitored by the host-sync lint with ZERO budgeted entries: a
+Import-light (stdlib + metrics + the peaks table; jax is used lazily
+inside the census) and monitored by the host-sync lint with ZERO budgeted entries: a
 device readback anywhere in this module is always a bug.
 """
 import collections
@@ -46,6 +46,7 @@ import time
 import weakref
 
 from . import metrics as _metrics
+from ..device import chip as _chip
 
 __all__ = [
     "KINDS", "HBM_ENVELOPE_ENV", "DEFAULT_HBM_BYTES", "hbm_envelope",
@@ -59,7 +60,9 @@ __all__ = [
 KINDS = ("argument", "output", "temp", "generated_code")
 
 HBM_ENVELOPE_ENV = "PADDLE_HBM_BYTES"
-DEFAULT_HBM_BYTES = 16 * 1024 ** 3      # one TPU v5e chip's HBM
+# off the chip the ledger budgets against the chip the repo targets
+# (the v5e row of the one peaks table, device/chip.py)
+DEFAULT_HBM_BYTES = _chip.peaks(_chip.V5E).hbm_bytes
 
 # forecast shape: least-squares slope over the last _TREND_WINDOW
 # censuses, reported only after _TREND_MIN samples exist (a 2-point
@@ -75,8 +78,10 @@ _POOL_IDS = iter(range(1 << 30))
 
 
 def hbm_envelope():
-    """Configured device HBM envelope in bytes (the per-surface budget
-    denominator)."""
+    """Device HBM envelope in bytes (the per-surface budget
+    denominator): ``PADDLE_HBM_BYTES`` when set; on a TPU backend the
+    local ``device_kind``'s row of the peaks table (an unknown kind
+    raises); elsewhere the target chip's."""
     raw = os.environ.get(HBM_ENVELOPE_ENV)
     if raw:
         try:
@@ -85,6 +90,8 @@ def hbm_envelope():
                 return v
         except ValueError:
             pass
+    if _platform() == "tpu":
+        return _chip.peaks().hbm_bytes
     return DEFAULT_HBM_BYTES
 
 

@@ -33,14 +33,18 @@ import math
 import os
 import sys
 
+from ..device import chip as _chip
+
 __all__ = ["parse_prometheus", "parse_jsonl", "render_report",
            "roofline_from_stats", "compile_stats_from_prom",
            "roofline_view", "requests_view", "request_rows_from_trace",
            "dropped_spans_from_trace", "memory_view", "main"]
 
-# defaults for the roofline roofs: TPU v5e bf16 peak and HBM bandwidth
-DEFAULT_PEAK_FLOPS = 197e12
-DEFAULT_HBM_BW = 819e9
+# default roofs for the OFFLINE report (sink files carry no device_kind):
+# the v5e row of the one peaks table; a live caller passes the roofs of
+# the device it measured on (device.chip.peaks())
+DEFAULT_PEAK_FLOPS = _chip.peaks(_chip.V5E).bf16_flops
+DEFAULT_HBM_BW = _chip.peaks(_chip.V5E).hbm_bytes_per_s
 
 # fallback join for surfaces whose measured latency the sinks already
 # carry: the hapi steppers map onto the step-latency histogram (one

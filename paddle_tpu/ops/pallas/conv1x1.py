@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import TPUCompilerParams
 
 
 def _conv1x1_kernel(x_ref, w_ref, sc_ref, sh_ref, res_ref, o_ref, acc,
@@ -96,7 +95,7 @@ def conv1x1_bn_act(x2d, w, scale, shift, residual=None, relu=True,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, N), x2d.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x2d, w, scale.astype(jnp.float32).reshape(1, N),

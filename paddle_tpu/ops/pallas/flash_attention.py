@@ -13,7 +13,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import TPUCompilerParams
 
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
@@ -382,7 +381,7 @@ def _flash_bhsd_bwd_fused(q, k, v, o, lse, do, causal=False,
         ],
         scratch_shapes=[pltpu.VMEM((S, D), jnp.float32),
                         pltpu.VMEM((S, D), jnp.float32)],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, o, lse[:, None, :].astype(jnp.float32))

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import TPUCompilerParams
 from .. import registry as kreg
 
 __all__ = ["fused_softmax_xent"]
@@ -164,7 +163,7 @@ def _fwd_pallas(logits2, lbl, *, n_v, bv, V, interpret):
         scratch_shapes=[pltpu.VMEM((_BT, _LANES), jnp.float32),
                         pltpu.VMEM((_BT, _LANES), jnp.float32),
                         pltpu.VMEM((_BT, _LANES), jnp.float32)],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(logits2, lbl)
@@ -173,6 +172,31 @@ def _fwd_pallas(logits2, lbl, *, n_v, bv, V, interpret):
 # standalone dispatches are compilestats-tracked (roofline attribution
 # under kernel.xent_*); traced calls inline into the caller's surface
 _fwd_tracked = kreg.TrackedKernel(_fwd_pallas, kreg.XENT_FWD_SURFACE)
+
+
+def _per_row_shard(local, out_specs, logits2, *rows):
+    """Run ``local(logits2, *rows)`` directly, or — under a multi-device
+    trace (``kreg.partitioned``), where XLA cannot partition the Mosaic
+    kernel — per shard of rows: T = B*S is batch-major, so the batch
+    axes split it like the batch; every row keeps its full vocab (a
+    vocab-parallel logits tensor is gathered by the shard_map boundary).
+    ``out_specs(t)`` maps the row-dim spec entry to the output specs."""
+    part = kreg.current_partition()
+    if part is None:
+        return local(logits2, *rows)
+    P = jax.sharding.PartitionSpec
+    t = part.batch(logits2.shape[0])
+    return part.shard_map(local, (P(t, None),) + (P(t),) * len(rows),
+                          out_specs(P, t))(logits2, *rows)
+
+
+def _fwd_rows(logits2, labels, *, bv, V, interp):
+    lg_p, lb_p, T0 = _pad_rows(logits2, labels.astype(jnp.int32))
+    lbl = _lane_col(lb_p, lg_p.shape[0])
+    n_v = -(-V // bv)      # ceil: tail chunk masked in-kernel
+    out, lse = _fwd_tracked(lg_p, lbl, n_v=n_v, bv=bv, V=V,
+                            interpret=interp)
+    return out[:T0, 0], lse[:T0, 0]
 
 
 def _fwd_impl(logits2, labels):
@@ -186,12 +210,9 @@ def _fwd_impl(logits2, labels):
         lg = logits2.astype(jnp.float32)
         lse = jax.scipy.special.logsumexp(lg, axis=-1)
         return _ref_rowloss(logits2, labels), lse
-    lg_p, lb_p, T0 = _pad_rows(logits2, labels.astype(jnp.int32))
-    lbl = _lane_col(lb_p, lg_p.shape[0])
-    n_v = -(-V // bv)      # ceil: tail chunk masked in-kernel
-    out, lse = _fwd_tracked(lg_p, lbl, n_v=n_v, bv=bv, V=V,
-                            interpret=interp)
-    return out[:T0, 0], lse[:T0, 0]
+    return _per_row_shard(
+        functools.partial(_fwd_rows, bv=bv, V=V, interp=interp),
+        lambda P, t: (P(t), P(t)), logits2, labels)
 
 
 def _xent_fwd(logits2, labels):
@@ -212,7 +233,7 @@ def _bwd_pallas(logits2, lbl, lse_l, g_l, *, bv, V, interpret):
         ],
         out_specs=pl.BlockSpec((_BT, bv), lambda t, v: (t, v)),
         out_shape=jax.ShapeDtypeStruct((T, V), logits2.dtype),
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(logits2, lbl, lse_l, g_l)
@@ -233,13 +254,19 @@ def _xent_bwd(res, g):
         valid = (labels >= 0).astype(jnp.float32)
         dlg = (p - onehot) * (g * valid)[:, None]
         return dlg.astype(logits2.dtype), None
+    return _per_row_shard(
+        functools.partial(_bwd_rows, bv=bv, V=V, interp=interp),
+        lambda P, t: P(t, None), logits2, labels, lse, g), None
+
+
+def _bwd_rows(logits2, labels, lse, g, *, bv, V, interp):
     lg_p, lb_p, T0 = _pad_rows(logits2, labels.astype(jnp.int32))
     Tp = lg_p.shape[0]
     lbl = _lane_col(lb_p, Tp)
     lse_l = _lane_col(jnp.pad(lse, (0, Tp - T0)), Tp)
     g_l = _lane_col(jnp.pad(g.astype(jnp.float32), (0, Tp - T0)), Tp)
     dlg = _bwd_tracked(lg_p, lbl, lse_l, g_l, bv=bv, V=V, interpret=interp)
-    return dlg[:T0], None
+    return dlg[:T0]
 
 
 fused_softmax_xent.defvjp(_xent_fwd, _xent_bwd)

@@ -33,16 +33,6 @@ __all__ = ["ring_flash_attention", "ulysses_attention"]
 _NEG_INF = -1e30
 
 
-def _axis_size(axis_name):
-    """Static (python int) size of a named mesh axis from inside
-    shard_map.  ``lax.axis_size`` only exists on newer jax; on the
-    pinned 0.4.x toolchain ``lax.psum`` of a literal 1 constant-folds
-    to the same static int."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
 def _repeat_kv(q, k, v):
     H, Hk = q.shape[2], k.shape[2]
     if Hk != H:  # MQA/GQA: repeat kv heads
@@ -59,7 +49,7 @@ def ring_flash_attention(q, k, v, axis_name, causal=False, scale=None):
     (B, S_local, H, D) — the exact softmax attention over the full
     sequence, computed without ever materializing full K/V on one device.
     """
-    size = _axis_size(axis_name)
+    size = lax.axis_size(axis_name)
     rank = lax.axis_index(axis_name)
     B, Sl, H, D = q.shape
     if scale is None:
@@ -114,7 +104,7 @@ def ulysses_attention(q, k, v, axis_name, causal=False, scale=None,
     all_to_all reshards to head-sharded/full-sequence, runs dense (flash)
     attention locally, reshards back.  Requires sep | H and sep | H_kv.
     """
-    size = _axis_size(axis_name)
+    size = lax.axis_size(axis_name)
     if q.shape[2] % size or k.shape[2] % size:
         raise ValueError(
             f"ulysses requires sep axis size {size} to divide num heads "

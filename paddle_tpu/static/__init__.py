@@ -328,7 +328,6 @@ def save_inference_model(path_prefix, feed_vars, fetch_vars, executor=None,
     arg_shapes = [jax.ShapeDtypeStruct(tuple(t.shape), t.dtype)
                   for _, t in ph_items]
     leaf_vals = [t._value for t in leaves]
-    # jax 0.4.x: `jax.export` is importable but not an attribute of jax
     from jax import export as _jax_export
     exported = _jax_export.export(
         jax.jit(pure), platforms=("cpu", "tpu"))(arg_shapes, leaf_vals)

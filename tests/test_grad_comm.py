@@ -112,12 +112,8 @@ class TestGradCommConfig:
         with pytest.raises(ValueError, match="unknown quantize mode"):
             GradCommConfig(quantize="int4")
 
-    def test_fp8_falls_back_when_unavailable(self):
-        cc = GradCommConfig(quantize="fp8")
-        if getattr(jnp, "float8_e4m3fn", None) is None:
-            assert cc.quantize == "int8" and cc.fp8_fallback
-        else:
-            assert cc.quantize == "fp8" and not cc.fp8_fallback
+    def test_fp8_mode_is_kept(self):
+        assert GradCommConfig(quantize="fp8").quantize == "fp8"
 
 
 # -- reducer on the 8-device mesh -------------------------------------------
@@ -137,7 +133,7 @@ class TestReducerOnMesh:
             pytest.approx(plan.overlap_fraction)
 
     def test_quant_reduce_tracks_exact_mean(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
         assert jax.device_count() == 8
@@ -160,7 +156,7 @@ class TestReducerOnMesh:
 
         out = jax.jit(shard_map(body, mesh=mesh, in_specs=(),
                                 out_specs=tuple(P() for _ in range(6)),
-                                check_rep=False))()
+                                check_vma=False))()
         exact, approx = out[:3], out[3:]
         for e, a in zip(exact, approx):
             amax = float(jnp.max(jnp.abs(e)))

@@ -87,9 +87,10 @@ class TestChoose:
             assert kreg.choose("attention").impl == "xla"
         assert kreg.choose("attention").impl == "pallas"  # interpret dflt
 
-    def test_typo_forced_impl_falls_back(self, monkeypatch):
+    def test_typo_forced_impl_raises(self, monkeypatch):
         monkeypatch.setenv("PADDLE_TPU_KERNEL_ATTENTION", "no_such_impl")
-        assert kreg.choose("attention").impl == "xla"
+        with pytest.raises(ValueError, match="no impl 'no_such_impl'"):
+            kreg.choose("attention")
 
     def test_unknown_kernel_raises(self):
         with pytest.raises(KeyError):
@@ -410,6 +411,90 @@ def _model_grads(build, loss_of):
 
     loss, grads = jax.value_and_grad(loss_fn)(pvals)
     return float(loss), grads
+
+
+class TestPartitionedDispatch:
+    """PR 21: XLA cannot partition a Mosaic kernel ("wrap the call in a
+    shard_map" — first seen on four v5e chips), so under a multi-device
+    trace the dispatch sites run the kernels per shard.  Interpret mode
+    on the CPU mesh checks the wrapping (specs, the (B, H, S) lse
+    residual, row sharding) against the single-device result."""
+
+    def _mesh(self):
+        from jax.sharding import Mesh
+        return Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2),
+                    ("data", "model"))
+
+    def test_context_is_trace_scoped_and_skips_one_device_meshes(self):
+        from jax.sharding import Mesh
+        assert kreg.current_partition() is None
+        with kreg.partitioned(self._mesh(), ("data", "sharding"), "model") \
+                as part:
+            assert kreg.current_partition() is part
+            assert part.batch_axes == ("data",)      # absent axis dropped
+            assert part.batch(8) == "data" and part.batch(3) is None
+            assert part.heads(4, 2) == "model" and part.heads(4, 1) is None
+        assert kreg.current_partition() is None
+        with kreg.partitioned(Mesh(np.asarray(jax.devices()[:1]),
+                                   ("data",)), ("data",), None):
+            assert kreg.current_partition() is None
+
+    @pytest.mark.parametrize("masked", [False, True])
+    def test_flash_per_shard_matches_single_device(self, masked):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from paddle_tpu.nn.functional import attention as A
+        mesh = self._mesh()
+        rng = np.random.RandomState(0)
+        B, S, H, D = 4, 256, 4, 64
+        q, k, v, g = (jnp.asarray(rng.randn(B, S, H, D).astype("f4"))
+                      for _ in range(4))
+        bias = jnp.asarray(np.where(rng.rand(B, S) < 0.2, -1e30, 0.0)
+                           .astype("f4")) if masked else None
+        flash = A._Flash(True, True)
+
+        def loss(q, k, v):
+            o = A._attention_core(q, k, v, True, None, flash) \
+                if bias is None else \
+                A._attention_core_bias(q, k, v, bias, False, flash)
+            return jnp.sum(o * g)
+
+        def sharded(q, k, v):
+            with kreg.partitioned(mesh, ("data",), "model"):
+                return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+        ref = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, k, v)
+        sh = NamedSharding(mesh, P("data", None, "model", None))
+        got = jax.jit(sharded, in_shardings=(sh, sh, sh))(q, k, v)
+        np.testing.assert_allclose(got[0], ref[0], rtol=1e-5)
+        for a, b in zip(got[1], ref[1]):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-5)
+        assert "shard_map" in str(jax.make_jaxpr(sharded)(q, k, v))
+
+    def test_xent_per_row_shard_matches_single_device(self, monkeypatch):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from paddle_tpu.ops.pallas import fused_xent as fx
+        monkeypatch.setenv("PADDLE_TPU_KERNEL_INTERPRET", "1")
+        mesh = self._mesh()
+        rng = np.random.RandomState(1)
+        T, V = 1000, 512                  # 500 rows per shard: row padding
+        lg = jnp.asarray(rng.randn(T, V).astype("f4"))
+        lb = jnp.asarray(rng.randint(0, V, (T,)).astype("i4")) \
+            .at[::5].set(-100)
+
+        def loss(x):
+            return jnp.sum(fx.fused_softmax_xent(x, lb))
+
+        def sharded(x):
+            with kreg.partitioned(mesh, ("data",), "model"):
+                return jax.value_and_grad(loss)(x)
+
+        ref = jax.jit(jax.value_and_grad(loss))(lg)
+        got = jax.jit(sharded, in_shardings=(
+            NamedSharding(mesh, P("data", "model")),))(lg)   # vocab-parallel in
+        np.testing.assert_allclose(got[0], ref[0], rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(got[1]), np.asarray(ref[1]),
+                                   atol=1e-6)
 
 
 class TestTrainStepParity:

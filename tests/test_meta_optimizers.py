@@ -182,7 +182,7 @@ def test_gradient_merge_parity_with_large_batch():
 def test_localsgd_sync_values_pmean():
     """Per-device divergent params average across the dp axis."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     mesh = Mesh(np.asarray(jax.devices()[:8]), ("data",))
     per_dev = jnp.arange(8, dtype=jnp.float32).reshape(8, 1)
 

@@ -222,10 +222,7 @@ def test_sparse_dispatch_flops_scale_linearly():
 
     def flops(fn):
         lowered = jax.jit(lambda xv, *ps: fn(xv, *ps)[0]).lower(x, *params)
-        ca = lowered.compile().cost_analysis()
-        if isinstance(ca, (list, tuple)):   # jax 0.4.x: per-device list
-            ca = ca[0]
-        return ca["flops"]
+        return lowered.compile().cost_analysis()["flops"]
 
     f_dense = flops(moe._moe_fn_stacked)
     f_sparse = flops(moe._moe_fn_stacked_sparse)

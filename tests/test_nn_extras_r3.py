@@ -168,14 +168,9 @@ def test_distributed_surface_r3():
     (reference: paddle.distributed API; TPU mapping: ppermute)."""
     import paddle_tpu.distributed as dist
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map
-        smap = lambda f, m, i, o: shard_map(f, mesh=m, in_specs=i,
-                                            out_specs=o)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-        smap = lambda f, m, i, o: shard_map(f, mesh=m, in_specs=i,
-                                            out_specs=o)
+    from jax import shard_map
+    smap = lambda f, m, i, o: shard_map(f, mesh=m, in_specs=i,
+                                        out_specs=o)
 
     assert dist.get_backend() == "XLA"
     objs = [{"a": 1}]
@@ -608,7 +603,7 @@ def test_batch_isend_irecv_rejects_inconsistent_shift():
     uniform shift), not silently mistraced."""
     import paddle_tpu.distributed as dist
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map as smap
+    from jax import shard_map as smap
     from paddle_tpu.framework.core import Tensor
 
     dist.init_parallel_env()
@@ -626,7 +621,7 @@ def test_batch_isend_irecv_rejects_inconsistent_shift():
 
     x = jnp.arange(8, dtype=jnp.float32).reshape(8, 1)
     with pytest.raises(ValueError, match="uniform shift|same rotation"):
-        smap(bad, mesh, P("g2"), P("g2"))(x)
+        smap(bad, mesh=mesh, in_specs=P("g2"), out_specs=P("g2"))(x)
 
 
 def test_py_func_skip_vars_backward_shapes():
@@ -660,7 +655,7 @@ def test_batch_isend_irecv_bidirectional_pairs_by_shift():
     bidirectional exchange declared sends-first must work."""
     import paddle_tpu.distributed as dist
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map as smap
+    from jax import shard_map as smap
     from paddle_tpu.framework.core import Tensor
 
     dist.init_parallel_env()
@@ -679,7 +674,8 @@ def test_batch_isend_irecv_bidirectional_pairs_by_shift():
         return fwd_buf._value + bwd_buf._value
 
     x = jnp.arange(8, dtype=jnp.float32).reshape(8, 1)
-    out = smap(bidir, mesh, P("g3"), P("g3"))(x)
+    out = smap(bidir, mesh=mesh, in_specs=P("g3"),
+               out_specs=P("g3"))(x)
     expect = np.roll(np.arange(8.0), 1) + 10.0 * np.roll(np.arange(8.0), -1)
     np.testing.assert_allclose(np.asarray(out).reshape(-1), expect)
 

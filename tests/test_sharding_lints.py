@@ -56,7 +56,7 @@ class TestMeshAxes:
     def test_flags_shard_map_arity_mismatch(self, tmp_path):
         found = _lint(tmp_path, """
             from jax.sharding import PartitionSpec as P
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
 
             def kernel(x):
                 return x
@@ -71,7 +71,7 @@ class TestMeshAxes:
     def test_matching_arity_is_clean(self, tmp_path):
         found = _lint(tmp_path, """
             from jax.sharding import PartitionSpec as P
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
 
             def kernel(x, y):
                 return x + y
@@ -96,7 +96,7 @@ class TestMeshAxes:
         found = _lint(tmp_path, """
             from jax import lax
             from jax.sharding import PartitionSpec as P
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
 
             def reduce(x):
                 return lax.psum(x, "data")

@@ -183,7 +183,7 @@ class TestDataParallelLockstep:
         # one rank's NaN must flip EVERY rank's verdict (pmin over the
         # dp axis) so replicas skip in lockstep instead of diverging
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         assert jax.device_count() == 8
         mesh = Mesh(np.asarray(jax.devices()[:2]), ("dp",))
         group = collective.new_group(axis_name="dp")

@@ -36,8 +36,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from paddle_tpu.ops.pallas.compat import TPUCompilerParams
-
 bk, bn = (int(sys.argv[1]), int(sys.argv[2])) if len(sys.argv) > 2 else (4096, 1024)
 mode = sys.argv[3] if len(sys.argv) > 3 else "mul"
 M,K,N,L,R = 32,4096,4096,32,500
@@ -79,7 +77,7 @@ def mm(x, w8, s):
         out_specs=pl.BlockSpec((M, bn), lambda n, k: (0, n)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.bfloat16),
         scratch_shapes=[pltpu.VMEM((M, bn), jnp.float32)],
-        compiler_params=TPUCompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
     )(x, w8, s.reshape(1, -1))
 
 @jax.jit
