@@ -251,7 +251,7 @@ def build_grad_reducer(shapes, dtypes, cfg, axis_name, world):
         # analytical bytes ONE step puts on the wire under this plan
         # (static shapes + wire mode — no readback): quantized modes
         # carry ~1 byte/element plus one fp32 scale per quant chunk;
-        # joined against compile-telemetry FLOPs by `report --roofline`
+        # joined against compile-telemetry FLOPs by `roofline_from_stats`
         n_elts = sum(int(np.prod(s, dtype=np.int64) or 1)
                      for s in shapes)
         item = {"int8": 1, "fp8": 1, "bf16": 2}.get(mode, 4)

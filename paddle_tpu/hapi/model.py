@@ -8,6 +8,7 @@ step (fwd+bwd+optimizer) is a single XLA executable; the reference needed
 the static-graph adapter + fused optimizer kernels to get this.  Eager
 (per-op) execution is kept as a debug mode (``Model.prepare(jit=False)``).
 """
+import contextlib
 import json
 import os
 import re
@@ -20,6 +21,8 @@ import jax.numpy as jnp
 
 from ..analysis import jit_surface
 from .. import observability as _obs
+from ..observability import tracing as _tracing
+from ..observability.tracing import scope as _scope
 from ..framework.core import Tensor
 from ..framework import autograd as _ag
 from ..framework import guardian as _guardian
@@ -303,26 +306,28 @@ class _CompiledStepper:
                 tv_map = dict(zip(t_idx, tv))
                 fi = iter(frozen_vals)
                 pv = []
-                for i in range(len(self.params)):
-                    if i in tv_map:
-                        v = tv_map[i]
-                        if amp in ("O1", "O2") and \
-                                jnp.issubdtype(v.dtype, jnp.floating):
-                            v = v.astype(jnp.bfloat16)
-                        pv.append(v)
-                    else:
-                        pv.append(next(fi))
-                ins = inputs
-                if amp in ("O1", "O2"):
-                    ins = [v.astype(jnp.bfloat16)
-                           if jnp.issubdtype(v.dtype, jnp.floating) else v
-                           for v in inputs]
+                with _scope("amp_cast"):
+                    for i in range(len(self.params)):
+                        if i in tv_map:
+                            v = tv_map[i]
+                            if amp in ("O1", "O2") and \
+                                    jnp.issubdtype(v.dtype, jnp.floating):
+                                v = v.astype(jnp.bfloat16)
+                            pv.append(v)
+                        else:
+                            pv.append(next(fi))
+                    ins = inputs
+                    if amp in ("O1", "O2"):
+                        ins = [v.astype(jnp.bfloat16)
+                               if jnp.issubdtype(v.dtype, jnp.floating)
+                               else v for v in inputs]
                 out_vals, new_buf = self._forward_pure(
                     pv, buffer_vals, key, ins, training=True)
                 if amp in ("O1", "O2"):
-                    out_vals = [v.astype(jnp.float32)
-                                if jnp.issubdtype(v.dtype, jnp.bfloat16)
-                                else v for v in out_vals]
+                    with _scope("amp_cast"):
+                        out_vals = [v.astype(jnp.float32)
+                                    if jnp.issubdtype(v.dtype, jnp.bfloat16)
+                                    else v for v in out_vals]
                 loss = self._loss_pure(out_vals, labels)
                 return loss, (out_vals, new_buf)
 
@@ -338,18 +343,22 @@ class _CompiledStepper:
             new_buf = [jax.lax.pmean(b, axis)
                        if jnp.issubdtype(b.dtype, jnp.inexact)
                        else jax.lax.pmax(b, axis) for b in new_buf]
-            new_train, new_opt = apply_functional_with_clip(
-                opt, train_vals, grads, opt_state, lr, param_names=pnames)
+            with _scope("optimizer"):
+                new_train, new_opt = apply_functional_with_clip(
+                    opt, train_vals, grads, opt_state, lr,
+                    param_names=pnames)
             if guard:
                 # reduced grads are replicated, so the verdict (and the
                 # skip) is identical on every replica — no extra pmin
-                ok = _guardian.tree_all_finite(list(grads) + [loss])
-                sel = lambda n, o: jnp.where(ok, n, o)  # noqa: E731
-                new_train = [sel(n, o) for n, o in zip(new_train,
-                                                       train_vals)]
-                new_opt = jax.tree_util.tree_map(sel, new_opt, opt_state)
-                new_buf = [sel(n, o) for n, o in zip(new_buf,
-                                                     buffer_vals)]
+                with _scope("guard"):
+                    ok = _guardian.tree_all_finite(list(grads) + [loss])
+                    sel = lambda n, o: jnp.where(ok, n, o)  # noqa: E731
+                    new_train = [sel(n, o) for n, o in zip(new_train,
+                                                           train_vals)]
+                    new_opt = jax.tree_util.tree_map(sel, new_opt,
+                                                     opt_state)
+                    new_buf = [sel(n, o) for n, o in zip(new_buf,
+                                                         buffer_vals)]
                 return loss, new_train, new_buf, new_opt, out_vals, ok
             return loss, new_train, new_buf, new_opt, out_vals
 
@@ -394,15 +403,16 @@ class _CompiledStepper:
                 pv = []
                 tv_map = dict(zip(t_idx, tv))
                 fi = iter(frozen_vals)
-                for i in range(len(self.params)):
-                    if i in tv_map:
-                        v = tv_map[i]
-                        if amp in ("O1", "O2") and \
-                                jnp.issubdtype(v.dtype, jnp.floating):
-                            v = v.astype(jnp.bfloat16)
-                        pv.append(v)
-                    else:
-                        pv.append(next(fi))
+                with _scope("amp_cast"):
+                    for i in range(len(self.params)):
+                        if i in tv_map:
+                            v = tv_map[i]
+                            if amp in ("O1", "O2") and \
+                                    jnp.issubdtype(v.dtype, jnp.floating):
+                                v = v.astype(jnp.bfloat16)
+                            pv.append(v)
+                        else:
+                            pv.append(next(fi))
                 new_amax = None
                 if fp8:
                     # fp8 pilot: STE fake-quant over the MERGED list
@@ -411,22 +421,26 @@ class _CompiledStepper:
                     pv, new_amax = _fp8_apply(pv, fp8_idx, fp8_amax)
                 ins = inputs
                 if amp in ("O1", "O2"):
-                    ins = [v.astype(jnp.bfloat16)
-                           if jnp.issubdtype(v.dtype, jnp.floating) else v
-                           for v in inputs]
+                    with _scope("amp_cast"):
+                        ins = [v.astype(jnp.bfloat16)
+                               if jnp.issubdtype(v.dtype, jnp.floating)
+                               else v for v in inputs]
                 out_vals, new_buf = self._forward_pure(
                     pv, buffer_vals, key, ins, training=True)
                 if amp in ("O1", "O2"):
-                    out_vals = [v.astype(jnp.float32)
-                                if jnp.issubdtype(v.dtype, jnp.bfloat16)
-                                else v for v in out_vals]
+                    with _scope("amp_cast"):
+                        out_vals = [v.astype(jnp.float32)
+                                    if jnp.issubdtype(v.dtype, jnp.bfloat16)
+                                    else v for v in out_vals]
                 loss = self._loss_pure(out_vals, labels)
                 return loss, (out_vals, new_buf, new_amax)
 
             (loss, (out_vals, new_buf, new_amax)), grads = \
                 jax.value_and_grad(loss_f, has_aux=True)(train_vals)
-            new_train, new_opt = apply_functional_with_clip(
-                opt, train_vals, grads, opt_state, lr, param_names=pnames)
+            with _scope("optimizer"):
+                new_train, new_opt = apply_functional_with_clip(
+                    opt, train_vals, grads, opt_state, lr,
+                    param_names=pnames)
             if guard:
                 # guardian sentinel: ONE fused finite reduction over the
                 # whole grad tree + loss, then a device-side select that
@@ -435,14 +449,18 @@ class _CompiledStepper:
                 # An fp8 saturation (NaN loss/grads) trips this exact
                 # ladder; the amax state also holds on trip so a
                 # poisoned batch cannot poison the scales.
-                ok = _guardian.tree_all_finite(list(grads) + [loss])
-                sel = lambda n, o: jnp.where(ok, n, o)  # noqa: E731
-                new_train = [sel(n, o) for n, o in zip(new_train,
-                                                       train_vals)]
-                new_opt = jax.tree_util.tree_map(sel, new_opt, opt_state)
-                new_buf = [sel(n, o) for n, o in zip(new_buf, buffer_vals)]
+                with _scope("guard"):
+                    ok = _guardian.tree_all_finite(list(grads) + [loss])
+                    sel = lambda n, o: jnp.where(ok, n, o)  # noqa: E731
+                    new_train = [sel(n, o) for n, o in zip(new_train,
+                                                           train_vals)]
+                    new_opt = jax.tree_util.tree_map(sel, new_opt,
+                                                     opt_state)
+                    new_buf = [sel(n, o)
+                               for n, o in zip(new_buf, buffer_vals)]
+                    if fp8:
+                        new_amax = sel(new_amax, fp8_amax)
                 if fp8:
-                    new_amax = sel(new_amax, fp8_amax)
                     return (loss, new_train, new_buf, new_opt, new_amax,
                             out_vals, ok)
                 return loss, new_train, new_buf, new_opt, out_vals, ok
@@ -769,12 +787,7 @@ class Model:
         if self._jit and self._stepper is not None:
             loss, out_vals = self._stepper.train_step(inputs, labels,
                                                       update=update)
-            metrics = self._update_metrics(
-                [Tensor(v) for v in out_vals], _as_list(labels))
-            if isinstance(self._optimizer._learning_rate, LRScheduler) and \
-                    update:
-                self._optimizer._learning_rate.step()
-            return self._pack_loss_metrics(float(loss), metrics)
+            return self._train_readback(loss, out_vals, labels, update)
         # eager path
         ins = [x if isinstance(x, Tensor) else Tensor(_to_jnp(x))
                for x in _as_list(inputs)]
@@ -795,6 +808,18 @@ class Model:
                 self._optimizer._learning_rate.step()
         metrics = self._update_metrics(outs, labs)
         return self._pack_loss_metrics(float(loss.item()), metrics)
+
+    def _train_readback(self, loss, out_vals, labels, update):
+        """The host half of a compiled ``train_batch``, after the
+        stepper's dispatch returned: metric updates, the LR schedule and
+        the step's one readback, ``float(loss)`` (``fit`` books it as
+        ``fit.readback``)."""
+        metrics = self._update_metrics(
+            [Tensor(v) for v in out_vals], _as_list(labels))
+        if isinstance(self._optimizer._learning_rate, LRScheduler) and \
+                update:
+            self._optimizer._learning_rate.step()
+        return self._pack_loss_metrics(float(loss), metrics)
 
     def eval_batch(self, inputs, labels=None):
         self.network.eval()
@@ -1065,11 +1090,14 @@ class Model:
                 cursor = self._resume_from(resume)
                 if cursor is not None:
                     start_epoch, skip_steps = cursor
-            self._fit_epochs(epochs, eval_freq, save_dir, cbks,
-                             train_loader, eval_loader, num_iters,
-                             accumulate_grad_batches, batch_size,
-                             start_epoch=start_epoch,
-                             skip_steps=skip_steps, save_freq=save_freq)
+            trace = _tracing.mint("fit")
+            with _tracing.region(trace, None, "fit") as root:
+                self._fit_epochs(epochs, eval_freq, save_dir, cbks,
+                                 train_loader, eval_loader, num_iters,
+                                 accumulate_grad_batches, batch_size,
+                                 trace, root.id, start_epoch=start_epoch,
+                                 skip_steps=skip_steps,
+                                 save_freq=save_freq)
         finally:
             if self._guardian is not None:
                 self._guardian.stop()
@@ -1082,16 +1110,49 @@ class Model:
             if _preempt_installed:
                 _preemption.uninstall()
 
+    @staticmethod
+    def _timed_batches(loader, trace, parent, count):
+        """``loader``'s batches, each ``__next__`` under a ``fit.data``
+        span numbered like the step it feeds (``count[0]``)."""
+        it = iter(loader)
+        while True:
+            with _tracing.region(trace, count[0], "fit.data",
+                                 parent=parent) as r:
+                try:
+                    batch = next(it)
+                except StopIteration:
+                    r.args["exhausted"] = True
+                    return
+            yield batch
+
     def _fit_epochs(self, epochs, eval_freq, save_dir, cbks, train_loader,
                     eval_loader, num_iters, accumulate_grad_batches,
-                    batch_size, start_epoch=0, skip_steps=0, save_freq=1):
+                    batch_size, trace, root, start_epoch=0, skip_steps=0,
+                    save_freq=1):
+        # the fit call's trace (observability/tracing.py): under the
+        # ``fit`` span ``root``, ``fit.data`` and ``fit.step`` per step,
+        # and on the compiled path ``fit.dispatch`` / ``fit.readback`` /
+        # ``fit.post`` tiling the step from ``_split_batch`` on.  Every
+        # stamp is a host clock read; docs/observability.md has the table
         logs = {}            # bound even when epochs == 0
+        jit = self._jit and self._stepper is not None
+        count = [0]          # steps of this fit call: the spans' req_id
+        readback_end = None  # end of the last fit.readback, for the gap
+
+        def child(name, n, parent, start_ns=None):
+            # the eager path has no dispatch/readback boundary to stamp:
+            # it books fit.step alone
+            if not jit:
+                return contextlib.nullcontext(_tracing.Region({}))
+            return _tracing.region(trace, n, name, parent=parent,
+                                   start_ns=start_ns)
         for epoch in range(start_epoch, epochs):
             cbks.on_epoch_begin(epoch)
             self._reset_metrics()
             self.network.train()
             logs = {}
-            for step, batch in enumerate(train_loader):
+            for step, batch in enumerate(self._timed_batches(
+                    train_loader, trace, root, count)):
                 if num_iters is not None and step >= num_iters:
                     break
                 if epoch == start_epoch and step < skip_steps:
@@ -1108,71 +1169,50 @@ class Model:
                     if self.stop_training:
                         break
                     continue
-                cbks.on_batch_begin("train", step, logs)
-                ins, labs = self._split_batch(batch)
-                guard = self._guardian
-                if guard is not None:
-                    if guard.skip_batch():   # post-rollback poisoned window
+                n = count[0]
+                count[0] += 1
+                with _tracing.region(trace, n, "fit.step",
+                                     parent=root) as sp:
+                    cbks.on_batch_begin("train", step, logs)
+                    guard = self._guardian
+                    do_update = (step + 1) % max(accumulate_grad_batches,
+                                                 1) == 0
+                    with child("fit.dispatch", n, sp.id) as disp:
+                        ins, labs = self._split_batch(batch)
+                        skip = guard is not None and guard.skip_batch()
+                        if not skip:
+                            if guard is not None:
+                                ins = guard.filter_batch(ins)
+                            # telemetry: wall time of the whole step,
+                            # including the per-step loss readback
+                            # below — recording adds NO device transfer
+                            # (every value is a host float/shape the
+                            # loop already owns)
+                            t_step = time.perf_counter()
+                            if jit:
+                                self.network.train()
+                                stepped = self._stepper.train_step(
+                                    ins, labs, update=do_update)
+                    if skip:     # post-rollback poisoned window
                         cbks.on_batch_end("train", step, logs)
                         continue
-                    ins = guard.filter_batch(ins)
-                do_update = (step + 1) % max(accumulate_grad_batches,
-                                             1) == 0
-                # telemetry: wall time of the whole step, including the
-                # per-step loss readback already inside train_batch —
-                # recording adds NO device transfer (values below are
-                # host floats/shapes the loop already owns)
-                t_step = time.perf_counter()
-                res = self.train_batch(ins, labs, update=do_update)
-                verdict = None
-                if guard is not None:
-                    loss_v = res[0][0] if isinstance(res, tuple) else res[0]
-                    ok = (self._stepper.last_ok
-                          if self._jit and self._stepper is not None
-                          else None)
-                    verdict = guard.after_step(loss_v, ok_flag=ok,
-                                               batch=(ins, labs))
-                step_s = time.perf_counter() - t_step
-                # one token count feeds both the metrics below and the
-                # flight sample — counted once so they can never drift
-                tokens = None
-                if ins and hasattr(ins[0], "shape"):
-                    tokens = 1
-                    for d in ins[0].shape:
-                        tokens *= int(d)
-                if _obs.enabled():
-                    _obs.observe("pt_train_step_latency_ms", step_s * 1e3)
-                    _obs.inc("pt_train_steps_total",
-                             outcome=verdict or "ok")
-                    if tokens is not None:
-                        _obs.inc("pt_train_tokens_total", tokens)
-                        _obs.set_gauge("pt_train_tokens_per_sec",
-                                       tokens / max(step_s, 1e-9))
-                logs = self._make_logs(res)
-                if _obs.enabled() and logs.get("loss") is not None:
-                    _obs.set_gauge("pt_train_loss", float(logs["loss"]))
-                # flight recorder (observability/flight.py): one sample
-                # per step at THIS existing sync point — every value is
-                # a host number the loop already owns (wall delta,
-                # static shapes, the loss readback train_batch already
-                # paid), so the zero-new-host-sync A/B contract holds
-                if _obs.flight.active():
-                    tok_s = None if tokens is None \
-                        else tokens / max(step_s, 1e-9)
-                    _obs.flight.record(
-                        "fit_step", step_latency_ms=step_s * 1e3,
-                        tokens_per_sec=tok_s,
-                        loss=(float(logs["loss"])
-                              if logs.get("loss") is not None else None),
-                        verdict=verdict or "ok",
-                        # live-buffer census (HBM ledger): host
-                        # metadata only, at the post-step sync
-                        **_obs.memory.census_fields("fit_step"))
-                logs["step"] = step
-                logs["batch_size"] = (
-                    ins[0].shape[0] if ins and hasattr(ins[0], "shape")
-                    else batch_size)
-                cbks.on_batch_end("train", step, logs)
+                    if readback_end is not None and disp.end_ns is not None:
+                        _obs.observe("pt_train_host_gap_ms",
+                                     (disp.end_ns - readback_end) / 1e6)
+                    with child("fit.readback", n, sp.id, disp.end_ns) as rb:
+                        res = self._train_readback(
+                            *stepped, labs, do_update) if jit else \
+                            self.train_batch(ins, labs, update=do_update)
+                    # the step's outputs (fp32 logits among them) must
+                    # not outlive the step in this frame: held, the next
+                    # step cannot reuse their memory
+                    stepped = None
+                    readback_end = rb.end_ns
+                    with child("fit.post", n, sp.id, rb.end_ns) as post:
+                        logs = self._after_train_batch(
+                            res, guard, ins, labs, t_step, step, batch_size)
+                        cbks.on_batch_end("train", step, logs)
+                    sp.end_ns = post.end_ns
                 if _preemption.preempted():
                     self._emergency_save(save_dir, epoch, step)
                     cbks.on_end("train", logs)
@@ -1206,6 +1246,61 @@ class Model:
             if self.stop_training:
                 break
         cbks.on_end("train", logs)
+
+    def _after_train_batch(self, res, guard, ins, labs, t_step, step,
+                           batch_size):
+        """What ``fit`` does with one step's result before
+        ``on_batch_end``: the guardian's verdict, the step's metrics and
+        flight sample, the logs (returned)."""
+        verdict = None
+        if guard is not None:
+            loss_v = res[0][0] if isinstance(res, tuple) else res[0]
+            ok = (self._stepper.last_ok
+                  if self._jit and self._stepper is not None
+                  else None)
+            verdict = guard.after_step(loss_v, ok_flag=ok,
+                                       batch=(ins, labs))
+        step_s = time.perf_counter() - t_step
+        # one token count feeds both the metrics below and the
+        # flight sample — counted once so they can never drift
+        tokens = None
+        if ins and hasattr(ins[0], "shape"):
+            tokens = 1
+            for d in ins[0].shape:
+                tokens *= int(d)
+        if _obs.enabled():
+            _obs.observe("pt_train_step_latency_ms", step_s * 1e3)
+            _obs.inc("pt_train_steps_total",
+                     outcome=verdict or "ok")
+            if tokens is not None:
+                _obs.inc("pt_train_tokens_total", tokens)
+                _obs.set_gauge("pt_train_tokens_per_sec",
+                               tokens / max(step_s, 1e-9))
+        logs = self._make_logs(res)
+        if _obs.enabled() and logs.get("loss") is not None:
+            _obs.set_gauge("pt_train_loss", float(logs["loss"]))
+        # flight recorder (observability/flight.py): one sample
+        # per step at THIS existing sync point — every value is
+        # a host number the loop already owns (wall delta,
+        # static shapes, the loss readback train_batch already
+        # paid), so the zero-new-host-sync A/B contract holds
+        if _obs.flight.active():
+            tok_s = None if tokens is None \
+                else tokens / max(step_s, 1e-9)
+            _obs.flight.record(
+                "fit_step", step_latency_ms=step_s * 1e3,
+                tokens_per_sec=tok_s,
+                loss=(float(logs["loss"])
+                      if logs.get("loss") is not None else None),
+                verdict=verdict or "ok",
+                # live-buffer census (HBM ledger): host
+                # metadata only, at the post-step sync
+                **_obs.memory.census_fields("fit_step"))
+        logs["step"] = step
+        logs["batch_size"] = (
+            ins[0].shape[0] if ins and hasattr(ins[0], "shape")
+            else batch_size)
+        return logs
 
     def evaluate(self, eval_data, batch_size=1, log_freq=10, verbose=2,
                  num_workers=0, callbacks=None, num_iters=None):

@@ -77,6 +77,7 @@ import jax.numpy as jnp
 
 from ..analysis import register_jit_surface
 from .. import observability as _obs
+from ..observability.tracing import scope as _scope
 
 __all__ = ["KVBundleError", "PagedCacheView", "PagedKVManager",
            "quantize_kv", "dequantize_kv",
@@ -236,18 +237,20 @@ def gather_pages(kp, vp, table):
     weight, so they contribute exactly 0 (same as the dense path's
     never-written zeros)."""
     B = table.shape[0]
-    k = kp[table].reshape(B, -1, kp.shape[2], kp.shape[3])
-    v = vp[table].reshape(B, -1, vp.shape[2], vp.shape[3])
+    with _scope("kv.gather"):
+        k = kp[table].reshape(B, -1, kp.shape[2], kp.shape[3])
+        v = vp[table].reshape(B, -1, vp.shape[2], vp.shape[3])
     return k, v
 
 
 def gather_pages_q(kp, vp, ks, vs, table, dtype):
     """int8 variant: dequantize with the per-token scale planes."""
     B = table.shape[0]
-    k = dequantize_kv(kp[table], ks[table], dtype)
-    v = dequantize_kv(vp[table], vs[table], dtype)
-    return (k.reshape(B, -1, kp.shape[2], kp.shape[3]),
-            v.reshape(B, -1, vp.shape[2], vp.shape[3]))
+    with _scope("kv.gather"):
+        k = dequantize_kv(kp[table], ks[table], dtype)
+        v = dequantize_kv(vp[table], vs[table], dtype)
+        return (k.reshape(B, -1, kp.shape[2], kp.shape[3]),
+                v.reshape(B, -1, vp.shape[2], vp.shape[3]))
 
 
 def _scatter_coords(table, pos, S, page_size):
@@ -270,22 +273,24 @@ def scatter_pages(kp, vp, k_new, v_new, table, pos):
     unmapped (trash) entry are discarded garbage by construction —
     inactive slots and pad positions beyond the allocated range."""
     S = k_new.shape[1]
-    phys, off = _scatter_coords(table, pos, S, kp.shape[1])
-    kp = kp.at[phys, off].set(k_new.astype(kp.dtype))
-    vp = vp.at[phys, off].set(v_new.astype(vp.dtype))
+    with _scope("kv.scatter"):
+        phys, off = _scatter_coords(table, pos, S, kp.shape[1])
+        kp = kp.at[phys, off].set(k_new.astype(kp.dtype))
+        vp = vp.at[phys, off].set(v_new.astype(vp.dtype))
     return kp, vp
 
 
 def scatter_pages_q(kp, vp, ks, vs, k_new, v_new, table, pos):
     """int8 variant: quantize each token row and store value + scale."""
     S = k_new.shape[1]
-    phys, off = _scatter_coords(table, pos, S, kp.shape[1])
-    qk, sk = quantize_kv(k_new)
-    qv, sv = quantize_kv(v_new)
-    kp = kp.at[phys, off].set(qk)
-    vp = vp.at[phys, off].set(qv)
-    ks = ks.at[phys, off].set(sk)
-    vs = vs.at[phys, off].set(sv)
+    with _scope("kv.scatter"):
+        phys, off = _scatter_coords(table, pos, S, kp.shape[1])
+        qk, sk = quantize_kv(k_new)
+        qv, sv = quantize_kv(v_new)
+        kp = kp.at[phys, off].set(qk)
+        vp = vp.at[phys, off].set(qv)
+        ks = ks.at[phys, off].set(sk)
+        vs = vs.at[phys, off].set(sv)
     return kp, vp, ks, vs
 
 
@@ -321,7 +326,8 @@ def _build_paged_prefill(apply, pick, eos, quant):
         pools = _layer_pools(new, quant)
         last = jax.lax.dynamic_slice_in_dim(
             logits, length - 1, 1, axis=1)[:, 0]            # (1, V)
-        t0, _ = pick(last, jax.random.key(0))               # (1,)
+        with _scope("sample"):
+            t0, _ = pick(last, jax.random.key(0))           # (1,)
         t0 = t0[0]
         hit_eos = (t0 == eos) if eos is not None else jnp.asarray(False)
         fin0 = hit_eos | (budget <= 1)
@@ -348,8 +354,9 @@ def _build_paged_decode_chunk(apply, pick, chunk, eos, pad, quant):
             caches = _layer_views(pools, safe, quant)
             logits, new = apply(pv, tokens[:, None], caches, pos)
             pools = _layer_pools(new, quant)
-            nxt, _ = pick(logits[:, 0, :], jax.random.key(0))
-            nxt = jnp.where(active, nxt, jnp.int32(pad))
+            with _scope("sample"):
+                nxt, _ = pick(logits[:, 0, :], jax.random.key(0))
+                nxt = jnp.where(active, nxt, jnp.int32(pad))
             emitted = active
             live = active.astype(jnp.int32)
             pos = pos + live

@@ -30,7 +30,8 @@ request cannot start until the whole batch finishes.  This engine keeps
 Greedy decode only (token picks shared bitwise with ``generate()`` via
 ``build_pick``); TTFT/throughput/queue-depth counters go to the
 guardian structured log (``serving_admit``/``serving_finish``/
-``serving_stats``) and profiler ``RecordEvent`` spans.  See
+``serving_stats``) and the program spans of ``observability.tracing``
+(``serving.step`` and its children, mirrored into the profiler).  See
 ``docs/serving.md``.
 """
 import threading
@@ -46,7 +47,6 @@ from ..analysis import register_jit_surface
 from ..framework import guardian
 from ..models.generation import (build_apply, build_pick, cast_weights,
                                  dominant_float_dtype, quantize_weights)
-from ..profiler import RecordEvent
 from .scheduler import FCFSScheduler, Request
 
 __all__ = ["ServingEngine", "Request", "FCFSScheduler"]
@@ -71,7 +71,8 @@ def _build_prefill(apply, pick, spec, cache_dtype, MAX, eos):
         logits, new = apply(pv, ids, fresh, jnp.zeros((), jnp.int32))
         last = jax.lax.dynamic_slice_in_dim(
             logits, length - 1, 1, axis=1)[:, 0]            # (1, V)
-        t0, _ = pick(last, jax.random.key(0))               # (1,)
+        with _tracing.scope("sample"):
+            t0, _ = pick(last, jax.random.key(0))           # (1,)
         t0 = t0[0]
         caches = [(jax.lax.dynamic_update_slice(
                        ck, nk.astype(ck.dtype), (slot, 0, 0, 0)),
@@ -98,8 +99,9 @@ def _build_decode_chunk(apply, pick, chunk, eos, pad):
         def body(carry, _):
             tokens, pos, active, remaining, caches = carry
             logits, caches = apply(pv, tokens[:, None], caches, pos)
-            nxt, _ = pick(logits[:, 0, :], jax.random.key(0))
-            nxt = jnp.where(active, nxt, jnp.int32(pad))
+            with _tracing.scope("sample"):
+                nxt, _ = pick(logits[:, 0, :], jax.random.key(0))
+                nxt = jnp.where(active, nxt, jnp.int32(pad))
             emitted = active
             live = active.astype(jnp.int32)
             pos = pos + live
@@ -368,6 +370,13 @@ class ServingEngine:
 
     def _init_device_state(self):
         S = self.num_slots
+        # the engine's trace (observability/tracing.py): one
+        # ``serving.step`` span per cycle, numbered by ``_cycle``; the
+        # end of the last ``serving.sync`` feeds pt_serving_host_gap_ms
+        # while requests stay in flight across the boundary
+        self._trace = _tracing.mint("engine")
+        self._cycle = 0
+        self._sync_end_ns = None
         self._tokens = jnp.full((S,), self.pad, jnp.int32)
         self._pos = jnp.zeros((S,), jnp.int32)
         self._active = jnp.zeros((S,), bool)
@@ -551,64 +560,89 @@ class ServingEngine:
         finished slots.  Returns the requests finished this cycle."""
         toks = valid = None
         saved_losses = [g.loss for g in self._gates]
-        try:
+        trace, cyc = self._trace, self._cycle
+        self._cycle += 1
+        with _tracing.region(trace, cyc, "serving.step") as sp:
+            # ``last`` is the child that ended last: the next one starts
+            # at its end, so the children tile the step
+            try:
+                with _tracing.region(trace, cyc, "serving.admit",
+                                     parent=sp.id,
+                                     start_ns=sp.start_ns) as last:
+                    if self._paged:
+                        self._page_pressure()
+                    pending = self._admit(cyc, last.id)
+                if self._paged and self.scheduler.queue_depth and \
+                        not pending and not self.scheduler.active:
+                    head = self.scheduler._queue[0]
+                    raise RuntimeError(
+                        f"kv page pool too small: request {head.req_id} "
+                        f"(resume length {self._resume_prompt(head).size}, "
+                        f"budget {head.max_new_tokens - len(head.tokens)}) "
+                        f"cannot be admitted even with all "
+                        f"{self._kv.num_pages - 1} pages free — raise "
+                        "num_pages or lower max_new_tokens")
+                if self.scheduler.active:
+                    with _tracing.region(trace, cyc, "serving.decode_chunk",
+                                         parent=sp.id,
+                                         start_ns=last.end_ns) as last:
+                        toks, valid = self._dispatch_chunk()
+                    if self._sync_end_ns is not None and \
+                            last.end_ns is not None:
+                        _obs.observe(
+                            "pt_serving_host_gap_ms",
+                            (last.end_ns - self._sync_end_ns) / 1e6)
+                    self.stats["chunks"] += 1
+                    _obs.inc("pt_serving_chunks_total")
+            finally:
+                for g, l in zip(self._gates, saved_losses):
+                    object.__setattr__(g, "loss", l)
+            self.stats["max_concurrent"] = max(
+                self.stats["max_concurrent"], len(self.scheduler.active))
+            finished, sp.end_ns = self._sync(pending, toks, valid, cyc,
+                                             sp.id, last.end_ns)
+            sp.args["in_flight"] = len(self.scheduler.active)
+        return finished
+
+    def _dispatch_chunk(self):
+        """Upload the page table and dispatch one compiled decode chunk
+        over all slots; returns the chunk's (tokens, valid) device
+        handles, read back at the sync."""
+        if self._spec is not None:
+            kv = self._pools if self._paged else self._caches
+            table = jnp.asarray(self._kv.table) \
+                if self._paged else None
+            (self._tokens, self._pos, self._active,
+             self._remaining, kv, self._draft_caches,
+             self._history, toks, valid) = \
+                self._decode_jit(
+                    self._pvals, self._draft_pvals,
+                    self._tokens, self._pos, self._active,
+                    self._remaining, kv, self._draft_caches,
+                    self._history, table)
             if self._paged:
-                self._page_pressure()
-            pending = self._admit()
-            if self._paged and self.scheduler.queue_depth and \
-                    not pending and not self.scheduler.active:
-                head = self.scheduler._queue[0]
-                raise RuntimeError(
-                    f"kv page pool too small: request {head.req_id} "
-                    f"(resume length {self._resume_prompt(head).size}, "
-                    f"budget {head.max_new_tokens - len(head.tokens)}) "
-                    f"cannot be admitted even with all "
-                    f"{self._kv.num_pages - 1} pages free — raise "
-                    "num_pages or lower max_new_tokens")
-            if self.scheduler.active:
-                with RecordEvent("serving.decode_chunk"):
-                    if self._spec is not None:
-                        kv = self._pools if self._paged else self._caches
-                        table = jnp.asarray(self._kv.table) \
-                            if self._paged else None
-                        (self._tokens, self._pos, self._active,
-                         self._remaining, kv, self._draft_caches,
-                         self._history, toks, valid) = \
-                            self._decode_jit(
-                                self._pvals, self._draft_pvals,
-                                self._tokens, self._pos, self._active,
-                                self._remaining, kv, self._draft_caches,
-                                self._history, table)
-                        if self._paged:
-                            self._pools = kv
-                            self._kv.set_pools(kv)
-                        else:
-                            self._caches = kv
-                        self.stats["spec_chunks"] += 1
-                        _obs.inc("pt_serving_spec_draft_chunks_total")
-                    elif self._paged:
-                        (self._tokens, self._pos, self._active,
-                         self._remaining, self._pools, toks, valid) = \
-                            self._decode_jit(
-                                self._pvals, self._tokens, self._pos,
-                                self._active, self._remaining,
-                                self._pools, jnp.asarray(self._kv.table))
-                        self._kv.set_pools(self._pools)
-                    else:
-                        (self._tokens, self._pos, self._active,
-                         self._remaining, self._caches, toks, valid) = \
-                            self._decode_jit(
-                                self._pvals, self._tokens, self._pos,
-                                self._active, self._remaining,
-                                self._caches)
-                self.stats["chunks"] += 1
-                _obs.inc("pt_serving_chunks_total")
-        finally:
-            for g, l in zip(self._gates, saved_losses):
-                object.__setattr__(g, "loss", l)
-        self.stats["max_concurrent"] = max(self.stats["max_concurrent"],
-                                           len(self.scheduler.active))
-        return self._sync(pending, toks, valid)
+                self._pools = kv
+                self._kv.set_pools(kv)
+            else:
+                self._caches = kv
+            self.stats["spec_chunks"] += 1
+            _obs.inc("pt_serving_spec_draft_chunks_total")
+        elif self._paged:
+            (self._tokens, self._pos, self._active,
+             self._remaining, self._pools, toks, valid) = \
+                self._decode_jit(
+                    self._pvals, self._tokens, self._pos,
+                    self._active, self._remaining,
+                    self._pools, jnp.asarray(self._kv.table))
+            self._kv.set_pools(self._pools)
+        else:
+            (self._tokens, self._pos, self._active,
+             self._remaining, self._caches, toks, valid) = \
+                self._decode_jit(
+                    self._pvals, self._tokens, self._pos,
+                    self._active, self._remaining,
+                    self._caches)
+        return toks, valid
 
     def run(self, timeout=None):
         """Drain the queue and all in-flight slots; returns finished
@@ -796,13 +830,15 @@ class ServingEngine:
         self._pools = self._kv.device_pools()
         return True
 
-    def _admit(self):
+    def _admit(self, cycle, parent):
         """Admit queued requests into free slots (bounded by the
         interleave knob): one compiled bucket prefill each, KV written
         straight into the assigned slot (dense) or into reserved pages
         (paged; a prefix-cache hit prefills only the uncached suffix).
         Returns the pending (request, first-token, finished-flag) device
-        handles — read back at the chunk-boundary sync, never here."""
+        handles — read back at the chunk-boundary sync, never here.
+        Each prefill dispatch books a ``serving.prefill`` span of this
+        ``cycle`` under ``parent`` (the cycle's ``serving.admit``)."""
         pending = []
         bound, armed, can_admit = {}, {}, None
         if self._paged:
@@ -904,7 +940,9 @@ class ServingEngine:
                 ids[0, :m] = rp[k:]
                 req.resume_len = n
                 req.emitted_since_admit = 0
-                with RecordEvent("serving.prefill"):
+                with _tracing.region(self._trace, cycle, "serving.prefill",
+                                     parent=parent, request=req.req_id,
+                                     bucket=bucket):
                     if self._spec is not None:
                         # the draft (and the token history) prefill the
                         # FULL resume prompt — the draft has no prefix
@@ -958,7 +996,9 @@ class ServingEngine:
                 ids[0, :n] = rp
                 req.resume_len = n
                 req.emitted_since_admit = 0
-                with RecordEvent("serving.prefill"):
+                with _tracing.region(self._trace, cycle, "serving.prefill",
+                                     parent=parent, request=req.req_id,
+                                     bucket=bucket):
                     if self._spec is not None:
                         ids_j = jnp.asarray(ids)   # full == suffix: no
                         (t0, fin0, self._tokens,   # dense prefix cache
@@ -1008,14 +1048,29 @@ class ServingEngine:
                            self.scheduler.queue_depth)
         return pending
 
-    def _sync(self, pending, toks, valid):
+    def _sync(self, pending, toks, valid, cycle, parent, start_ns):
         """THE chunk-boundary host sync: one ``jax.device_get`` of the
-        prefill first-tokens + decode-chunk tokens + slot liveness,
-        then stream callbacks, stamp TTFT, and free finished slots."""
-        with RecordEvent("serving.sync"):
+        prefill first-tokens + decode-chunk tokens + slot liveness
+        (``serving.sync``), then the delivery (``serving.deliver``).
+        Returns (finished requests, end of the delivery)."""
+        with _tracing.region(self._trace, cycle, "serving.sync",
+                             parent=parent, start_ns=start_ns) as sy:
             bundle = jax.device_get(
                 ([(t0, fin0) for _, _, t0, fin0 in pending],
                  toks, valid, self._active))
+        with _tracing.region(self._trace, cycle, "serving.deliver",
+                             parent=parent, start_ns=sy.end_ns) as dl:
+            finished = self._deliver(pending, bundle, parent)
+        # the host gap to the next chunk's dispatch counts only while
+        # requests stay in flight: an idle engine's wait is no host work
+        self._sync_end_ns = sy.end_ns if self.scheduler.active else None
+        return finished, dl.end_ns
+
+    def _deliver(self, pending, bundle, parent):
+        """Everything after the readback, all on host values it brought:
+        stream callbacks, stamp TTFT, book the request spans (children of
+        ``parent``, the cycle's ``serving.step``) and free finished
+        slots."""
         first, toks_h, valid_h, active_h = bundle
         now = time.perf_counter_ns()
         new_ttfts = []       # stamped THIS sync (flight-recorder sample)
@@ -1104,10 +1159,11 @@ class ServingEngine:
                                              req.requeue_ns,
                                              req.route_ns) if s)
                     _tracing.span(req.trace_id, req.req_id, "queue_wait",
-                                  qstart, req.admit_ns,
+                                  qstart, req.admit_ns, parent=parent,
                                   resume=req.evictions > 0, **rep)
                     _tracing.span(req.trace_id, req.req_id, "prefill",
-                                  req.admit_ns, now, bucket=req.bucket,
+                                  req.admit_ns, now, parent=parent,
+                                  bucket=req.bucket,
                                   cached_tokens=req.prefix_cached,
                                   resume=req.evictions > 0,
                                   tokens=len(toks_slot),
@@ -1117,7 +1173,7 @@ class ServingEngine:
                     _tracing.span(req.trace_id, req.req_id,
                                   "spec_decode" if self._spec is not None
                                   else "decode",
-                                  start, now,
+                                  start, now, parent=parent,
                                   tokens=len(toks_slot),
                                   reason=req.finish_reason, **rep)
             # decode_ms (the TPOT numerator) and the span cursor are

@@ -24,6 +24,7 @@ from .. import nn
 from ..nn import functional as F
 from ..distributed.fleet.meta_parallel.parallel_layers.mp_layers import (
     ColumnParallelLinear, RowParallelLinear, VocabParallelEmbedding)
+from ..observability.tracing import scope as _scope
 from .generation import GenerationMixin
 
 __all__ = ["GPTConfig", "GPTModel", "GPTForPretraining",
@@ -91,9 +92,10 @@ class GPTAttention(nn.Layer):
     def forward(self, x, cache=None, pos=None, attn_mask=None):
         from ..tensor.manipulation import reshape, concat
         B, S, H = x.shape
-        qkv = self.qkv_proj(x)
-        qkv = reshape(qkv, [B, S, 3, self.num_heads, self.head_dim])
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        with _scope("attention.qkv"):
+            qkv = self.qkv_proj(x)
+            qkv = reshape(qkv, [B, S, 3, self.num_heads, self.head_dim])
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         if pos is not None:
             # static-shape decode: write this chunk's k/v at offset `pos`
             # into the preallocated (B, MAX, nH, D) buffers and attend
@@ -105,11 +107,13 @@ class GPTAttention(nn.Layer):
             k = concat([cache[0], k], axis=1)
             v = concat([cache[1], v], axis=1)
             cache = (k, v)
-        out = F.scaled_dot_product_attention(
-            q, k, v, is_causal=True, dropout_p=self.dropout,
-            training=self.training)
-        out = reshape(out, [B, S, H])
-        out = self.out_proj(out)
+        with _scope("attention.core"):
+            out = F.scaled_dot_product_attention(
+                q, k, v, is_causal=True, dropout_p=self.dropout,
+                training=self.training)
+            out = reshape(out, [B, S, H])
+        with _scope("attention.out"):
+            out = self.out_proj(out)
         if cache is not None:
             return out, cache
         return out
@@ -164,8 +168,9 @@ def _cached_attention(out_proj, q, k, v, cache, pos, B, S, H,
             return buf.at[jnp.arange(B)[:, None], idx].set(new)
         return jax.lax.dynamic_update_slice(
             buf, new, (0, p.astype(jnp.int32), 0, 0))
-    k_buf = call_op(write, k_buf, k, pos)
-    v_buf = call_op(write, v_buf, v, pos)
+    with _scope("kv.scatter"):
+        k_buf = call_op(write, k_buf, k, pos)
+        v_buf = call_op(write, v_buf, v, pos)
 
     def mask_fn(p, *extra):
         qpos = _decode_position_ids(p, S)            # (S,) or (B, S)
@@ -176,11 +181,15 @@ def _cached_attention(out_proj, q, k, v, cache, pos, B, S, H,
         if extra:
             m = m + extra[0].astype(m.dtype)[:, None, None, :]
         return m
-    mask = call_op(mask_fn, pos) if attn_mask is None else \
-        call_op(mask_fn, pos, attn_mask)
-    out = F.scaled_dot_product_attention(q, k_buf, v_buf, attn_mask=mask,
-                                         is_causal=False, training=False)
-    out = reshape(out, [B, S, H])
+    with _scope("attention.core"):
+        mask = call_op(mask_fn, pos) if attn_mask is None else \
+            call_op(mask_fn, pos, attn_mask)
+        out = F.scaled_dot_product_attention(
+            q, k_buf, v_buf, attn_mask=mask, is_causal=False,
+            training=False)
+        out = reshape(out, [B, S, H])
+    with _scope("attention.out"):
+        out = out_proj(out)
     if paged:
         if cache.k_scales is None:
             kp, vp = call_op(_kvc.scatter_pages, cache.k_pages,
@@ -192,16 +201,22 @@ def _cached_attention(out_proj, q, k, v, cache, pos, B, S, H,
                 cache.k_scales, cache.v_scales, k, v, cache.table, pos)
             new_cache = cache._replace(k_pages=kp, v_pages=vp,
                                        k_scales=ks, v_scales=vs)
-        return out_proj(out), new_cache
-    return out_proj(out), (k_buf, v_buf)
+        return out, new_cache
+    return out, (k_buf, v_buf)
 
 
 def _cached_block(ln1, attn, ln2, ffn, x, cache, pos, attn_mask=None):
     """One decode step of a pre-LN block: cached attention + FFN with
     residuals — shared by the GPT/GPT-MoE/LLaMA decoder layers."""
-    a, cache = attn(ln1(x), cache=cache, pos=pos, attn_mask=attn_mask)
-    x = x + a
-    x = x + ffn(ln2(x))
+    with _scope("norm"):
+        h = ln1(x)
+    a, cache = attn(h, cache=cache, pos=pos, attn_mask=attn_mask)
+    with _scope("attention.out"):
+        x = x + a
+    with _scope("norm"):
+        h = ln2(x)
+    with _scope("mlp"):
+        x = x + ffn(h)
     return x, cache
 
 
@@ -212,7 +227,8 @@ def _cached_layers(layers, caches, pos, x, final_norm, attn_mask=None):
     for blk, cache in zip(layers, caches):
         x, cache = blk(x, cache=cache, pos=pos, attn_mask=attn_mask)
         new_caches.append(cache)
-    return final_norm(x), new_caches
+    with _scope("norm"):
+        return final_norm(x), new_caches
 
 
 class GPTMLP(nn.Layer):
@@ -227,7 +243,8 @@ class GPTMLP(nn.Layer):
             self.down = nn.Linear(I, H)
 
     def forward(self, x):
-        return self.down(F.gelu(self.up(x), approximate=True))
+        with _scope("mlp"):
+            return self.down(F.gelu(self.up(x), approximate=True))
 
 
 class GPTDecoderLayer(nn.Layer):
@@ -246,8 +263,17 @@ class GPTDecoderLayer(nn.Layer):
         if pos is not None:
             return _cached_block(self.ln1, self.attn, self.ln2, self.mlp,
                                  x, cache, pos, attn_mask=attn_mask)
-        x = x + self.dropout(self.attn(self.ln1(x)))
-        x = x + self.dropout(self.mlp(self.ln2(x)))
+        # the residual adds ride the scope of the branch they close, so
+        # that a matmul+add fusion has one name whichever is its root
+        with _scope("norm"):
+            h = self.ln1(x)
+        a = self.attn(h)
+        with _scope("attention.out"):
+            x = x + self.dropout(a)
+        with _scope("norm"):
+            h = self.ln2(x)
+        with _scope("mlp"):
+            x = x + self.dropout(self.mlp(h))
         return x
 
 
@@ -269,8 +295,9 @@ class GPTEmbeddings(nn.Layer):
         if position_ids is None:
             S = input_ids.shape[1]
             position_ids = arange(S, dtype="int64")
-        return self.dropout(self.word_embeddings(input_ids) +
-                            self.position_embeddings(position_ids))
+        with _scope("embed"):
+            return self.dropout(self.word_embeddings(input_ids) +
+                                self.position_embeddings(position_ids))
 
 
 class GPTModel(nn.Layer):
@@ -299,7 +326,8 @@ class GPTModel(nn.Layer):
                 x = _remat_block(blk, x, self.config.remat_policy)
             else:
                 x = blk(x)
-        return self.final_norm(x)
+        with _scope("norm"):
+            return self.final_norm(x)
 
 
 def _remat_policy(name):
@@ -380,11 +408,12 @@ class GPTForPretraining(nn.Layer, GenerationMixin):
         # transposed QuantizedWeight: the head then dispatches through
         # the kernel registry (closure capture, like F.linear's branch)
         wv = getattr(w, "_value", None)
-        if type(wv).__name__ == "QuantizedWeight":
-            from ..ops.quant_dispatch import quant_matmul
-            return call_op(lambda h: quant_matmul(h, wv,
-                                                  out_dtype=h.dtype), x)
-        return call_op(lambda h, t: h @ t.T, x, w)
+        with _scope("lm_head"):
+            if type(wv).__name__ == "QuantizedWeight":
+                from ..ops.quant_dispatch import quant_matmul
+                return call_op(lambda h: quant_matmul(h, wv,
+                                                      out_dtype=h.dtype), x)
+            return call_op(lambda h, t: h @ t.T, x, w)
 
     def forward(self, input_ids, position_ids=None, caches=None, pos=None,
                 attn_mask=None):
@@ -417,7 +446,8 @@ class GPTPretrainingCriterion(nn.Layer):
         from ..tensor.creation import full
         from ..tensor.manipulation import concat, reshape
         B = labels.shape[0]
-        tail = full([B, 1], -100, dtype=str(labels.dtype))
-        lb = concat([labels[:, 1:], tail], axis=1)
-        return F.cross_entropy(reshape(logits, [-1, V]),
-                               reshape(lb, [-1]))
+        with _scope("xent"):
+            tail = full([B, 1], -100, dtype=str(labels.dtype))
+            lb = concat([labels[:, 1:], tail], axis=1)
+            return F.cross_entropy(reshape(logits, [-1, V]),
+                                   reshape(lb, [-1]))

@@ -74,7 +74,7 @@ kreg.register("attention", "pallas", _fa.flash_attention_fwd,
 kreg.register("attention", "xla", _xla_attention, platforms=("*",))
 
 # standalone (eager) flash dispatches are compilestats-tracked under the
-# kernel.* surfaces so `report --roofline` attributes per-kernel
+# kernel.* surfaces so `roofline_from_stats` attributes per-kernel
 # FLOPs/bytes; traced calls inline into the caller's surface
 _flash_fwd = kreg.TrackedKernel(_fa.flash_attention_fwd,
                                 kreg.FLASH_FWD_SURFACE)

@@ -18,14 +18,16 @@ fourth piece that makes them ONE picture:
 - :mod:`.timeline` — the merged chrome trace overlaying metric samples
   and guardian events onto the profiler's host spans on one clock;
 - :mod:`.report` — ``python -m paddle_tpu.observability report``
-  renders a run summary from the sinks (``--roofline`` joins compile
-  telemetry with measured latency; ``--requests`` summarizes the
-  per-request lanes);
+  renders a run summary from the sinks (``--device`` gives device
+  time by named scope from a profiler trace; ``--requests`` summarizes
+  the per-request lanes);
 - :mod:`.compilestats` — compile telemetry per jit surface (analytical
   FLOPs/bytes/footprint from the lowering, compile counts + wall, the
   ``compile_retrace`` guardian sentinel on budget overrun);
-- :mod:`.tracing` — request-scoped serving traces booked at the
-  engine's existing chunk-boundary sync.
+- :mod:`.tracing` — the span ring: request-scoped serving traces
+  booked at the engine's existing chunk-boundary sync, the program
+  spans of ``Model.fit`` and ``ServingEngine.step`` (``region``), and
+  the vocabulary of named scopes in the device programs (``SCOPES``).
 
 THE design constraint (machine-checked: this package sits in
 ``analysis.allowlist.MONITORED_MODULES``, and the instrumented call

@@ -31,6 +31,10 @@ METRICS = {
         "type": _H, "labels": (),
         "help": "wall time of one train step incl. the per-step host "
                 "sync (loss readback)"},
+    "pt_train_host_gap_ms": {
+        "type": _H, "labels": (),
+        "help": "host share of a compiled fit step: end of fit.readback "
+                "(the loss read) to end of the next step's fit.dispatch"},
     "pt_train_tokens_total": {
         "type": _C, "labels": (),
         "help": "input elements trained on (batch x seq of the first "
@@ -50,6 +54,11 @@ METRICS = {
     "pt_serving_queue_wait_ms": {
         "type": _H, "labels": (),
         "help": "submit -> slot admission wait"},
+    "pt_serving_host_gap_ms": {
+        "type": _H, "labels": (),
+        "help": "host share of an engine cycle: end of serving.sync to "
+                "end of the next serving.decode_chunk dispatch, while "
+                "requests stay in flight"},
     "pt_serving_slot_occupancy": {
         "type": _G, "labels": (),
         "help": "in-flight slots after the latest admit/release"},

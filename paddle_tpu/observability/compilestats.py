@@ -20,7 +20,7 @@ per request).  This module closes that gap:
   lowering pipeline, bitwise-identical outputs;
 - every record lands in the ``pt_compile_*`` metrics (labels:
   ``surface``) and in a module registry :func:`snapshot` the roofline
-  view joins against measured latency (``report --roofline``,
+  arithmetic joins against measured latency (``roofline_from_stats``,
   ``telemetry/roofline.json``);
 - the **retrace sentinel**: each wrapper declares a compile *budget* —
   the number of distinct signatures the surface legitimately needs in
@@ -40,13 +40,18 @@ The grad_comm reducer closures have no executable of their own — they
 are traced *into* the ``hapi.train_step_comm`` stepper, so their cost
 shows up in that surface's row.
 """
+import json
+import os
+import re
 import threading
 import time
+import weakref
 
 from . import metrics as _metrics
 
 __all__ = ["wrap", "CompiledSurface", "signature", "signature_diff",
-           "snapshot", "reset", "surfaces", "retrace_total"]
+           "snapshot", "reset", "surfaces", "retrace_total",
+           "hlo_op_names", "op_names", "write_op_names", "OP_NAMES_FILE"]
 
 
 # -- shape signatures -------------------------------------------------------
@@ -201,6 +206,73 @@ def _count_retrace(surface):
         _metrics.inc("pt_compile_retraces_total", surface=surface)
 
 
+# -- op_name maps for the device view ---------------------------------------
+#
+# libtpu 0.0.34 names a device event by its HLO instruction text and puts
+# no ``op_name`` on it, so ``report --device`` joins the event's
+# instruction name and program to the ``metadata={op_name=...}`` of the
+# executables the wrappers below hold.  Nothing here runs unless asked:
+# ``as_text()`` of a whole train step is megabytes.
+
+OP_NAMES_FILE = "op_names.json"     # sidecar beside a trace's plugins/
+_WRAPPERS = weakref.WeakSet()       # live CompiledSurface objects
+_HLO_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def hlo_op_names(text):
+    """(module name, {instruction name: op_name}) of one executable's
+    HLO text.  Instruction names are unique in a module, so one flat map
+    serves the entry computation, loop bodies and fused computations."""
+    module, names = None, {}
+    for line in text.splitlines():
+        if module is None:
+            m = _HLO_MODULE.match(line)
+            if m:
+                module = m.group(1)
+            continue
+        m = _HLO_INSTR.match(line)
+        if m:
+            op = _HLO_OP_NAME.search(line)
+            if op:
+                names[m.group(1)] = op.group(1)
+    return module, names
+
+
+def op_names():
+    """{program name: {instruction name: op_name}} over every executable
+    the live wrappers hold.  Programs that share a name (the prefill
+    buckets are all ``jit_paged_prefill``) share one map; an instruction
+    they disagree on maps to None and the device view books it under its
+    HLO name."""
+    out = {}
+    for w in list(_WRAPPERS):
+        for entry in list(w._cache.values()):
+            module, names = hlo_op_names(entry.as_text())
+            have = out.setdefault(module, {})
+            for instr, op in names.items():
+                if have.setdefault(instr, op) != op:
+                    have[instr] = None
+    return out
+
+
+def write_op_names(trace_dir):
+    """Write :func:`op_names` beside a profiler trace
+    (``<trace_dir>/op_names.json``) for ``report --device`` to join;
+    returns the path, or None where no wrapper holds an executable."""
+    table = op_names()
+    if not table:
+        return None
+    os.makedirs(trace_dir, exist_ok=True)
+    path = os.path.join(trace_dir, OP_NAMES_FILE)
+    tmp = path + ".tmp"
+    with open(tmp, "w", encoding="utf-8") as f:
+        json.dump(table, f)
+    os.replace(tmp, path)
+    return path
+
+
 # -- the wrapper ------------------------------------------------------------
 
 class CompiledSurface:
@@ -221,6 +293,7 @@ class CompiledSurface:
         self._cache = {}       # sig -> AOT compiled executable
         self._last_sig = None
         self._lock = threading.Lock()
+        _WRAPPERS.add(self)
 
     @property
     def compiles(self):

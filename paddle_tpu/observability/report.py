@@ -6,43 +6,44 @@ human-readable run summary: counters and gauges grouped by subsystem,
 histograms with count / mean / estimated p50/p90/p99 (linear
 interpolation inside the winning bucket), trace-event totals.
 
-Two focused subviews (ISSUE 10):
+Focused subviews:
 
-- ``report --roofline --prom <file>`` — join the compile-telemetry
-  analytical costs (``pt_compile_flops`` / ``pt_compile_bytes_accessed``
-  per surface) with measured step latency and the grad_comm wire-bytes
-  gauge into a per-surface roofline table: arithmetic intensity, the
-  compute/memory roofline time at the given ``--peak-flops`` /
-  ``--hbm-bw``, which roof binds, and — where a measured latency
-  exists — the step-time attribution across compute / memory /
-  dispatch+other (the artifact the MFU-plateau roadmap item asks for;
-  bench runs commit it as ``telemetry/roofline.json``);
+- ``report --device <trace dir>`` — device time from a jax profiler
+  trace (``.xplane.pb``), by the named scope of the program
+  (``tracing.SCOPES``), by compiled program, and the device's idle gaps
+  by the program span (``fit.*``, ``serving.*``) the host was in; see
+  :func:`device_view`;
 - ``report --requests --trace <file>`` — fold the per-request lanes of
   a merged chrome trace back into request summaries: TTFT/TPOT
   percentiles plus the mean per-phase breakdown of the slowest-TTFT
   decile (where the tail's time went).
 
 Both support ``--json``.  The parsers are deliberately self-contained
-(stdlib only): the report must run against files produced by an earlier
-process, a different machine, or a BENCH_* artifact — never against
-live registry state.
+(stdlib only, ``--device`` aside, which reads the trace through
+``jax.profiler.ProfileData``): the report must run against files
+produced by an earlier process, a different machine, or a BENCH_*
+artifact — never against live registry state.
 """
 import argparse
+import bisect
+import glob
 import json
 import math
 import os
+import re
 import sys
 
 from ..device import chip as _chip
 
 __all__ = ["parse_prometheus", "parse_jsonl", "render_report",
            "roofline_from_stats", "compile_stats_from_prom",
-           "roofline_view", "requests_view", "request_rows_from_trace",
+           "load_device_trace", "device_view", "render_device",
+           "scope_of", "requests_view", "request_rows_from_trace",
            "dropped_spans_from_trace", "memory_view", "main"]
 
-# default roofs for the OFFLINE report (sink files carry no device_kind):
-# the v5e row of the one peaks table; a live caller passes the roofs of
-# the device it measured on (device.chip.peaks())
+# default roofs where a caller of roofline_from_stats passes none: the
+# v5e row of the one peaks table; a live caller passes the roofs of the
+# device it measured on (device.chip.peaks())
 DEFAULT_PEAK_FLOPS = _chip.peaks(_chip.V5E).bf16_flops
 DEFAULT_HBM_BW = _chip.peaks(_chip.V5E).hbm_bytes_per_s
 
@@ -260,7 +261,14 @@ def render_report(prom=None, jsonl=None, trace=None):
     return "\n".join(lines)
 
 
-# -- roofline view ---------------------------------------------------------
+# -- roofline arithmetic -----------------------------------------------------
+#
+# Still called by ``observability/doctor.py`` (its compute/memory/dispatch
+# attribution rows, and through ``evidence_from_sinks`` the two prom
+# readers below) and by ``bench.py`` ``_roofline_snapshot``; the
+# ``report --roofline`` view that rendered it is gone: it divided XLA's
+# ``cost_analysis`` by a host-timed dispatch.  ROADMAP queue 3 items 1
+# and 3 take the callers, and these functions with them.
 
 def roofline_from_stats(stats, measured_ms=None, peak_flops=None,
                         hbm_bw=None, wire_bytes=None):
@@ -402,15 +410,6 @@ def measured_from_prom(metrics):
     return out
 
 
-def roofline_view(prom, peak_flops=None, hbm_bw=None):
-    """Build the roofline table from one prom exposition file."""
-    metrics = parse_prometheus(prom)
-    stats = compile_stats_from_prom(metrics)
-    wire = _series_value(metrics, "pt_collective_wire_bytes_per_step")
-    return roofline_from_stats(stats, measured_from_prom(metrics),
-                               peak_flops, hbm_bw, wire_bytes=wire)
-
-
 def _fmt_num(v):
     if v is None:
         return "-"
@@ -423,39 +422,266 @@ def _fmt_num(v):
     return f"{v:.3g}E"
 
 
-def render_roofline(table):
-    lines = ["== roofline / MFU attribution ==",
-             f"peak_flops={_fmt_num(table['peak_flops'])}  "
-             f"hbm_bw={_fmt_num(table['hbm_bw_bytes_per_s'])}B/s"
-             + (f"  wire_bytes/step="
-                f"{_fmt_num(table['wire_bytes_per_step'])}"
-                if table.get("wire_bytes_per_step") else "")]
-    hdr = (f"{'surface':<28} {'flops':>8} {'bytes':>8} {'int.':>7} "
-           f"{'bound':>7} {'roof_ms':>9} {'meas_ms':>9} {'mfu':>6}  "
-           "attribution c/m/d")
-    lines.append(hdr)
-    for r in table["rows"]:
-        att = r["attribution"]
-        if att:
-            att_s = (f"{att['compute_frac']:.0%}/"
-                     f"{att['memory_frac']:.0%}/"
-                     f"{att['dispatch_other_frac']:.0%}")
+# -- device view -------------------------------------------------------------
+#
+# Device time by the program's named scopes, from a jax profiler trace.
+
+_DEVICE_PLANE = re.compile(r"^/device:(?:TPU|GPU):(\d+)$")
+_OPS_LINE, _MODULES_LINE = "XLA Ops", "XLA Modules"
+_OP_NAME_STATS = ("tf_op", "op_name")
+_NUMBER = re.compile(r"(\.\d+)+$")
+_MODULE_ID = re.compile(r"\(\d+\)$")
+_SCOPE_SEGMENT = re.compile(r"^(?:\w+\()*([\w.]+?)\)*$")
+# a gap this short between two operations of one program is the device's
+# own launch overhead, not the host's doing
+SHORT_GAP_NS = 10_000
+SHORT_GAPS = "between_ops_under_10us"
+NO_SPAN = "no_span"
+
+
+def find_xplane(trace_dir):
+    """The newest ``.xplane.pb`` under ``trace_dir`` (as
+    ``jax.profiler.start_trace`` lays it out), or None."""
+    files = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    return files[-1] if files else None
+
+
+def load_device_trace(path):
+    """Read an ``.xplane.pb`` with ``jax.profiler.ProfileData`` into
+    plain lists: ``{"devices": {index: {"ops": [...], "modules": [...]}},
+    "spans": [...]}``.  A device operation is ``(name, start_ns, end_ns,
+    op_name or None)``, a program run (the ``XLA Modules`` line) and a
+    program span (a host event named ``fit``, ``fit.*`` or ``serving.*``:
+    ``tracing.region`` mirrors them into the trace, on the device's
+    clock) are ``(name, start_ns, end_ns)``."""
+    from jax.profiler import ProfileData
+    from . import tracing as _tracing
+    data = ProfileData.from_file(path)
+    devices, spans = {}, []
+    for plane in data.planes:
+        m = _DEVICE_PLANE.match(plane.name)
+        if m:
+            dev = devices.setdefault(int(m.group(1)),
+                                     {"ops": [], "modules": []})
+            for line in plane.lines:
+                if line.name == _MODULES_LINE:
+                    dev["modules"].extend(
+                        (e.name, e.start_ns, e.start_ns + e.duration_ns)
+                        for e in line.events)
+                elif line.name == _OPS_LINE:
+                    events = list(line.events)
+                    # reading every event's stats is the slow part: look
+                    # at the first to learn whether this runtime puts
+                    # the op_name there at all
+                    stat = next((k for k, _ in events[0].stats
+                                 if k in _OP_NAME_STATS), None) \
+                        if events else None
+                    for e in events:
+                        dev["ops"].append((
+                            e.name, e.start_ns, e.start_ns + e.duration_ns,
+                            dict(e.stats).get(stat) if stat else None))
+        elif plane.name.startswith("/host:"):
+            for line in plane.lines:
+                spans.extend(
+                    (e.name, e.start_ns, e.start_ns + e.duration_ns)
+                    for e in line.events
+                    if _tracing.is_program_span(e.name))
+    return {"devices": devices, "spans": spans}
+
+
+def scope_of(op_name):
+    """(scope, "fwd" | "bwd") of an HLO ``op_name``: the innermost
+    segment of the name stack that is a name of ``tracing.SCOPES``,
+    backward where it or a segment above it sits under ``transpose(``
+    (what ``value_and_grad`` makes of ``jvp(<scope>)``; a scope nested in
+    another keeps its bare name below the outer's
+    ``transpose(jvp(<outer>))``); None outside every scope."""
+    from .tracing import SCOPES
+    segments = (op_name or "").split("/")
+    for i in range(len(segments) - 1, -1, -1):
+        m = _SCOPE_SEGMENT.match(segments[i])
+        if m and m.group(1) in SCOPES:
+            back = any(seg.startswith("transpose(")
+                       for seg in segments[:i + 1])
+            return m.group(1), "bwd" if back else "fwd"
+    return None
+
+
+def _instruction(event_name):
+    """``fusion.12`` of ``%fusion.12 = f32[..] fusion(..)``."""
+    return event_name.split(" = ", 1)[0].strip().lstrip("%")
+
+
+def _self_times(events):
+    """(event, self ns) per event of one line: its duration less that of
+    the events nested inside it (a ``while`` holds its body's)."""
+    out, stack = [], []          # stack of [event, self_ns]
+    for ev in sorted(events, key=lambda ev: (ev[1], -ev[2])):
+        while stack and stack[-1][0][2] <= ev[1]:
+            out.append(tuple(stack.pop()))
+        if stack:
+            stack[-1][1] -= min(ev[2], stack[-1][0][2]) - ev[1]
+        stack.append([ev, ev[2] - ev[1]])
+    out.extend(tuple(x) for x in stack)
+    return out
+
+
+def _union(intervals):
+    merged = []
+    for s, e in sorted(intervals):
+        if merged and s <= merged[-1][1]:
+            merged[-1][1] = max(merged[-1][1], e)
         else:
-            reason = r.get("attribution_reason")
-            att_s = f"n/a ({reason})" if reason else "-"
-        mfu_s = f"{r['mfu']:.3f}" if r["mfu"] is not None else "-"
+            merged.append([s, e])
+    return merged
+
+
+def device_view(loaded, op_names=None):
+    """The reduction, on what :func:`load_device_trace` gives (or a
+    hand-made list of the same shape).  Device 0 is the lowest device
+    index; the window runs from its first operation to its last.
+
+    (a) ``by_scope``: device SELF time by named scope, forward and
+    backward apart.  The ``op_name`` comes from the event where the
+    runtime puts it there (a ``tf_op`` / ``op_name`` stat); libtpu 0.0.34
+    does not, so the event's instruction name (number kept) and its
+    program (the ``XLA Modules`` run that covers it) are joined to
+    ``op_names`` — ``compilestats.op_names()``, which the profiler
+    writes beside the trace as ``op_names.json``.  A fusion has one
+    ``op_name``, the one XLA leaves on the fusion instruction: its
+    root's, or, where the root is a compiler-made convert or tuple with
+    none, that of the operation the fusion was built around (the matmul
+    of a ``convolution_*_fusion``); a fusion that spans two scopes is
+    booked whole to that one.  An operation outside every scope is
+    booked under its HLO name (number dropped), so the rows always sum
+    to the busy time.  ``cross`` splits every row by HLO name and by
+    program (the decode chunk's rows apart from the prefill's).
+    (b) ``by_program``: seconds and runs per compiled program.
+    (c) ``idle_gaps``: device 0's idle time by the innermost program
+    span covering each gap's middle."""
+    if not loaded["devices"]:
+        raise ValueError("the trace holds no device plane")
+    dev0 = loaded["devices"][min(loaded["devices"])]
+    ops = dev0["ops"]
+    if not ops:
+        raise ValueError("device 0 ran no operation in the trace")
+    op_names = op_names or {}
+    t0, t1 = min(e[1] for e in ops), max(e[2] for e in ops)
+    runs = sorted((s, e, _MODULE_ID.sub("", name))
+                  for name, s, e in dev0["modules"])
+    starts = [r[0] for r in runs]
+
+    def program_at(t):
+        i = bisect.bisect_right(starts, t) - 1
+        return runs[i][2] if i >= 0 and t < runs[i][1] else None
+
+    rows, cross, programs = {}, {}, {}
+    for (name, s, e, stat), self_ns in _self_times(ops):
+        instr, program = _instruction(name), program_at(s)
+        op = stat or op_names.get(program, {}).get(instr)
+        hlo = _NUMBER.sub("", instr)
+        key = scope_of(op) or (hlo, None)
+        rows[key] = rows.get(key, 0) + self_ns
+        at = key + (hlo, program)
+        cross[at] = cross.get(at, 0) + self_ns
+    for s, e, name in runs:
+        sec, n = programs.get(name, (0, 0))
+        programs[name] = (sec + e - s, n + 1)
+    merged = _union((e[1], e[2]) for e in ops)
+    busy_ns = sum(e - s for s, e in merged)
+    spans = sorted((e - s, name, s, e) for name, s, e in loaded["spans"])
+    gaps = {}
+    for (_, gs), (ge, _) in zip(merged, merged[1:]):
+        mid = (gs + ge) / 2
+        owner = SHORT_GAPS if ge - gs < SHORT_GAP_NS else next(
+            (name for _, name, s, e in spans if s <= mid <= e), NO_SPAN)
+        gaps[owner] = gaps.get(owner, 0) + ge - gs
+    outside = sum(ns for (_, way), ns in rows.items() if way is None)
+
+    def table(d, make):
+        return [make(k, v / 1e9) for k, v in
+                sorted(d.items(), key=lambda kv: -kv[1])]
+    return {
+        "window_s": (t1 - t0) / 1e9,
+        "busy_s": busy_ns / 1e9,
+        "idle_s": (t1 - t0 - busy_ns) / 1e9,
+        "outside_scope_s": outside / 1e9,
+        "by_scope": table(rows, lambda k, sec: {
+            "scope": k[0], "direction": k[1], "seconds": sec}),
+        "cross": table(cross, lambda k, sec: {
+            "scope": k[0], "direction": k[1], "hlo": k[2],
+            "program": k[3], "seconds": sec}),
+        "by_program": table(
+            {k: v[0] for k, v in programs.items()}, lambda k, sec: {
+                "program": k, "seconds": sec, "runs": programs[k][1]}),
+        "idle_gaps": table(gaps, lambda k, sec: {
+            "span": k, "seconds": sec}),
+    }
+
+
+def render_device(view, top=3):
+    busy = view["busy_s"]
+    lines = ["== device time by scope ==",
+             f"window={view['window_s']:.4f}s  busy={busy:.4f}s  "
+             f"idle={view['idle_s']:.4f}s  outside every scope="
+             f"{view['outside_scope_s']:.4f}s "
+             f"({100 * view['outside_scope_s'] / busy:.1f}% of busy)",
+             f"{'scope':<34} {'seconds':>10} {'share':>7}  "
+             "largest HLO operations"]
+    for r in view["by_scope"]:
+        inside = {}
+        for c in view["cross"]:
+            if (c["scope"], c["direction"]) == (r["scope"], r["direction"]):
+                inside[c["hlo"]] = inside.get(c["hlo"], 0) + c["seconds"]
+        inside = sorted(inside.items(), key=lambda kv: -kv[1])[:top]
+        name = r["scope"] if r["direction"] != "bwd" \
+            else r["scope"] + " (bwd)"
+        if r["direction"] is None:
+            name = "hlo:" + name
         lines.append(
-            f"{r['surface']:<28} {_fmt_num(r['flops']):>8} "
-            f"{_fmt_num(r['bytes_accessed']):>8} "
-            f"{_fmt_num(r['intensity_flop_per_byte']):>7} "
-            f"{(r['bound'] or '-'):>7} "
-            f"{_fmt_num(r['roofline_ms']):>9} "
-            f"{_fmt_num(r['measured_ms']):>9} "
-            f"{mfu_s:>6}  {att_s}")
-    if not table["rows"]:
-        lines.append("(no pt_compile_* series in this exposition — run "
-                     "with compile telemetry wired, e.g. bench.py)")
+            f"{name:<34} {r['seconds']:>10.4f} "
+            f"{100 * r['seconds'] / busy:>6.1f}%  "
+            + ", ".join(f"{hlo} {sec:.4f}" for hlo, sec in inside
+                        if r["direction"] is not None))
+    lines.append("== by program ==")
+    for r in view["by_program"]:
+        lines.append(f"{r['program']:<34} {r['seconds']:>10.4f} "
+                     f"runs={r['runs']}")
+    lines.append("== idle gaps by program span ==")
+    for r in view["idle_gaps"]:
+        lines.append(f"{r['span']:<34} {r['seconds']:>10.4f}")
     return "\n".join(lines)
+
+
+def run_device(trace_dir, as_json):
+    """``report --device``: print the view and return the exit code;
+    non-zero where there is no trace, or the trace has no device plane
+    (a CPU trace: its program spans still load, and the line says how
+    many)."""
+    path = find_xplane(trace_dir)
+    if path is None:
+        print(f"no data: device — no .xplane.pb under {trace_dir}",
+              file=sys.stderr)
+        return 1
+    from .compilestats import OP_NAMES_FILE
+    loaded = load_device_trace(path)
+    names = {}
+    sidecar = os.path.join(trace_dir, OP_NAMES_FILE)
+    if os.path.exists(sidecar):
+        with open(sidecar, encoding="utf-8") as f:
+            names = json.load(f)
+    try:
+        view = device_view(loaded, names)
+    except ValueError as e:
+        print(f"no data: device — {e} ({path}; "
+              f"{len(loaded['spans'])} program spans loaded)",
+              file=sys.stderr)
+        return 1
+    view["trace"] = path
+    view["op_names"] = sidecar if names else None
+    print(json.dumps(view, indent=1) if as_json else render_device(view))
+    return 0
 
 
 # -- requests view ---------------------------------------------------------
@@ -751,9 +977,11 @@ def main(argv=None):
                     help="JSONL metrics log (PADDLE_METRICS_LOG format)")
     rp.add_argument("--trace", default=None,
                     help="merged chrome-trace JSON (timeline.py)")
-    rp.add_argument("--roofline", action="store_true",
-                    help="per-surface roofline/MFU-attribution table "
-                         "from the --prom file's pt_compile_* series")
+    rp.add_argument("--device", default=None, metavar="TRACE_DIR",
+                    help="device time by named scope, by program, and "
+                         "idle gaps by program span, from the newest "
+                         ".xplane.pb under a jax profiler trace dir "
+                         "(joined to its op_names.json)")
     rp.add_argument("--requests", action="store_true",
                     help="per-request TTFT/TPOT summary from the "
                          "--trace file's request lanes")
@@ -770,18 +998,11 @@ def main(argv=None):
                          "roofline.json (bench runs / "
                          "memory.write_memory_json)")
     rp.add_argument("--json", action="store_true", dest="as_json",
-                    help="emit the subview as JSON (with --roofline / "
-                         "--requests)")
+                    help="emit the subview as JSON (with --device / "
+                         "--requests / --memory)")
     rp.add_argument("--doctor", action="store_true", dest="doctor",
                     help="append the doctor's ranked probable-cause "
                          "diagnosis built from the same sinks")
-    rp.add_argument("--peak-flops", type=float,
-                    default=DEFAULT_PEAK_FLOPS,
-                    help="compute roof (FLOP/s) for --roofline "
-                         "(default: TPU v5e bf16 peak)")
-    rp.add_argument("--hbm-bw", type=float, default=DEFAULT_HBM_BW,
-                    help="memory roof (bytes/s) for --roofline "
-                         "(default: TPU v5e HBM)")
     args = ap.parse_args(argv)
     if args.cmd == "doctor":
         from . import doctor as _doctor
@@ -789,9 +1010,8 @@ def main(argv=None):
     if args.cmd != "report":
         ap.print_help()
         return 2
-    if args.roofline and not args.prom:
-        print("error: --roofline needs --prom", file=sys.stderr)
-        return 2
+    if args.device:
+        return run_device(args.device, args.as_json)
     if args.requests and not args.trace:
         print("error: --requests needs --trace", file=sys.stderr)
         return 2
@@ -807,28 +1027,13 @@ def main(argv=None):
               "--memory-json", file=sys.stderr)
         return 2
     try:
-        if args.roofline or args.requests or args.memory:
+        if args.requests or args.memory:
             # no-data discipline (ISSUE 13 satellite): a missing,
             # empty, or torn telemetry file prints ONE line and exits
             # 0 (`--json` emits {}) — a cron job or CI smoke over a
             # quiet run must not die on a traceback
             out = {}
             no_data = []
-            if args.roofline:
-                note = _sink_note(args.prom, "prom")
-                table = None
-                if note is None:
-                    table = roofline_view(args.prom, args.peak_flops,
-                                          args.hbm_bw)
-                    if not table["rows"]:
-                        note = f"no pt_compile_* series in {args.prom}"
-                        table = None
-                if table is None:
-                    no_data.append(f"no data: roofline — {note}")
-                elif args.as_json:
-                    out["roofline"] = table
-                else:
-                    print(render_roofline(table))
             if args.requests:
                 note = _sink_note(args.trace, "trace")
                 rows = None

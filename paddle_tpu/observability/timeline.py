@@ -22,7 +22,9 @@ Event mapping:
 - samples     -> ``"ph": "C"`` counters (one track per metric+labels)
 - request traces (``tracing.py``) -> one LANE per request (tid 100+,
   named by trace id): ``"X"`` spans for queue_wait/prefill/decode,
-  ``"i"`` instants for page evictions — already on the perf clock.
+  ``"i"`` instants for page evictions — already on the perf clock;
+- program spans of the same ring (``fit.*``, ``serving.*``) -> ``"X"``
+  events with ``"cat": "program"`` on tid 2, ``id``/``parent`` in args.
 """
 import json
 import os
@@ -36,6 +38,7 @@ __all__ = ["merged_trace_events", "export_chrome_trace"]
 PID = 0
 TID_SPANS = 0
 TID_GUARDIAN = 1
+TID_PROGRAM = 2         # the loops' own spans (fit.*, serving.*)
 TID_REQUESTS = 100      # first per-request lane
 
 # fallback (wall_ns, perf_ns) pair when no metric capture ran: minted
@@ -70,6 +73,8 @@ def merged_trace_events(include_profiler=True, include_guardian=True,
          "args": {"name": "host spans"}},
         {"name": "thread_name", "ph": "M", "pid": PID,
          "tid": TID_GUARDIAN, "args": {"name": "guardian events"}},
+        {"name": "thread_name", "ph": "M", "pid": PID,
+         "tid": TID_PROGRAM, "args": {"name": "program spans"}},
     ]
     if include_profiler:
         from ..profiler import _collect_events
@@ -96,6 +101,16 @@ def merged_trace_events(include_profiler=True, include_guardian=True,
                 "args": {"count": _tracing.dropped_spans()}})
         lanes = {}
         for s in _tracing.spans():
+            if _tracing.is_program_span(s["phase"]):
+                events.append({
+                    "name": s["phase"], "cat": "program", "ph": "X",
+                    "ts": s["start_ns"] / 1e3,
+                    "dur": (s["end_ns"] - s["start_ns"]) / 1e3,
+                    "pid": PID, "tid": TID_PROGRAM,
+                    "args": {"trace": s["trace"], "req_id": s["req_id"],
+                             "id": s["id"], "parent": s["parent"],
+                             **s["args"]}})
+                continue
             tid = lanes.get(s["trace"])
             if tid is None:
                 tid = lanes[s["trace"]] = TID_REQUESTS + len(lanes)
@@ -103,7 +118,8 @@ def merged_trace_events(include_profiler=True, include_guardian=True,
                     "name": "thread_name", "ph": "M", "pid": PID,
                     "tid": tid, "args": {"name": f"req {s['trace']}"}})
             args = {"trace": s["trace"], "req_id": s["req_id"],
-                    "phase": s["phase"], **s["args"]}
+                    "phase": s["phase"], "parent": s["parent"],
+                    **s["args"]}
             if s["end_ns"] > s["start_ns"]:
                 events.append({
                     "name": s["phase"], "cat": "request", "ph": "X",

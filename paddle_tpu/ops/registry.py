@@ -36,7 +36,7 @@ or attributed.  The registry centralizes the *policy*:
   per-process.
 - **roofline attribution** — kernels registered here are dispatched
   through :class:`TrackedKernel`, which wraps standalone (non-traced)
-  calls in ``observability.compilestats.wrap`` so ``report --roofline``
+  calls in ``observability.compilestats.wrap`` so ``roofline_from_stats``
   attributes per-kernel FLOPs / bytes / dispatch latency under the
   ``kernel.*`` surface names below.  Calls made *inside* an outer jit
   trace (the hapi train stepper) inline into the caller's surface and

@@ -197,6 +197,9 @@ class Profiler:
             except Exception:
                 pass
             self._running = False
+            # what `report --device <log_dir>` joins device events to
+            from ..observability import compilestats
+            compilestats.write_op_names(self._log_dir)
         if self._on_trace_ready is not None:
             self._on_trace_ready(self)
 
@@ -328,9 +331,9 @@ def load_profiler_result(path):
     round-trips names, categories and durations to µs precision on the
     same clock base).  Non-span phases — the instants and counter
     samples a merged ``observability.timeline`` trace adds — are
-    skipped, as are the per-request lanes (``"cat": "request"``, which
-    are serving-request spans, not host profiler spans), so a merged
-    trace loads as its host-span subset.  Returns
+    skipped, as are the span ring's lanes (``"cat": "request"`` and
+    ``"program"``: the ring's copies, not host profiler spans), so a
+    merged trace loads as its host-span subset.  Returns
     ``None`` when ``path`` does not exist (probe-friendly, the old stub
     behavior); raises ``ValueError`` on a file that is not a chrome
     trace (no ``traceEvents``)."""
@@ -346,7 +349,8 @@ def load_profiler_result(path):
             "(missing traceEvents)")
     events = []
     for rec in data["traceEvents"]:
-        if rec.get("ph") != "X" or rec.get("cat") == "request":
+        if rec.get("ph") != "X" or \
+                rec.get("cat") in ("request", "program"):
             continue
         start = int(round(rec.get("ts", 0) * 1e3))
         dur = int(round(rec.get("dur", 0) * 1e3))

@@ -17,6 +17,7 @@ if "host_platform_device_count" not in flags:
         flags + " --xla_force_host_platform_device_count=8").strip()
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
@@ -48,3 +49,14 @@ def pytest_configure(config):
         "markers", "multichip: multi-device mesh parity test (runs on "
         "the forced-8-virtual-device CPU mesh above; exercises "
         "grad_comm / hybrid DP wire patterns) — select with -m multichip")
+
+
+@pytest.fixture(autouse=True)
+def _observability_gate_restored():
+    """Put the one recording gate (``observability.enabled()``) back
+    after every test: a test that leaves it off would silence whatever
+    file the same xdist worker runs next."""
+    from paddle_tpu import observability as obs
+    was_on = obs.enabled()
+    yield
+    obs.enable(was_on)

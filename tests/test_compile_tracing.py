@@ -308,27 +308,25 @@ class TestRoofline:
         m = rows["s.memory"]
         assert m["bound"] == "memory" and m["attribution"] is None
 
-    def test_report_roofline_cli_from_prom(self, gpt, tmp_path):
+    def test_roofline_rows_from_a_prom_exposition(self, gpt, tmp_path):
+        """The readers the doctor feeds ``roofline_from_stats`` with:
+        compile telemetry and measured dispatch latency out of one
+        written exposition."""
         _run_engine(gpt)
         obs.observe("pt_compile_dispatch_ms", 5.0,
                     surface="serving.decode_chunk")
         prom = str(tmp_path / "t.prom")
         export.write_prometheus(prom)
-        out = subprocess.run(
-            [sys.executable, "-m", "paddle_tpu.observability", "report",
-             "--prom", prom, "--roofline", "--json",
-             "--peak-flops", "1e12", "--hbm-bw", "5e10"],
-            capture_output=True, text=True, cwd=REPO,
-            env={**os.environ, "JAX_PLATFORMS": "cpu"})
-        assert out.returncode == 0, out.stderr
-        table = json.loads(out.stdout)["roofline"]
+        metrics = report.parse_prometheus(prom)
+        table = report.roofline_from_stats(
+            report.compile_stats_from_prom(metrics),
+            report.measured_from_prom(metrics),
+            peak_flops=1e12, hbm_bw=5e10)
         rows = {r["surface"]: r for r in table["rows"]}
-        assert "serving.decode_chunk" in rows
         assert "serving.prefill" in rows
         dec = rows["serving.decode_chunk"]
         assert dec["measured_ms"] == pytest.approx(5.0)
         att = dec["attribution"]
-        assert att is not None
         assert 0 <= att["compute_frac"] <= 1
         assert att["dispatch_other_frac"] > 0   # tiny model: dispatch
 

@@ -57,12 +57,13 @@ def _clean():
     failpoints.clear()
     preemption.reset()
     guardian.clear_events()
+    was_on = obs.enabled()
     obs.enable(True)
     obs.get_registry().reset()
     yield
     failpoints.clear()
     preemption.reset()
-    obs.enable(False)
+    obs.enable(was_on)
 
 
 def _sharded_state(mesh):
