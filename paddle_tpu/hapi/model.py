@@ -27,9 +27,11 @@ from ..framework.core import Tensor
 from ..framework import autograd as _ag
 from ..framework import guardian as _guardian
 from ..framework import preemption as _preemption
-from ..framework.random import rng_scope, next_key, set_rng_state
+from ..framework.random import (rng_scope, next_key, get_rng_state,
+                                set_rng_state)
 from ..framework.io import save as _save, load as _load
 from ..metric import Metric
+from ..nn.layer.layers import mode_stamp
 from ..optimizer.lr import LRScheduler
 from ..optimizer.optimizer import apply_functional_with_clip
 from ..io import DataLoader, Dataset, DistributedBatchSampler
@@ -63,6 +65,12 @@ def _as_list(x):
     if x is None:
         return []
     return list(x) if isinstance(x, (list, tuple)) else [x]
+
+
+def _total(loss):
+    """The sum of a loss function's results where it returns several."""
+    return sum(loss[1:], loss[0]) if isinstance(loss, (list, tuple)) \
+        else loss
 
 
 def _fp8_apply(pv, idx, amax):
@@ -117,7 +125,8 @@ class _CompiledStepper:
         # kept) — toggled by Model.fit, which clears the step caches
         self.guard_numerics = False
         self.last_ok = None
-        self._last_rng = None
+        self._last_rng = None    # the key chain the last step drew from
+        self._lr = (None, None)  # get_lr()'s last float, its device scalar
         # fp8 train pilot (enable_fp8): trace-time constant like
         # guard_numerics; fp8_state is the delayed-scaling amax vector,
         # one fp32 entry per Linear weight, donated through the step
@@ -150,6 +159,9 @@ class _CompiledStepper:
         self.buffers = [b for _, b in self.network.named_buffers()]
         self.t_idx = [i for i, p in enumerate(self.params)
                       if not p.stop_gradient]
+        trainable = set(self.t_idx)
+        self.f_idx = [i for i in range(len(self.params))
+                      if i not in trainable]
 
     def enable_fp8(self):
         """Turn on the fp8 train pilot: every Linear weight matmul in
@@ -195,6 +207,52 @@ class _CompiledStepper:
                 return fn(*args)
         return scoped
 
+    def _merged_params(self, train_vals, frozen_vals):
+        """The traced step's full parameter list from its trainable and
+        frozen halves; under AMP the trainable floats go in as bf16."""
+        pv = [None] * len(self.params)
+        cast = self._amp_cast(train_vals, jnp.floating, jnp.bfloat16)
+        for i, v in zip(self.t_idx + self.f_idx,
+                        list(cast) + list(frozen_vals)):
+            pv[i] = v
+        return pv
+
+    def _amp_cast(self, vals, kind, to):
+        """Under AMP, ``vals`` with every ``kind`` leaf cast to ``to``."""
+        if self.amp_level not in ("O1", "O2"):
+            return vals
+        with _scope("amp_cast"):
+            return [v.astype(to) if jnp.issubdtype(v.dtype, kind) else v
+                    for v in vals]
+
+    def _drawing(self, step, key_at):
+        """``step`` fed the global key chain where it takes a key: the
+        compiled step splits the chain itself — what ``next_key`` does
+        eagerly, so the stream is the same, without a launch of its own
+        — runs on the drawn half and returns the advanced chain last.
+        Not under a plan: a chain that came out of a mesh program would
+        commit every later eager draw of the process to that mesh."""
+        if self.plan is not None:
+            return step
+
+        def drawing(*args):
+            chain, key = jax.random.split(args[key_at])
+            return step(*args[:key_at], key, *args[key_at + 1:]) + (chain,)
+        drawing.__name__ = step.__name__   # the program's name stays
+        return drawing
+
+    def _take_key(self):
+        """The next step's key argument: the global chain, which the
+        step advances itself (``_drawing``); a drawn key under a plan."""
+        return get_rng_state()[0] if self.plan is None else next_key()
+
+    def _give_key(self, out):
+        """A step's outputs; the advanced chain goes to the global state."""
+        out = list(out)
+        if self.plan is None:
+            set_rng_state([out.pop()])
+        return out
+
     def _forward_pure(self, param_vals, buffer_vals, key, inputs, training):
         """Run network on traced values; returns (outs, new_buffer_vals)."""
         olds = [t._value for t in self.params + self.buffers]
@@ -225,18 +283,10 @@ class _CompiledStepper:
         with _ag.suspend_tape():
             outs = [Tensor(v) for v in out_vals]
             labels = [Tensor(v) for v in label_vals]
-            if callable(self.loss_fn):
-                loss = self.loss_fn(*(outs + labels)) \
-                    if not hasattr(self.loss_fn, "forward") \
-                    else self.loss_fn(*(outs + labels))
-            else:
+            if not callable(self.loss_fn):
                 raise TypeError("loss must be callable")
-        if isinstance(loss, (list, tuple)):
-            total = loss[0]
-            for l in loss[1:]:
-                total = total + l
-            loss = total
-        return loss._value
+            loss = self.loss_fn(*(outs + labels))
+        return _total(loss)._value
 
     def _use_grad_comm(self):
         """True when the step should use the explicit bucketed/quantized
@@ -283,7 +333,6 @@ class _CompiledStepper:
         from ..distributed.grad_comm import build_grad_reducer
         opt = self.optimizer
         t_idx = self.t_idx
-        amp = self.amp_level
         guard = self.guard_numerics
         pnames = [self.param_names[i] for i in t_idx]
         plan = self.plan
@@ -303,31 +352,12 @@ class _CompiledStepper:
             key = jax.random.fold_in(key, jax.lax.axis_index(axis))
 
             def loss_f(tv):
-                tv_map = dict(zip(t_idx, tv))
-                fi = iter(frozen_vals)
-                pv = []
-                with _scope("amp_cast"):
-                    for i in range(len(self.params)):
-                        if i in tv_map:
-                            v = tv_map[i]
-                            if amp in ("O1", "O2") and \
-                                    jnp.issubdtype(v.dtype, jnp.floating):
-                                v = v.astype(jnp.bfloat16)
-                            pv.append(v)
-                        else:
-                            pv.append(next(fi))
-                    ins = inputs
-                    if amp in ("O1", "O2"):
-                        ins = [v.astype(jnp.bfloat16)
-                               if jnp.issubdtype(v.dtype, jnp.floating)
-                               else v for v in inputs]
                 out_vals, new_buf = self._forward_pure(
-                    pv, buffer_vals, key, ins, training=True)
-                if amp in ("O1", "O2"):
-                    with _scope("amp_cast"):
-                        out_vals = [v.astype(jnp.float32)
-                                    if jnp.issubdtype(v.dtype, jnp.bfloat16)
-                                    else v for v in out_vals]
+                    self._merged_params(tv, frozen_vals), buffer_vals, key,
+                    self._amp_cast(inputs, jnp.floating, jnp.bfloat16),
+                    training=True)
+                out_vals = self._amp_cast(out_vals, jnp.bfloat16,
+                                          jnp.float32)
                 loss = self._loss_pure(out_vals, labels)
                 return loss, (out_vals, new_buf)
 
@@ -390,7 +420,6 @@ class _CompiledStepper:
             return self._build_train_comm(n_in, n_lab)
         opt = self.optimizer
         t_idx = self.t_idx
-        amp = self.amp_level
         guard = self.guard_numerics   # trace-time constant: zero cost off
         fp8 = self.fp8_matmul         # same: off costs nothing
         fp8_idx = self._fp8_idx
@@ -399,39 +428,19 @@ class _CompiledStepper:
         def step(train_vals, frozen_vals, buffer_vals, opt_state, lr, key,
                  inputs, labels, fp8_amax=None):
             def loss_f(tv):
-                # merge trainable into full param list
-                pv = []
-                tv_map = dict(zip(t_idx, tv))
-                fi = iter(frozen_vals)
-                with _scope("amp_cast"):
-                    for i in range(len(self.params)):
-                        if i in tv_map:
-                            v = tv_map[i]
-                            if amp in ("O1", "O2") and \
-                                    jnp.issubdtype(v.dtype, jnp.floating):
-                                v = v.astype(jnp.bfloat16)
-                            pv.append(v)
-                        else:
-                            pv.append(next(fi))
+                pv = self._merged_params(tv, frozen_vals)
                 new_amax = None
                 if fp8:
                     # fp8 pilot: STE fake-quant over the MERGED list
                     # (after any amp cast) so gradients flow straight
                     # through to the trainable values
                     pv, new_amax = _fp8_apply(pv, fp8_idx, fp8_amax)
-                ins = inputs
-                if amp in ("O1", "O2"):
-                    with _scope("amp_cast"):
-                        ins = [v.astype(jnp.bfloat16)
-                               if jnp.issubdtype(v.dtype, jnp.floating)
-                               else v for v in inputs]
                 out_vals, new_buf = self._forward_pure(
-                    pv, buffer_vals, key, ins, training=True)
-                if amp in ("O1", "O2"):
-                    with _scope("amp_cast"):
-                        out_vals = [v.astype(jnp.float32)
-                                    if jnp.issubdtype(v.dtype, jnp.bfloat16)
-                                    else v for v in out_vals]
+                    pv, buffer_vals, key,
+                    self._amp_cast(inputs, jnp.floating, jnp.bfloat16),
+                    training=True)
+                out_vals = self._amp_cast(out_vals, jnp.bfloat16,
+                                          jnp.float32)
                 loss = self._loss_pure(out_vals, labels)
                 return loss, (out_vals, new_buf, new_amax)
 
@@ -472,14 +481,14 @@ class _CompiledStepper:
                     out_vals
             return loss, new_train, new_buf, new_opt, out_vals
 
+        step = self._drawing(step, 5)
         if self.plan is None:
             return jax.jit(step,
                            donate_argnums=(0, 2, 3) + ((8,) if fp8
                                                        else ()))
         plan = self.plan
         t_sh = [self._param_shardings[i] for i in self.t_idx]
-        f_sh = [self._param_shardings[i] for i in range(len(self.params))
-                if i not in set(self.t_idx)]
+        f_sh = [self._param_shardings[i] for i in self.f_idx]
         b_sh = list(self._buffer_shardings)
         o_sh = self._opt_shardings_for(self.opt_state)
         rep = plan.replicated()
@@ -493,26 +502,12 @@ class _CompiledStepper:
     @jit_surface
     def _build_grad(self):
         """Gradient-only step (no optimizer apply) for accumulation."""
-        amp = self.amp_level
-        t_idx = self.t_idx
-
         def gstep(train_vals, frozen_vals, buffer_vals, key, inputs,
                   labels):
             def loss_f(tv):
-                tv_map = dict(zip(t_idx, tv))
-                fi = iter(frozen_vals)
-                pv = []
-                for i in range(len(self.params)):
-                    if i in tv_map:
-                        v = tv_map[i]
-                        if amp in ("O1", "O2") and \
-                                jnp.issubdtype(v.dtype, jnp.floating):
-                            v = v.astype(jnp.bfloat16)
-                        pv.append(v)
-                    else:
-                        pv.append(next(fi))
                 out_vals, new_buf = self._forward_pure(
-                    pv, buffer_vals, key, inputs, training=True)
+                    self._merged_params(tv, frozen_vals), buffer_vals, key,
+                    inputs, training=True)
                 loss = self._loss_pure(out_vals, labels)
                 return loss, (out_vals, new_buf)
             (loss, (out_vals, new_buf)), grads = jax.value_and_grad(
@@ -521,7 +516,8 @@ class _CompiledStepper:
         # donation-unsafe by design: train/frozen vals must stay live
         # for the later apply step, and the trip path keeps pre-batch
         # buffers when a poisoned microbatch is dropped
-        return jax.jit(self._under_plan(gstep))  # lint: allow(missing-donation)
+        return jax.jit(  # lint: allow(missing-donation)
+            self._under_plan(self._drawing(gstep, 3)))
 
     @jit_surface
     def _build_apply(self):
@@ -588,13 +584,14 @@ class _CompiledStepper:
                         f"divisible by the data-parallel world size "
                         f"{world}; pad or resize the batch")
         train_vals = [self.params[i]._value for i in self.t_idx]
-        frozen_vals = [p._value for i, p in enumerate(self.params)
-                       if i not in set(self.t_idx)]
+        frozen_vals = [self.params[i]._value for i in self.f_idx]
         buffer_vals = [b._value for b in self.buffers]
         self.ensure_opt_state()
-        lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
-        rng = next_key()
-        self._last_rng = rng     # guardian attribution replays this key
+        lr = self.optimizer.get_lr()
+        if lr != self._lr[0]:
+            self._lr = (lr, jnp.asarray(lr, jnp.float32))
+        lr = self._lr[1]
+        rng = self._last_rng = self._take_key()   # the guardian replays it
 
         accumulating = (not update) or self._accum_count > 0
         if accumulating and self.fp8_matmul:
@@ -614,17 +611,11 @@ class _CompiledStepper:
                     lr, rng, inputs, labels)
             if fp8:
                 args = args + (self.ensure_fp8_state(),)
-            out = self._train_cache[key](*args)
-            if self.guard_numerics:
-                out, ok = out[:-1], out[-1]
-                self.last_ok = ok
-            else:
-                self.last_ok = None
+            out = self._give_key(self._train_cache[key](*args))
+            self.last_ok = out.pop() if self.guard_numerics else None
             if fp8:
-                loss, new_train, new_buf, new_opt, new_fp8, out_vals = out
-                self.fp8_state = new_fp8
-            else:
-                loss, new_train, new_buf, new_opt, out_vals = out
+                self.fp8_state = out.pop(4)
+            loss, new_train, new_buf, new_opt, out_vals = out
             for i, v in zip(self.t_idx, new_train):
                 self.params[i]._value = v
             for b, v in zip(self.buffers, new_buf):
@@ -637,8 +628,8 @@ class _CompiledStepper:
         if key not in self._grad_cache:
             self._grad_cache[key] = self._tracked(self._build_grad(),
                                                   "hapi.grad_step")
-        loss, out_vals, new_buf, grads = self._grad_cache[key](
-            train_vals, frozen_vals, buffer_vals, rng, inputs, labels)
+        loss, out_vals, new_buf, grads = self._give_key(self._grad_cache[key](
+            train_vals, frozen_vals, buffer_vals, rng, inputs, labels))
         if self.guard_numerics:
             # accumulation: a poisoned microbatch must not contaminate
             # the running grad sum — drop it here (host check; this path
@@ -700,15 +691,15 @@ class _CompiledStepper:
             self._grad_cache[key] = self._tracked(self._build_grad(),
                                                   "hapi.grad_step")
         train_vals = [self.params[i]._value for i in self.t_idx]
-        frozen_vals = [p._value for i, p in enumerate(self.params)
-                       if i not in set(self.t_idx)]
+        frozen_vals = [self.params[i]._value for i in self.f_idx]
         buffer_vals = [b._value for b in self.buffers]
-        rng = getattr(self, "_last_rng", None)
-        if rng is None:
-            rng = next_key()
-        _, _, _, grads = self._grad_cache[key](
-            train_vals, frozen_vals, buffer_vals, rng, inputs, labels)
-        return list(grads)
+        replay = self._last_rng is not None
+        out = self._grad_cache[key](
+            train_vals, frozen_vals, buffer_vals,
+            self._last_rng if replay else self._take_key(), inputs, labels)
+        if not replay:       # no step to replay: a draw of its own
+            out = self._give_key(out)
+        return list(out[3])
 
     def ensure_opt_state(self):
         """Lazily build (and plan-place) the functional optimizer state
@@ -744,6 +735,7 @@ class Model:
         self._stepper = None
         self._jit = True
         self._guardian = None
+        self._train_stamp = None
         self.stop_training = False
 
     # -- prepare ------------------------------------------------------------
@@ -782,8 +774,15 @@ class Model:
             optimizer._parameter_list = self.network.parameters()
 
     # -- single-batch ops ---------------------------------------------------
+    def _train_mode(self):
+        """``network.train()``, unless no layer's mode was assigned since
+        this model last called it: the walk is over every sublayer."""
+        if self._train_stamp != mode_stamp():
+            self.network.train()
+            self._train_stamp = mode_stamp()
+
     def train_batch(self, inputs, labels=None, update=True):
-        self.network.train()
+        self._train_mode()
         if self._jit and self._stepper is not None:
             loss, out_vals = self._stepper.train_step(inputs, labels,
                                                       update=update)
@@ -794,12 +793,7 @@ class Model:
         labs = [x if isinstance(x, Tensor) else Tensor(_to_jnp(x))
                 for x in _as_list(labels)]
         outs = _as_list(self.network(*ins))
-        loss = self._loss(*(outs + labs))
-        if isinstance(loss, (list, tuple)):
-            total = loss[0]
-            for l in loss[1:]:
-                total = total + l
-            loss = total
+        loss = _total(self._loss(*(outs + labs)))
         loss.backward()
         if update:
             self._optimizer.step()
@@ -835,13 +829,7 @@ class Model:
                     for x in _as_list(labels)]
             loss = None
             if self._loss is not None and labs:
-                loss_t = self._loss(*(outs + labs))
-                if isinstance(loss_t, (list, tuple)):
-                    total = loss_t[0]
-                    for l in loss_t[1:]:
-                        total = total + l
-                    loss_t = total
-                loss = float(loss_t.item())
+                loss = float(_total(self._loss(*(outs + labs))).item())
             metrics = self._update_metrics(outs, labs)
         return self._pack_loss_metrics(loss, metrics)
 
@@ -1149,7 +1137,7 @@ class Model:
         for epoch in range(start_epoch, epochs):
             cbks.on_epoch_begin(epoch)
             self._reset_metrics()
-            self.network.train()
+            self._train_mode()
             logs = {}
             for step, batch in enumerate(self._timed_batches(
                     train_loader, trace, root, count)):
@@ -1190,7 +1178,7 @@ class Model:
                             # loop already owns)
                             t_step = time.perf_counter()
                             if jit:
-                                self.network.train()
+                                self._train_mode()
                                 stepped = self._stepper.train_step(
                                     ins, labs, update=do_update)
                     if skip:     # post-rollback poisoned window

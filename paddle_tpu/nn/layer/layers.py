@@ -31,6 +31,17 @@ class HookRemoveHelper:
         self._hooks.pop(self._idx, None)
 
 
+# Counts every assignment of a layer's ``training`` flag in the
+# process.  A caller that put a network into one mode and remembers the
+# stamp knows, while the stamp stands, that no layer has left that mode
+# (``hapi.Model`` skips its per-step ``network.train()`` walk by it).
+_MODE_STAMP = [0]
+
+
+def mode_stamp():
+    return _MODE_STAMP[0]
+
+
 class Layer:
     def __init__(self, name_scope=None, dtype="float32"):
         object.__setattr__(self, "_parameters", OrderedDict())
@@ -71,6 +82,8 @@ class Layer:
         elif bufs is not None and name in bufs:
             bufs[name] = value
         else:
+            if name == "training":
+                _MODE_STAMP[0] += 1
             object.__setattr__(self, name, value)
 
     def __getattr__(self, name):

@@ -245,8 +245,9 @@ METRICS = {
                 "(one AOT lower+compile each)"},
     "pt_compile_wall_ms": {
         "type": _H, "labels": ("surface",),
-        "help": "trace+lower+compile wall time per compile (host work "
-                "jax does anyway, measured at the wrapper)"},
+        "help": "wall time of each call that compiled: trace, lower, "
+                "compile and launch (host work jax does anyway) plus "
+                "the wrapper's analysis readout"},
     "pt_compile_flops": {
         "type": _G, "labels": ("surface",),
         "help": "analytical FLOPs of ONE dispatch from the lowering's "
@@ -263,6 +264,12 @@ METRICS = {
         "type": _C, "labels": ("surface",),
         "help": "compiles past the surface's declared budget — each "
                 "one also raised a guardian compile_retrace event"},
+    "pt_compile_dispatch_total": {
+        "type": _C, "labels": ("surface", "path"),
+        "help": "calls of a tracked jit surface by path: 'fast' = jax's "
+                "own dispatch knew the call and nothing was walked in "
+                "Python, 'signature' = the call was new to it (a "
+                "compile, as a rule), so the signature was walked"},
     "pt_compile_dispatch_ms": {
         "type": _H, "labels": ("surface",),
         "help": "measured wall time of ONE dispatch of this surface, "

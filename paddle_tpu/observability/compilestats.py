@@ -12,12 +12,12 @@ per request).  This module closes that gap:
 
 - :func:`wrap` takes an already-``jax.jit``-ed callable and a *surface*
   name (matching the ``analysis.jit_surface`` registry vocabulary) and
-  returns a :class:`CompiledSurface` that owns the executable cache per
-  shape signature.  On a signature's first call it lowers once, records
-  the lowering's ``cost_analysis()`` (FLOPs / bytes accessed) and the
-  compiled ``memory_analysis()`` footprint plus the compile wall time,
-  then calls the AOT executable — ONE compile per signature, same
-  lowering pipeline, bitwise-identical outputs;
+  returns a :class:`CompiledSurface` that calls it and keeps one record
+  per shape signature.  After a call that compiled it walks the
+  signature, records the lowering's ``cost_analysis()`` (FLOPs / bytes
+  accessed), the compiled ``memory_analysis()`` footprint and the
+  compile wall time — ONE compile per signature; a call JAX's own
+  dispatch knows walks nothing (``pt_compile_dispatch_total``);
 - every record lands in the ``pt_compile_*`` metrics (labels:
   ``surface``) and in a module registry :func:`snapshot` the roofline
   arithmetic joins against measured latency (``roofline_from_stats``,
@@ -276,21 +276,23 @@ def write_op_names(trace_dir):
 # -- the wrapper ------------------------------------------------------------
 
 class CompiledSurface:
-    """Owns the per-signature executable cache for one jit surface.
-
-    Calling it with a new signature lowers + compiles once (recording
-    cost/memory analysis and compile wall time), then dispatches the
-    AOT executable; a cached signature goes straight to its executable.
-    There is no fallback to the plain jitted callable: a lowering,
-    compile or dispatch error of the AOT executable propagates (only
-    the cost/memory *analysis* readouts are best-effort).
+    """Tracks the executables of one jit surface; dispatch is the jitted
+    callable's own.  JAX's C++ dispatch decides, by the pytree and avals
+    it keys its cache on, whether a call is known, and a known call
+    costs what ``jax.jit``'s cached call costs.  Only after a call that
+    added an entry to that cache is the Python :func:`signature` walked;
+    a new signature is lowered again for its analysis (lowering and
+    executable come out of JAX's caches: ONE backend compile each),
+    recorded, and held against the budget.  No fallback: a trace,
+    compile or dispatch error propagates (only the *analysis* readouts
+    are best-effort).
     """
 
     def __init__(self, fn, surface, budget=None):
         self._fn = fn
         self.surface = surface
         self.budget = budget
-        self._cache = {}       # sig -> AOT compiled executable
+        self._cache = {}       # sig -> AOT executable (analysis, HLO text)
         self._last_sig = None
         self._lock = threading.Lock()
         _WRAPPERS.add(self)
@@ -300,18 +302,24 @@ class CompiledSurface:
         return len(self._cache)
 
     def __call__(self, *args):
-        sig = signature(args)
-        entry = self._cache.get(sig)
-        if entry is None:
-            entry = self._compile(sig, args)
-        return entry(*args)
+        fn = self._fn
+        known = fn._cache_size()
+        t0 = time.perf_counter()
+        out = fn(*args)
+        new = fn._cache_size() != known
+        _metrics.inc("pt_compile_dispatch_total", surface=self.surface,
+                     path="signature" if new else "fast")
+        if new:
+            # donated arguments are deleted by now; shapes and dtypes stay
+            sig = signature(args)
+            if sig not in self._cache:
+                self._compiled(sig, args, t0)
+        return out
 
-    def _compile(self, sig, args):
+    def _compiled(self, sig, args, t0):
         with self._lock:
-            entry = self._cache.get(sig)
-            if entry is not None:
-                return entry
-            t0 = time.perf_counter()
+            if sig in self._cache:
+                return
             cost = mem = None
             kinds = {}
             lowered = self._fn.lower(*args)
@@ -353,7 +361,6 @@ class CompiledSurface:
                 self._retrace(sig, n)
             self._last_sig = sig
             self._cache[sig] = entry
-            return entry
 
     def _retrace(self, sig, n):
         diff = signature_diff(self._last_sig, sig)
@@ -365,9 +372,8 @@ class CompiledSurface:
 
 def wrap(fn, surface, budget=None):
     """Wrap an already-jitted callable as a tracked
-    :class:`CompiledSurface`.  ``surface`` names the jit surface (the
-    ``analysis`` registry vocabulary: ``hapi.train_step``,
-    ``serving.decode_chunk``, ...); ``budget`` is the declared number
-    of legitimate compiles for this wrapper's lifetime (None = no
-    retrace sentinel, count-only)."""
+    :class:`CompiledSurface`.  ``surface`` names the jit surface
+    (``hapi.train_step``, ``serving.decode_chunk``, ...); ``budget`` is
+    the number of legitimate compiles in this wrapper's lifetime (None
+    = no retrace sentinel, count-only)."""
     return CompiledSurface(fn, surface, budget=budget)

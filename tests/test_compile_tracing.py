@@ -150,6 +150,125 @@ class TestCompileStats:
         assert [r.tokens for r in reqs_a] == [r.tokens for r in reqs_b]
 
 
+# -- the wrapper's call path (ISSUE 28) ------------------------------------
+
+def _dispatches(surface):
+    c = obs.get_registry().get("pt_compile_dispatch_total")
+    return (c.value(surface=surface, path="fast"),
+            c.value(surface=surface, path="signature"))
+
+
+class TestCallPath:
+    @pytest.fixture
+    def walks(self, monkeypatch):
+        """Counts the Python ``signature`` walks (counted, not timed)."""
+        seen = []
+        real = compilestats.signature
+
+        def counting(args):
+            seen.append(1)
+            return real(args)
+        monkeypatch.setattr(compilestats, "signature", counting)
+        return seen
+
+    @pytest.fixture
+    def backend_compiles(self):
+        """Backend compiles by JAX's own monitoring event (what
+        ``chip_smoke.CompileMeter`` counts)."""
+        import jax.monitoring as mon
+        seen = []
+
+        def on(name, secs, **kw):
+            if name.endswith("backend_compile_duration"):
+                seen.append(secs)
+        mon.register_event_duration_secs_listener(on)
+        yield seen
+        mon.unregister_event_duration_listener(on)
+
+    def test_known_call_walks_nothing_and_compiles_once(
+            self, walks, backend_compiles):
+        f = compilestats.wrap(
+            jax.jit(lambda a, b: jnp.tanh(a @ b) * 3), "t.path", budget=1)
+        a = jnp.ones((8, 8), jnp.float32)
+        n = 6
+        before = len(backend_compiles)
+        outs = [f(a, a) for _ in range(n)]
+        assert len(walks) == 1
+        assert _dispatches("t.path") == (n - 1, 1)
+        # lowered again for its analysis, dispatched by jax.jit: still
+        # ONE backend compile of the executable
+        assert len(backend_compiles) - before == 1
+        st = compilestats.snapshot()["t.path"]
+        assert st["compiles"] == 1 and st["signatures"] == 1
+        assert st["flops"] > 0 and st["memory_bytes"] > 0
+        assert st["compile_wall_ms"] > 0
+        assert f.compiles == 1
+        assert "jit" in next(iter(compilestats.op_names()))
+        assert all(np.array_equal(outs[0], o) for o in outs)
+        assert guardian.events("compile_retrace") == []
+
+    @pytest.mark.parametrize("drift,diff", [
+        (lambda: jnp.ones((4,), jnp.bfloat16),
+         "arg[0]: float32[4] -> bfloat16[4]"),
+        (lambda: jnp.ones((6,), jnp.float32),
+         "arg[0]: float32[4] -> float32[6]"),
+    ], ids=["dtype", "shape"])
+    def test_drift_compiles_once_more_and_says_what_changed(
+            self, walks, backend_compiles, drift, diff):
+        f = compilestats.wrap(jax.jit(lambda a: a * 2), "t.drift", budget=1)
+        x, y = jnp.ones((4,), jnp.float32), drift()
+        f(x), f(x)
+        before = len(backend_compiles)
+        f(y), f(y), f(x)
+        assert len(backend_compiles) - before == 1
+        assert len(walks) == 2
+        assert _dispatches("t.drift") == (3, 2)
+        st = compilestats.snapshot()["t.drift"]
+        assert st["compiles"] == 2 and st["signatures"] == 2
+        assert st["retraces"] == 1
+        (ev,) = guardian.events("compile_retrace")
+        assert ev["diff"] == diff
+        assert ev["compiles"] == 2 and ev["budget"] == 1
+
+    def test_none_against_array_is_another_executable(self):
+        f = compilestats.wrap(
+            jax.jit(lambda a, m: a if m is None else a * m), "t.none")
+        x = jnp.ones((4,), jnp.float32)
+        assert np.array_equal(f(x, None), x)
+        assert np.array_equal(f(x, x * 3), x * 3)
+        assert np.array_equal(f(x, None), x)
+        st = compilestats.snapshot()["t.none"]
+        assert st["compiles"] == 2 and st["signatures"] == 2
+        assert _dispatches("t.none") == (1, 2)
+
+    def test_donated_arguments_stay_donated(self):
+        f = compilestats.wrap(
+            jax.jit(lambda s, g: s + g, donate_argnums=(0,)), "t.donate")
+        state, g = jnp.zeros((64,), jnp.float32), jnp.ones((64,))
+        for _ in range(3):
+            old, state = state, f(state, g)
+            assert old.is_deleted() and not g.is_deleted()
+        assert float(state[0]) == 3.0
+        # the first call's signature was walked over a deleted buffer
+        assert compilestats.snapshot()["t.donate"]["compiles"] == 1
+
+    def test_an_error_inside_the_executable_propagates(self):
+        def boom(x):
+            raise RuntimeError("host callback failed")
+
+        def fn(a):
+            return jax.pure_callback(
+                boom, jax.ShapeDtypeStruct(a.shape, a.dtype), a)
+        f = compilestats.wrap(jax.jit(fn), "t.boom")
+        with pytest.raises(Exception, match="host callback failed"):
+            jax.block_until_ready(f(jnp.ones((4,), jnp.float32)))
+        # and one that fails while tracing, before anything compiled
+        g = compilestats.wrap(jax.jit(lambda a: a @ a), "t.trace_error")
+        with pytest.raises(TypeError):
+            g(jnp.ones((3, 4), jnp.float32))
+        assert "t.trace_error" not in compilestats.snapshot()
+
+
 # -- request-scoped traces -------------------------------------------------
 
 class TestRequestTracing:
