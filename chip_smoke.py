@@ -482,8 +482,12 @@ def kernel_flash_gpt1p3b(ck):
 
 
 def kernel_flash_two_pass(ck):
-    """two-pass dq / dkv backward: S*D past the fused-backward cap."""
-    return _sdpa_case(ck, "flash_two_pass", 1, 16384, 1, 64, causal=True)
+    """two-pass dq / dkv backward: S*D past the fused-backward cap; a
+    single head of 64 (the transposing path) and a pair tile (in place)."""
+    return {"transposed": _sdpa_case(ck, "flash_two_pass", 1, 16384, 1, 64,
+                                     causal=True),
+            "in_place": _sdpa_case(ck, "flash_two_pass_pair", 1, 16384, 2,
+                                   64, causal=True)}
 
 
 def kernel_fused_xent(ck):

@@ -2,12 +2,14 @@
 python/paddle/nn/functional/flash_attention.py — cutlass flash-attn;
 paddle/phi/kernels/fusion/gpu/fused_attention — fused QKV attention).
 
-TPU-native: one `scaled_dot_product_attention` entry.  Forward uses the
-Pallas blockwise online-softmax kernel on TPU for long sequences (VMEM-
-resident q blocks, streamed k/v — the flash pattern); the XLA path (which
-the compiler already fuses into two MXU matmuls + softmax) is used for
-short sequences, on CPU, and for the backward (recompute-based pullback,
-the flash-bwd recompute strategy expressed at the XLA level).
+TPU-native: one `scaled_dot_product_attention` entry.  On TPU, long
+sequences run the Pallas flash kernels forward AND backward (blockwise
+online softmax; the backward recomputes P blockwise from the saved
+log-sum-exp rows), reading and writing (B, S, H, D) in place
+(ops/pallas/flash_attention.py).  The XLA path (which the compiler
+already fuses into two MXU matmuls + softmax, with a recompute-based
+pullback) serves short sequences, the CPU, and every shape the kernel
+constraint ladder below turns away.
 """
 import math
 from collections import namedtuple
@@ -89,9 +91,9 @@ _NO_FLASH = _Flash(False, False)
 
 # Under a multi-device trace (kreg.partitioned) XLA cannot partition the
 # Mosaic kernels, so the three flash entries run per shard: batch over
-# the batch axes, heads over the tensor-parallel axis.  The lse residual
-# crosses the shard_map boundary as (B, H, S) — its kernel layout
-# (B*H, S) folds two differently-sharded dims into one.
+# the batch axes, heads over the tensor-parallel axis (the lse residual
+# is (B, H, S)).  Each shard's own head count decides whether its
+# kernels read the layout in place (_fa.lane_tiled).
 
 def _flash_specs(part, q, k):
     P = jax.sharding.PartitionSpec
@@ -108,12 +110,8 @@ def _run_flash_fwd(q, k, v, bias, causal, interpret, with_lse):
     s4, s2, s3 = _flash_specs(part, q, k)
 
     def local(q_, k_, v_, *b_):
-        out = entry(q_, k_, v_, b_[0] if b_ else None, causal=causal,
-                    interpret=interpret)
-        if not with_lse:
-            return out
-        o, lse = out
-        return o, lse.reshape(q_.shape[0], q_.shape[2], q_.shape[1])
+        return entry(q_, k_, v_, b_[0] if b_ else None, causal=causal,
+                     interpret=interpret)
     with_bias = () if bias is None else (bias,)
     return part.shard_map(
         local, (s4, s4, s4) + (s2,) * len(with_bias),
@@ -128,24 +126,35 @@ def _run_flash_bwd(q, k, v, o, lse, g, bias, causal, interpret):
     s4, s2, s3 = _flash_specs(part, q, k)
 
     def local(q_, k_, v_, o_, lse_, g_, *b_):
-        return _flash_bwd(q_, k_, v_, o_, lse_.reshape(-1, lse_.shape[-1]),
-                          g_, b_[0] if b_ else None, causal=causal,
-                          interpret=interpret)
+        return _flash_bwd(q_, k_, v_, o_, lse_, g_, b_[0] if b_ else None,
+                          causal=causal, interpret=interpret)
     with_bias = () if bias is None else (bias,)
     return part.shard_map(
         local, (s4, s4, s4, s4, s3, s4) + (s2,) * len(with_bias),
         (s4, s4, s4))(q, k, v, o, lse, g, *with_bias)
 
 
+def _local_heads(H, Hk):
+    """The query-head count one shard's kernels see."""
+    part = kreg.current_partition()
+    if part is None or part.heads(H, Hk) is None:
+        return H
+    return H // part.mesh.shape[part.head_axis]
+
+
 def _select_flash(S, Sk, D, causal, has_mask, mask_is_keybias, scale,
-                  dropout_p=0.0):
+                  dropout_p=0.0, *, heads):
     """The dispatch decision for one attention call, made on static
-    shapes at trace time.  Platform/override policy comes from the
-    registry; the constraint ladder maps what the kernels support, and
-    every constraint fallback is booked in pt_kernel_fallbacks_total
-    (a silently dense-running config must be visible in telemetry)."""
-    sel = kreg.choose("attention")
+    shapes at trace time (``heads`` = (H, H_kv)).  Platform/override
+    policy comes from the registry; the constraint ladder maps what the
+    kernels support, and every constraint fallback is booked in
+    pt_kernel_fallbacks_total (a silently dense-running config must be
+    visible in telemetry).  A shape the flash kernels take only through
+    a transposed copy (the lane-tile rule, per shard) is booked in
+    pt_kernel_selects_total as ``pallas_transposed``."""
+    sel = kreg.choose("attention", book=False)
     if sel.impl != "pallas":
+        kreg.record_select("attention", sel.impl)
         return _NO_FLASH
     pad = (-S) % _PAD_GRANULE
     spad = S + pad
@@ -166,6 +175,10 @@ def _select_flash(S, Sk, D, causal, has_mask, mask_is_keybias, scale,
         reason = "mask-large" if has_mask else "pad-noncausal"
     elif not sel.forced and S < _PALLAS_MIN_SEQ:
         reason = "short-seq"
+    transposed = reason is None and not _fa.lane_tiled(
+        _local_heads(*heads), D)
+    kreg.record_select("attention",
+                       "pallas_transposed" if transposed else "pallas")
     if reason is not None:
         kreg.record_fallback("attention", reason)
         return _NO_FLASH
@@ -304,7 +317,8 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     flash = _select_flash(S, Sk, D, causal,
                           has_mask=attn_mask is not None,
                           mask_is_keybias=reduce is not None,
-                          scale=None, dropout_p=drop)
+                          scale=None, dropout_p=drop,
+                          heads=(H, k.shape[2]))
     if flash.use:
         if attn_mask is None:
             return call_op(lambda a, b, c: _attention_core(

@@ -281,7 +281,11 @@ METRICS = {
         "type": _C, "labels": ("kernel", "impl"),
         "help": "kernel-registry selections by implementation (one per "
                 "dispatch decision: trace time for jitted surfaces, "
-                "per call for eager dispatches)"},
+                "per call for eager dispatches); attention books "
+                "impl=pallas_transposed beside pallas for a call the "
+                "flash kernels take only through a transposed "
+                "(B*H, S, D) copy (head size and per-shard head count "
+                "outside the lane-tile rule, docs/kernels.md)"},
     "pt_kernel_fallbacks_total": {
         "type": _C, "labels": ("kernel", "reason"),
         "help": "calls the platform policy routed to a Pallas impl but "

@@ -55,8 +55,8 @@ import jax
 
 __all__ = [
     "register", "choose", "impl_fn", "force", "interpret_enabled",
-    "record_fallback", "TrackedKernel", "flash_blocks", "autotune_flash",
-    "autotune_table", "autotune_cache_path", "Selection",
+    "record_select", "record_fallback", "TrackedKernel", "flash_blocks",
+    "autotune_flash", "autotune_table", "autotune_cache_path", "Selection",
     "partitioned", "current_partition",
 ]
 
@@ -155,13 +155,16 @@ def _env_override(kernel):
     return None
 
 
-def choose(kernel, platform=None):
+def choose(kernel, platform=None, book=True):
     """Pick the implementation for ``kernel`` on ``platform`` (default:
     the active jax backend).  Order: ``force()`` context > env override
     > first registered impl whose platform matches.  Returns
     ``Selection(impl, forced, interpret)``; ``interpret`` is True when
     the pick is a Pallas impl running off-platform under interpret
-    mode.  The selection is counted in ``pt_kernel_selects_total``."""
+    mode.  The selection is counted in ``pt_kernel_selects_total``;
+    a dispatch site that tells variants of one impl apart (attention's
+    ``pallas_transposed``) passes ``book=False`` and books the label
+    itself through :func:`record_select`."""
     plat = platform or jax.default_backend()
     interp = interpret_enabled()
     _ensure_defaults(kernel)
@@ -201,10 +204,16 @@ def choose(kernel, platform=None):
         raise RuntimeError(
             f"kernel {kernel!r} has no impl for platform {plat!r} "
             f"(registered: {[(e[0], e[2]) for e in entries]})")
+    if book:
+        record_select(kernel, sel.impl)
+    return sel
+
+
+def record_select(kernel, impl):
+    """Book one dispatch decision in ``pt_kernel_selects_total``."""
     m = _metrics()
     if m.enabled():
-        m.inc("pt_kernel_selects_total", kernel=kernel, impl=sel.impl)
-    return sel
+        m.inc("pt_kernel_selects_total", kernel=kernel, impl=impl)
 
 
 def record_fallback(kernel, reason):
@@ -541,10 +550,9 @@ def autotune_flash(S, D, heads=8, batch=1, candidates=None, iters=3,
     if not cands:
         raise ValueError(f"no candidate block pair divides S={S}")
     rng = np.random.RandomState(0)
-    shape = (batch * heads, S, D)
-    q = jnp.asarray(rng.randn(*shape).astype("float32"))
-    k = jnp.asarray(rng.randn(*shape).astype("float32"))
-    v = jnp.asarray(rng.randn(*shape).astype("float32"))
+    pack, _ = fa._packing(batch, heads, D)     # the layout dispatch runs
+    q, k, v = (pack(jnp.asarray(rng.randn(batch, S, heads, D)
+                                .astype("float32"))) for _ in range(3))
 
     m = _metrics()
     results = {}
@@ -552,9 +560,9 @@ def autotune_flash(S, D, heads=8, batch=1, candidates=None, iters=3,
         bq_, bk_ = min(bq, S), min(bk, S)
 
         def run():
-            o, lse = fa._flash_bhsd_fwd_lse(q, k, v, causal=True,
-                                            block_q=bq_, block_k=bk_,
-                                            interpret=interpret)
+            o, lse = fa._flash_bhsd_fwd(q, k, v, head_dim=D, causal=True,
+                                        block_q=bq_, block_k=bk_,
+                                        interpret=interpret)
             # completion barrier: D2H of a dependent scalar (the
             # bench methodology contract)
             float(o.ravel()[0])
@@ -572,10 +580,10 @@ def autotune_flash(S, D, heads=8, batch=1, candidates=None, iters=3,
                       surface=FLASH_FWD_LSE_SURFACE)
     best = min(results, key=results.get)
     # table keys carry the FOLDED head count (batch*heads): that is the
-    # (BH, S, D) layout the sweep timed and the shape component
-    # _fwd_blocks(S, D, B*H) looks up at dispatch — keying on the
-    # unfolded ``heads`` would park every batch>1 winner on a key no
-    # dispatch ever reads (and hand it to the wrong batch=1 config)
+    # shape component _fwd_blocks(S, D, B*H) looks up at dispatch —
+    # keying on the unfolded ``heads`` would park every batch>1 winner
+    # on a key no dispatch ever reads (and hand it to the wrong batch=1
+    # config)
     key = (S, D, batch * heads)
     rec = {"block_q": best[0], "block_k": best[1],
            "ms": round(results[best], 4)}

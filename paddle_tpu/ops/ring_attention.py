@@ -126,7 +126,8 @@ def ulysses_attention(q, k, v, axis_name, causal=False, scale=None,
         def attention_fn(a, b, c):
             sel = _select_flash(a.shape[1], b.shape[1], a.shape[3],
                                 bool(causal), has_mask=False,
-                                mask_is_keybias=False, scale=scale)
+                                mask_is_keybias=False, scale=scale,
+                                heads=(a.shape[2], b.shape[2]))
             return _attention_core(a, b, c, bool(causal), scale, sel)
     o = attention_fn(q, k, v)
     # (B, S, H/sep, D) -> (B, S/sep, H, D)

@@ -1,0 +1,61 @@
+"""The flash kernels compile for a v5e at the widths the models run.
+
+No chip is attached: the TPU compiler installed here compiles for a chip
+that is described (``jax.experimental.topologies``).  Interpret mode
+cannot show what this shows: a slice Mosaic cannot tile, or a kernel
+that asks for more scoped VMEM than it may have — the pair tiles hold
+two heads' k/v and dk/dv where one head a program held one.  Nothing
+runs, so this says nothing about results or times.
+"""
+import os
+
+import pytest
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.ops.pallas import flash_attention as fa
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no TPU compiler here: nothing to check
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("B,S,H,D,causal,masked", [
+    (4, 2048, 16, 64, True, False),     # gpt3-medium: head-folded fwd, fused bwd, pair tiles
+    (2, 2048, 16, 128, True, False),    # gpt3-xl: q-grid fwd, fused bwd, one head a tile
+    (2, 1024, 12, 64, True, False),     # gpt-125m: head-folded fwd and bwd
+    (2, 512, 12, 64, False, True),      # BERT-base: the key bias
+    # past the default 16 MB of scoped VMEM with two heads a tile (16.9 MB
+    # once blocks are double-buffered: more than one tile or batch row)
+    (2, 4096, 16, 64, True, False),
+    (2, 8192, 16, 64, True, False),     # the fused backward at its S*D cap
+    (1, 16384, 2, 64, True, False),     # two-pass backward: a grid of one tile, lowering only
+    (2, 2048, 8, 32, True, False),      # four heads a tile
+    (2, 2048, 12, 96, True, False),     # transposed: D=96
+    (2, 2048, 1, 64, True, False),      # transposed: a single head of 64
+], ids=["medium", "xl", "gpt125m", "bert_mask", "s4096", "fused_cap",
+        "two_pass", "d32", "d96_transposed", "h1_transposed"])
+def test_kernels_compile_for_v5e(one_chip, B, S, H, D, causal, masked):
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    x = sds((B, S, H, D))
+    bias = (sds((B, S), jnp.float32),) if masked else ()
+    # conftest asks for "highest" everywhere; the chip's programs run at
+    # the default, and Mosaic takes bf16 operands at no other
+    with jax.default_matmul_precision(None):
+        fwd = jax.jit(lambda q, k, v, *b: fa.flash_attention_fwd_lse(
+            q, k, v, *b, causal=causal)).lower(x, x, x, *bias).compile()
+        bwd = jax.jit(lambda q, k, v, o, lse, g, *b: fa.flash_attention_bwd(
+            q, k, v, o, lse, g, *b, causal=causal)).lower(
+                x, x, x, x, sds((B, H, S), jnp.float32), x, *bias).compile()
+    assert "tpu_custom_call" in fwd.as_text()
+    assert "tpu_custom_call" in bwd.as_text()

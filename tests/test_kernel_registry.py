@@ -288,7 +288,8 @@ class TestDispatch:
         m0 = reg.get("pt_kernel_fallbacks_total")
         base = m0.value(kernel="attention", reason="mask") if m0 else 0
         sel = _select_flash(512, 512, 64, causal=False, has_mask=True,
-                            mask_is_keybias=False, scale=None)
+                            mask_is_keybias=False, scale=None,
+                            heads=(8, 8))
         assert not sel.use
         m = reg.get("pt_kernel_fallbacks_total")
         assert m.value(kernel="attention", reason="mask") == base + 1
@@ -301,7 +302,7 @@ class TestDispatch:
         def reason_of(**kw):
             args = dict(S=2048, Sk=2048, D=64, causal=True,
                         has_mask=False, mask_is_keybias=False,
-                        scale=None)
+                        scale=None, heads=(16, 16))
             args.update(kw)
             return _select_flash(**args)
 
@@ -319,11 +320,13 @@ class TestDispatch:
         from paddle_tpu.nn.functional.attention import _select_flash
         monkeypatch.setenv("PADDLE_TPU_KERNEL_INTERPRET", "1")
         auto = _select_flash(256, 256, 64, causal=True, has_mask=False,
-                             mask_is_keybias=False, scale=None)
+                             mask_is_keybias=False, scale=None,
+                             heads=(8, 8))
         assert not auto.use                       # S < 1024, not forced
         monkeypatch.setenv("PADDLE_TPU_ATTN_IMPL", "flash")
         forced = _select_flash(256, 256, 64, causal=True, has_mask=False,
-                               mask_is_keybias=False, scale=None)
+                               mask_is_keybias=False, scale=None,
+                               heads=(8, 8))
         assert forced.use and forced.interpret
 
 

@@ -61,7 +61,7 @@ def fb_chain(q, k, v):
     def loss(qq, kk, vv):
         sel = _select_flash(qq.shape[1], kk.shape[1], qq.shape[3],
                             True, has_mask=False, mask_is_keybias=False,
-                            scale=None)
+                            scale=None, heads=(qq.shape[2], kk.shape[2]))
         return jnp.sum(_attention_core(qq, kk, vv, True, None, sel)
                        .astype(jnp.float32))
     g = jax.grad(loss, argnums=(0,))
