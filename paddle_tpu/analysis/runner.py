@@ -43,15 +43,6 @@ def _passes():
                                 MetricNamesPass)}
 
 
-def _optional_passes():
-    """Passes that run ONLY when named in --passes (never in the
-    default all-passes sweep): the bench trajectory gate depends on
-    committed BENCH artifacts and machine-load-sensitive numbers, so
-    it belongs in the bench workflow, opted into explicitly."""
-    from .bench_gate import BenchComparePass
-    return {p.name: p for p in (BenchComparePass,)}
-
-
 class Context:
     """What a pass sees: the parsed code index plus the reference files
     (tests/docs) the registry lints scan."""
@@ -122,16 +113,11 @@ def run_passes(paths=None, passes=None, root=None, ctx=None,
     import time
     ctx = ctx or make_context(paths, root)
     registry = _passes()
-    if passes:
-        # opt-in passes join the registry only when explicitly named
-        optional = _optional_passes()
-        registry.update({n: p for n, p in optional.items()
-                         if n in passes})
     names = list(registry) if not passes else list(passes)
     unknown = [n for n in names if n not in registry]
     if unknown:
-        known = sorted(set(_passes()) | set(_optional_passes()))
-        raise ValueError(f"unknown pass(es) {unknown}; known: {known}")
+        raise ValueError(
+            f"unknown pass(es) {unknown}; known: {sorted(registry)}")
     findings = []
     ast_passes = {"tracer-safety", "host-sync", "collective-order",
                   "donation", "retrace-hazard", "concurrency",
@@ -254,8 +240,6 @@ def main(argv=None):
     if args.list_passes:
         for name in _passes():
             print(name)
-        for name in _optional_passes():
-            print(f"{name} (opt-in: runs only when named in --passes)")
         return 0
 
     passes = [p.strip() for p in args.passes.split(",")] \

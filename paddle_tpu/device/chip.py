@@ -4,7 +4,7 @@ result carries, where the persistent compile cache lives, and a probe
 that counts the host's chips without initialising a JAX backend.
 
 Nothing here runs at ``import paddle_tpu``: entry points (chip_smoke.py,
-bench.py, the launcher) call it explicitly.
+the benchmark harness, the launcher) call it explicitly.
 """
 import glob
 import os

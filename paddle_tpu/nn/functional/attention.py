@@ -28,10 +28,10 @@ __all__ = ["scaled_dot_product_attention", "flash_attention",
 
 # Pallas kernel pays off past this seq length on TPU (short seqs fit XLA's
 # fused softmax just fine and avoid kernel-launch overhead); forcing the
-# impl (sdp_kernel / PADDLE_TPU_ATTN_IMPL=flash) skips the floor
+# impl (sdp_kernel / PADDLE_TPU_KERNEL_ATTENTION=pallas) skips the floor
 _PALLAS_MIN_SEQ = 1024
 # sequences pad up to this granule so S need not be a multiple of 512
-# (256 divides every block pair the autotune table can answer)
+# (256 divides every block pair registry.flash_blocks answers for it)
 _PAD_GRANULE = 256
 
 
@@ -385,7 +385,7 @@ class sdp_kernel:
     the reference also exposes), now wired to the kernel registry:
     ``enable_flash=False`` forces the XLA path, ``enable_math=False``
     (with flash enabled) forces the Pallas kernel — the same override
-    rail as ``PADDLE_TPU_ATTN_IMPL``/``PADDLE_TPU_KERNEL_ATTENTION``.
+    rail as ``PADDLE_TPU_KERNEL_ATTENTION``.
     With both enabled (the default) the dispatch stays automatic."""
 
     def __init__(self, enable_flash=True, enable_math=True,

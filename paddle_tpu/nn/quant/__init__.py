@@ -49,8 +49,7 @@ def weight_quantize(x, algo="weight_only_int8", group_size=-1):
         costs more than fp8's upconvert, so int4 on this chip is a
         CAPACITY feature (4x smaller checkpoints / HBM weights than
         fp32, 2x vs int8-or-fp8), not a latency one — the serving
-        latency path is fp8 (1.66x) or int8-MXU (1.32x), see
-        bench.py fp8_linear.
+        latency path is fp8 or int8-MXU.
     """
     if algo not in ("weight_only_int8", "llm.int8", "weight_only_int4"):
         raise ValueError(f"unsupported algo {algo}")

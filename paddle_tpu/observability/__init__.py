@@ -3,8 +3,8 @@ timeline, and zero-sync hot-path instrumentation.
 
 The repo grew three disjoint telemetry streams — profiler host spans
 (``paddle_tpu.profiler``), the guardian structured log
-(``framework.guardian``), and bench.py one-shots.  This package is the
-fourth piece that makes them ONE picture:
+(``framework.guardian``), and one-shot bench scripts.  This package is
+the fourth piece that makes them ONE picture:
 
 - :mod:`.metrics` — process-wide Counter/Gauge/Histogram registry with
   labels, recorded from every hot layer (hapi fit stepper, serving

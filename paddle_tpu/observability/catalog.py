@@ -297,14 +297,6 @@ METRICS = {
                 "weights degraded to int8) | fp8-weight-only (fp8 "
                 "always streams through the XLA weight-only path — "
                 "no Pallas fp8 kernel by design)"},
-    "pt_kernel_autotune_runs_total": {
-        "type": _C, "labels": ("kernel",),
-        "help": "block-size micro-sweeps executed (autotune_flash; "
-                "winners persist to the autotune cache)"},
-    "pt_kernel_autotune_best_ms": {
-        "type": _G, "labels": ("kernel", "key"),
-        "help": "median dispatch ms of the winning block config for "
-                "one (S, D, heads) autotune key"},
     # -- HBM memory ledger (observability/memory.py) ----------------------
     "pt_memory_static_bytes": {
         "type": _G, "labels": ("surface", "kind"),

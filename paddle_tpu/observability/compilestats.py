@@ -20,8 +20,7 @@ per request).  This module closes that gap:
   dispatch knows walks nothing (``pt_compile_dispatch_total``);
 - every record lands in the ``pt_compile_*`` metrics (labels:
   ``surface``) and in a module registry :func:`snapshot` the roofline
-  arithmetic joins against measured latency (``roofline_from_stats``,
-  ``telemetry/roofline.json``);
+  arithmetic joins against measured latency (``roofline_from_stats``);
 - the **retrace sentinel**: each wrapper declares a compile *budget* —
   the number of distinct signatures the surface legitimately needs in
   its lifetime (1 for a chunked decode loop; ``len(buckets)`` for a

@@ -31,9 +31,8 @@ probable causes with the evidence lines that support each verdict —
 Inputs: a bundle directory (``flight.BUNDLE_FILES``) or any subset of
 ``--prom`` / ``--jsonl`` / ``--trace`` sinks — the same self-contained
 stdlib parsers ``report`` uses, so ``doctor`` runs against artifacts
-from another process or machine (the ``tools/ci_check.py --doctor``
-smoke runs it over the committed ``telemetry/`` snapshots: healthy
-artifacts must parse clean and yield the ``no alerts`` verdict).
+from another process or machine (healthy artifacts parse clean and
+yield the ``no alerts`` verdict).
 Missing / empty / torn inputs degrade to notes, never tracebacks.
 
 CLI::

@@ -143,12 +143,10 @@ def write_jsonl(path=None, registry=None, run=None, replace_run=False):
     ``replace_run=True`` (needs ``run``) makes the write idempotent per
     run id: existing records carrying the same ``run`` are dropped
     before the new snapshot lands (atomic rewrite), while records of
-    *other* runs — and unparseable lines — survive untouched.  This is
-    how bench keeps ``telemetry/<tag>.jsonl`` from re-appending one
-    snapshot per invocation (the PR 7–8 duplicate-commit churn).
+    *other* runs — and unparseable lines — survive untouched, so a
+    per-tag snapshot file does not grow by one snapshot per invocation.
 
-    Use ``replace_run`` only on files this process owns (bench's
-    per-tag snapshots): same-process writers are serialized by a module
+    Use ``replace_run`` only on files this process owns: same-process writers are serialized by a module
     lock (concurrent threads each land their own run intact), but the
     read-rewrite-replace cycle still races a *foreign-process* appender
     — and after the replace a live writer's open fd points at the

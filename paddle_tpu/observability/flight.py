@@ -60,7 +60,7 @@ BUNDLE_FILES = ("meta.json", "window.jsonl", "metrics.jsonl",
 
 # env prefixes worth snapshotting into a bundle's meta (knobs that
 # change framework behavior; values are configuration, never secrets)
-_ENV_PREFIXES = ("PADDLE_", "JAX_", "XLA_", "BENCH_")
+_ENV_PREFIXES = ("PADDLE_", "JAX_", "XLA_")
 
 
 class FlightRecorder:

@@ -16,10 +16,10 @@ off-TPU, never to a traceback).  Booked as
 ``pt_memory_static_bytes{surface,kind}`` gauges, checked against a
 configurable device HBM envelope (``PADDLE_HBM_BYTES``, default the
 TPU v5e's 16 GiB — an over-envelope surface raises the guardian
-``memory_budget`` event), and written as ``telemetry/memory.json``
-next to ``roofline.json`` with one row for EVERY surface in the
-analysis registry (never-compiled surfaces get explicit placeholder
-rows, so a vanished surface is visible drift, not silence).
+``memory_budget`` event), and written by :func:`write_memory_json`
+with one row for EVERY surface in the analysis registry
+(never-compiled surfaces get explicit placeholder rows, so a vanished
+surface is visible drift, not silence).
 
 **Dynamic side** — a live-buffer census sampled ONLY at the flight
 recorder's pre-existing sync points (hapi post-step, serving chunk
@@ -374,19 +374,13 @@ def snapshot(envelope=None):
     }
 
 
-def write_memory_json(path=None, envelope=None):
-    """Write the ledger snapshot atomically (tmp + ``os.replace``, the
-    roofline.json discipline); default path sits next to it under
-    ``BENCH_TELEMETRY_DIR``.  Returns the path."""
+def write_memory_json(path, envelope=None):
+    """Write the ledger snapshot to ``path`` atomically (tmp +
+    ``os.replace``).  Returns the path."""
     import json
-    if path is None:
-        d = os.environ.get("BENCH_TELEMETRY_DIR", "telemetry")
+    d = os.path.dirname(path)
+    if d:
         os.makedirs(d, exist_ok=True)
-        path = os.path.join(d, "memory.json")
-    else:
-        d = os.path.dirname(path)
-        if d:
-            os.makedirs(d, exist_ok=True)
     tmp = path + ".tmp"
     with open(tmp, "w", encoding="utf-8") as f:
         json.dump(snapshot(envelope), f, indent=1, sort_keys=True)

@@ -21,8 +21,8 @@ Focused subviews:
 Both support ``--json``.  The parsers are deliberately self-contained
 (stdlib only, ``--device`` aside, which reads the trace through
 ``jax.profiler.ProfileData``): the report must run against files
-produced by an earlier process, a different machine, or a BENCH_*
-artifact — never against live registry state.
+produced by an earlier process or a different machine — never against
+live registry state.
 """
 import argparse
 import bisect
@@ -265,10 +265,9 @@ def render_report(prom=None, jsonl=None, trace=None):
 #
 # Still called by ``observability/doctor.py`` (its compute/memory/dispatch
 # attribution rows, and through ``evidence_from_sinks`` the two prom
-# readers below) and by ``bench.py`` ``_roofline_snapshot``; the
-# ``report --roofline`` view that rendered it is gone: it divided XLA's
-# ``cost_analysis`` by a host-timed dispatch.  ROADMAP queue 3 items 1
-# and 3 take the callers, and these functions with them.
+# readers below); the ``report --roofline`` view that rendered it is
+# gone: it divided XLA's ``cost_analysis`` by a host-timed dispatch.
+# ROADMAP queue 3 item 3 takes the caller, and these functions with it.
 
 def roofline_from_stats(stats, measured_ms=None, peak_flops=None,
                         hbm_bw=None, wire_bytes=None):
@@ -824,7 +823,7 @@ def render_requests(summary, rows):
 
 def memory_view(prom=None, memory_json=None):
     """Per-surface static + per-pool live memory tables from the HBM
-    ledger's sinks: a ``telemetry/memory.json`` artifact and/or the
+    ledger's sinks: a ``memory.json`` artifact and/or the
     ``pt_memory_*`` series of a prom exposition.  Either input alone
     works (the artifact carries the full static ledger; prom carries
     the last census's gauges); returns None when neither yields data."""
@@ -994,9 +993,8 @@ def main(argv=None):
                          "tables from the HBM ledger (pt_memory_* "
                          "series of --prom and/or --memory-json)")
     rp.add_argument("--memory-json", default=None, dest="memory_json",
-                    help="memory.json artifact written next to "
-                         "roofline.json (bench runs / "
-                         "memory.write_memory_json)")
+                    help="memory.json artifact "
+                         "(memory.write_memory_json)")
     rp.add_argument("--json", action="store_true", dest="as_json",
                     help="emit the subview as JSON (with --device / "
                          "--requests / --memory)")

@@ -3,7 +3,7 @@
 Reference analogue: paddle/phi/kernels/fusion/gpu conv+bn+act fusions
 (cudnn fused conv epilogues) used by ResNet-style bottlenecks.
 
-TPU-native rationale (bench.py ResNet analysis, VERDICT r3 #6): a 1x1
+TPU-native rationale (ResNet analysis, VERDICT r3 #6): a 1x1
 conv IS a (B*H*W, Cin) @ (Cin, Cout) matmul with arithmetic intensity
 ~Cin*Cout/(Cin+Cout) flops/byte — HBM-bound at ResNet bottleneck shapes
 (~21-26%-of-peak roofline on v5e), while the XLA conv emitter measured

@@ -45,11 +45,10 @@ _MH_BWD_MAX_SD = 1024 * 64
 
 
 def _fwd_blocks(S, D=64, heads=None):
-    """(block_q, block_k) from the kernel registry's autotune table
-    (ops/registry.py): env override > cached micro-sweep winner >
-    measured static heuristic.  Blocks must DIVIDE S — the kernels size
-    their loops as S // block (S=4608 with bk=1024 would silently skip
-    the last 512 keys) — and the registry guarantees that."""
+    """(block_q, block_k) from the kernel registry's static rule
+    (ops/registry.py ``flash_blocks``).  Blocks must DIVIDE S — the
+    kernels size their loops as S // block (S=4608 with bk=1024 would
+    silently skip the last 512 keys) — and the rule guarantees that."""
     from ..registry import flash_blocks
     return flash_blocks(S, D, heads)
 

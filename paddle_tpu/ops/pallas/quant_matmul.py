@@ -136,8 +136,8 @@ def fp8_matmul(x, w_fp8, w_scale, act_scale=None, out_dtype=jnp.float32):
     subtracted — the r4 numbers in both directions were latency
     noise): the MXU has no fp8 arithmetic, XLA upconverts the weight
     to bf16 on the fly *inside* its matmul pipeline.  In the weight-
-    bandwidth-bound serving regime (M=32, K=N=4096, 32-layer chain,
-    bench.py fp8_linear) this measures 1.46 ms/pass bf16 (733 GB/s
+    bandwidth-bound serving regime (M=32, K=N=4096, 32-layer chain)
+    this measured 1.46 ms/pass bf16 (733 GB/s
     weight stream) vs 0.88 ms/pass fp8 (609 GB/s of half-size
     weights) = **1.66x** — the memory-bandwidth win is real and XLA's
     own streaming beats every Pallas upconvert kernel we tried

@@ -19,21 +19,15 @@ or attributed.  The registry centralizes the *policy*:
   CPU-only knob: set on a TPU backend it is an error, never a chip run
   that quietly interprets its kernels.
 - **overrides** — ``force(kernel, impl)`` (the ``sdp_kernel`` context
-  manager hook) and env knobs: ``PADDLE_TPU_KERNEL_<KERNEL>=<impl>``
-  generically, plus the legacy ``PADDLE_TPU_ATTN_IMPL=dense|flash``
-  spelling for attention.  Overrides are read at TRACE time: a cached
-  executable keeps the impl it was traced with (the shape-keyed stepper
-  cache contract); sweeps that flip impls build fresh steppers.  An
-  unknown impl name raises, and so does a forced impl that cannot run
-  on a TPU backend; no registered impl for the platform raises too.
-- **block-size autotune table** keyed on ``(S, D, heads)`` — the
-  committed ``_BUILTIN_TABLE`` (measured v5e entries, r3/r4 sweeps)
-  alone by default.  Only with ``PADDLE_TPU_AUTOTUNE_CACHE=path`` set
-  are learned entries read from / persisted to that file by
-  :func:`autotune_flash` (a micro-sweep: median-timed candidate block
-  pairs) — no state outside the checkout changes what gets compiled
-  unless asked.  ``PADDLE_TPU_FLASH_BLOCKS="bq,bk"`` overrides
-  per-process.
+  manager hook) and the env knob ``PADDLE_TPU_KERNEL_<KERNEL>=<impl>``.
+  Overrides are read at TRACE time: a cached executable keeps the impl
+  it was traced with (the shape-keyed stepper cache contract); sweeps
+  that flip impls build fresh steppers.  An unknown impl name raises,
+  and so does a forced impl that cannot run on a TPU backend; no
+  registered impl for the platform raises too.
+- **flash block sizes** — :func:`flash_blocks` is one static rule on
+  the (padded) sequence length; nothing outside the arguments changes
+  what gets compiled.
 - **roofline attribution** — kernels registered here are dispatched
   through :class:`TrackedKernel`, which wraps standalone (non-traced)
   calls in ``observability.compilestats.wrap`` so ``roofline_from_stats``
@@ -46,7 +40,6 @@ Selection decisions are recorded in the ``pt_kernel_*`` metrics
 (catalog.py; docs/kernels.md documents the dispatch rules).
 """
 import functools
-import json
 import os
 import threading
 from collections import namedtuple
@@ -56,8 +49,7 @@ import jax
 __all__ = [
     "register", "choose", "impl_fn", "force", "interpret_enabled",
     "record_select", "record_fallback", "TrackedKernel", "flash_blocks",
-    "autotune_flash", "autotune_table", "autotune_cache_path", "Selection",
-    "partitioned", "current_partition",
+    "Selection", "partitioned", "current_partition",
 ]
 
 # -- compile-surface vocabulary --------------------------------------------
@@ -75,9 +67,6 @@ XENT_BWD_SURFACE = "kernel.xent_bwd"
 QUANT_MATMUL_SURFACE = "kernel.quant_matmul"
 
 _INTERPRET_ENV = "PADDLE_TPU_KERNEL_INTERPRET"
-_ATTN_ENV = "PADDLE_TPU_ATTN_IMPL"          # legacy attention spelling
-_BLOCKS_ENV = "PADDLE_TPU_FLASH_BLOCKS"     # "bq,bk" process override
-_CACHE_ENV = "PADDLE_TPU_AUTOTUNE_CACHE"
 
 _LOCK = threading.Lock()
 _IMPLS = {}      # kernel -> [(impl_name, fn, platforms)]  (registration order)
@@ -143,18 +132,6 @@ def interpret_enabled():
     return on
 
 
-def _env_override(kernel):
-    ov = os.environ.get(f"PADDLE_TPU_KERNEL_{kernel.upper()}")
-    if ov:
-        return ov
-    if kernel == "attention":
-        legacy = os.environ.get(_ATTN_ENV)
-        if legacy:
-            # dense/flash are the documented legacy spellings
-            return {"dense": "xla", "flash": "pallas"}.get(legacy, legacy)
-    return None
-
-
 def choose(kernel, platform=None, book=True):
     """Pick the implementation for ``kernel`` on ``platform`` (default:
     the active jax backend).  Order: ``force()`` context > env override
@@ -173,7 +150,8 @@ def choose(kernel, platform=None, book=True):
         forced_name = _FORCED.get(kernel)
     if not entries:
         raise KeyError(f"unknown kernel {kernel!r}")
-    forced = forced_name or _env_override(kernel)
+    forced = forced_name or os.environ.get(
+        f"PADDLE_TPU_KERNEL_{kernel.upper()}")
     sel = None
     if forced:
         plats = next((pl_ for name, _fn, pl_ in entries if name == forced),
@@ -346,8 +324,8 @@ class TrackedKernel:
 
     Standalone (eager) dispatches go through one
     ``compilestats.wrap``-ed AOT surface per static-kwarg config, so the
-    roofline CLI attributes per-kernel FLOPs/bytes (and the autotune
-    sweep's measured dispatch latency) under the ``kernel.*`` surface.
+    roofline CLI attributes per-kernel FLOPs/bytes under the
+    ``kernel.*`` surface.
     Calls with tracer operands are *being traced into a larger surface*
     (the hapi train stepper): they pass straight through to the jitted
     callable, inline, and are attributed to the caller — the same
@@ -379,132 +357,18 @@ class TrackedKernel:
         return cs(*args)
 
 
-# -- flash block-size autotune table ---------------------------------------
-#
-# Keyed on (S, D, heads); ``heads`` is batch*heads of the folded kernel
-# layout (None = any).  Seeded with the measured v5e picks:
-#   r4 scan autotune, S=4096 D=64: (512,512) 6.97ms vs (512,1024) 7.36ms
-#     (the r3 (512,1024) pick was taken under ~5ms dispatch noise);
-#   r3: S in [1024,4096) prefers 256/256 for the head-folded kernel
-#     (smaller unrolled stack, better VPU/MXU overlap).
-# Entries must DIVIDE the (padded) sequence; flash_blocks() re-checks.
-_BUILTIN_TABLE = {
-    (4096, 64, None): {"block_q": 512, "block_k": 512},
-    (2048, 64, None): {"block_q": 256, "block_k": 256},
-    (1024, 64, None): {"block_q": 256, "block_k": 256},
-}
-
-_SWEEP_CANDIDATES = ((256, 256), (256, 512), (512, 256), (512, 512),
-                     (512, 1024), (1024, 512))
-
-_table_lock = threading.Lock()
-_learned_table = None      # {key-tuple: {"block_q", "block_k", "ms"}}
-
-
-def autotune_cache_path():
-    """The learned-table file, or None: opt-in through
-    ``PADDLE_TPU_AUTOTUNE_CACHE`` (the default is the committed
-    ``_BUILTIN_TABLE`` alone)."""
-    return os.environ.get(_CACHE_ENV) or None
-
-
-def _key_str(key):
-    return ",".join("*" if v is None else str(v) for v in key)
-
-
-def _key_of(s):
-    return tuple(None if t == "*" else int(t) for t in s.split(","))
-
-
-def _load_table():
-    global _learned_table
-    with _table_lock:
-        if _learned_table is not None:
-            return _learned_table
-        table = {}
-        path = autotune_cache_path()
-        if path is None:
-            _learned_table = table
-            return table
-        try:
-            with open(path, encoding="utf-8") as f:
-                raw = json.load(f)
-            for ks, rec in raw.get("entries", {}).items():
-                try:
-                    key = _key_of(ks)
-                    table[key] = {"block_q": int(rec["block_q"]),
-                                  "block_k": int(rec["block_k"]),
-                                  "ms": float(rec.get("ms", 0.0))}
-                except (KeyError, TypeError, ValueError):
-                    continue   # torn/foreign entry: skip, don't crash
-        except (OSError, ValueError):
-            pass
-        _learned_table = table
-        return table
-
-
-def _save_table(table):
-    path = autotune_cache_path()
-    if path is None:
-        return
-    try:
-        os.makedirs(os.path.dirname(path), exist_ok=True)
-        tmp = path + ".tmp"
-        with open(tmp, "w", encoding="utf-8") as f:
-            json.dump({"entries": {_key_str(k): v
-                                   for k, v in sorted(table.items())}},
-                      f, indent=1, sort_keys=True)
-            f.write("\n")
-        os.replace(tmp, path)
-    except OSError:
-        pass   # cache is an optimization; never fail the caller
-
-
-def autotune_table():
-    """The merged autotune table: learned (cache) entries over the
-    built-in measured seeds."""
-    merged = dict(_BUILTIN_TABLE)
-    merged.update(_load_table())
-    return merged
-
-
-def _divides(S, bq, bk):
-    return S % bq == 0 and S % bk == 0
-
+# -- flash block sizes --------------------------------------------------------
 
 def flash_blocks(S, D, heads=None):
     """(block_q, block_k) for the flash kernels at sequence ``S`` /
-    head_dim ``D``.  Priority: ``PADDLE_TPU_FLASH_BLOCKS`` env >
-    autotune table ((S, D, heads) exact, then (S, D, *)) > measured
-    static heuristic.  Every answer divides ``S`` (callers pad S to the
-    256 granule first); a non-dividing override/entry is ignored with a
-    warning so a stale table can never mis-slice the key loop."""
-    ov = os.environ.get(_BLOCKS_ENV)
-    if ov:
-        try:
-            bq, bk = (int(t) for t in ov.split(","))
-        except ValueError:
-            bq = bk = -1
-        if bq > 0 and bk > 0 and _divides(S, bq, bk):
-            return (bq, bk)
-        import warnings
-        warnings.warn(
-            f"{_BLOCKS_ENV}={ov} ignored: blocks must divide S={S} "
-            "(measurement would be attributed to the wrong config)",
-            RuntimeWarning)
-    table = autotune_table()
-    for key in ((S, D, heads), (S, D, None)):
-        rec = table.get(key)
-        if rec:
-            if _divides(S, rec["block_q"], rec["block_k"]):
-                return (rec["block_q"], rec["block_k"])
-            import warnings
-            warnings.warn(
-                f"autotune entry {_key_str(key)} -> "
-                f"({rec['block_q']},{rec['block_k']}) ignored: blocks "
-                f"must divide S={S} (stale/foreign cache entry)",
-                RuntimeWarning)
-    # measured static heuristic (the old _fwd_blocks rules)
+    head_dim ``D`` / ``heads`` (batch*heads of the folded layout).
+    Every answer divides ``S`` (callers pad S to the 256 granule
+    first).  The pairs are the v5e picks of the round 3-4 sweeps at
+    D=64: (512,512) at S=4096; 256/256 below it for the head-folded
+    kernel (smaller unrolled stack, better VPU/MXU overlap).  A sweep
+    on the chip passes ``block_q`` / ``block_k`` to the kernels
+    directly; a winner this rule does not give becomes a branch on its
+    ``(S, D, heads)`` here."""
     if S >= 4096 and S % 512 == 0:
         return (512, 512)
     if S % 256 == 0:
@@ -520,91 +384,7 @@ def flash_blocks(S, D, heads=None):
     return (S, S)
 
 
-def autotune_flash(S, D, heads=8, batch=1, candidates=None, iters=3,
-                   interpret=None, persist=True):
-    """Micro-sweep the flash forward over candidate block pairs at one
-    (S, D, heads) shape; the MEDIAN-of-``iters`` fastest candidate wins
-    (min-of-N was how the r3 table picked (512,1024) under dispatch
-    noise), is stored in the in-process table, persisted to the JSON
-    cache when ``PADDLE_TPU_AUTOTUNE_CACHE`` names one, and returned.  Per-candidate medians are recorded as
-    ``pt_compile_dispatch_ms`` (surface ``kernel.flash_fwd_lse``) so
-    the roofline row for the kernel carries *measured* latency, and the
-    winner lands in ``pt_kernel_autotune_best_ms``.
-
-    On TPU this times the compiled kernel; off-TPU it requires
-    interpret mode (tiny shapes only — CI exercises the plumbing, the
-    table, and the persistence, not the physics)."""
-    import statistics
-    import time as _time
-
-    import jax.numpy as jnp
-    import numpy as np
-
-    from .pallas import flash_attention as fa
-
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    cands = [c for c in (candidates or _SWEEP_CANDIDATES)
-             if _divides(S, c[0] if c[0] <= S else S,
-                         c[1] if c[1] <= S else S)]
-    if not cands:
-        raise ValueError(f"no candidate block pair divides S={S}")
-    rng = np.random.RandomState(0)
-    pack, _ = fa._packing(batch, heads, D)     # the layout dispatch runs
-    q, k, v = (pack(jnp.asarray(rng.randn(batch, S, heads, D)
-                                .astype("float32"))) for _ in range(3))
-
-    m = _metrics()
-    results = {}
-    for bq, bk in cands:
-        bq_, bk_ = min(bq, S), min(bk, S)
-
-        def run():
-            o, lse = fa._flash_bhsd_fwd(q, k, v, head_dim=D, causal=True,
-                                        block_q=bq_, block_k=bk_,
-                                        interpret=interpret)
-            # completion barrier: D2H of a dependent scalar (the
-            # bench methodology contract)
-            float(o.ravel()[0])
-
-        run()                      # compile + warm
-        times = []
-        for _ in range(iters):
-            t0 = _time.perf_counter()
-            run()
-            times.append((_time.perf_counter() - t0) * 1e3)
-        med = statistics.median(times)
-        results[(bq_, bk_)] = med
-        if m.enabled():
-            m.observe("pt_compile_dispatch_ms", med,
-                      surface=FLASH_FWD_LSE_SURFACE)
-    best = min(results, key=results.get)
-    # table keys carry the FOLDED head count (batch*heads): that is the
-    # shape component _fwd_blocks(S, D, B*H) looks up at dispatch —
-    # keying on the unfolded ``heads`` would park every batch>1 winner
-    # on a key no dispatch ever reads (and hand it to the wrong batch=1
-    # config)
-    key = (S, D, batch * heads)
-    rec = {"block_q": best[0], "block_k": best[1],
-           "ms": round(results[best], 4)}
-    table = _load_table()
-    with _table_lock:
-        table[key] = rec
-        if persist:
-            _save_table(table)
-    if m.enabled():
-        m.inc("pt_kernel_autotune_runs_total", kernel="attention")
-        m.set_gauge("pt_kernel_autotune_best_ms", results[best],
-                    kernel="attention", key=_key_str(key))
-    return {"key": key, "best": rec,
-            "candidates": {f"{a},{b}": round(ms, 4)
-                           for (a, b), ms in sorted(results.items())}}
-
-
 def _reset_for_tests():
-    """Drop learned autotune entries and force overrides (test isolation)."""
-    global _learned_table
-    with _table_lock:
-        _learned_table = None
+    """Drop force overrides (test isolation)."""
     with _LOCK:
         _FORCED.clear()

@@ -568,9 +568,8 @@ class FP8Linear(Layer):
     win is MEMORY — half the weight HBM footprint/bandwidth of bf16 —
     which pays exactly when the matmul is weight-bandwidth-bound (small
     batch / decode-style serving): **1.66x** over bf16 at M=32,
-    K=N=4096 (609 GB/s fp8 weight stream, repeat jitter <0.1%).
-    bench.py's fp8_linear config measures that regime; at large batch
-    the dot is compute-bound and fp8 ~ties bf16.
+    K=N=4096 (609 GB/s fp8 weight stream, repeat jitter <0.1%); at
+    large batch the dot is compute-bound and fp8 ~ties bf16.
     """
 
     def __init__(self, layer):

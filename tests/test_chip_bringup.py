@@ -1,6 +1,6 @@
 """PR 21 bring-up contracts: nothing that can hide the device.
 
-Importing the package (or the launcher, or bench) claims no chip; a
+Importing the package (or the launcher) claims no chip; a
 device, kernel impl, peak or mesh that is not there raises instead of
 falling back; the compile cache lives at one fixed place."""
 import os
@@ -28,7 +28,6 @@ def _run(code_or_argv, **env):
 def test_imports_leave_backend_uninitialised():
     r = _run("import paddle_tpu\n"
              "import paddle_tpu.distributed.launch.main\n"
-             "import bench\n"
              "from jax._src import xla_bridge\n"
              "assert not xla_bridge.backends_are_initialized()\n"
              "paddle_tpu.seed(3)\n"            # first key use builds it
@@ -88,20 +87,6 @@ class TestRegistryErrors:
         with pytest.raises(RuntimeError, match="no impl for platform"):
             kreg.choose("_only_tpu_kernel")
 
-    def test_autotune_table_is_builtin_only_unless_asked(self, monkeypatch,
-                                                         tmp_path):
-        monkeypatch.delenv("PADDLE_TPU_AUTOTUNE_CACHE", raising=False)
-        kreg._reset_for_tests()
-        assert kreg.autotune_cache_path() is None
-        assert kreg.autotune_table() == kreg._BUILTIN_TABLE
-        path = tmp_path / "learned.json"
-        path.write_text('{"entries": {"512,64,*": '
-                        '{"block_q": 128, "block_k": 128}}}')
-        monkeypatch.setenv("PADDLE_TPU_AUTOTUNE_CACHE", str(path))
-        kreg._reset_for_tests()
-        assert kreg.flash_blocks(512, 64) == (128, 128)
-        kreg._reset_for_tests()
-
 
 class TestChipModule:
     def test_peaks_table_knows_v5e_and_raises_on_unknown(self):
@@ -160,13 +145,3 @@ def test_spawn_refuses_several_workers_on_a_tpu_host(monkeypatch):
     monkeypatch.delenv("JAX_PLATFORMS", raising=False)
     with pytest.raises(RuntimeError, match="One process drives"):
         spawn(print, nprocs=2)
-
-
-def test_bench_never_starts_a_child_from_a_tpu_parent(monkeypatch):
-    sys.path.insert(0, REPO)
-    import bench
-    import jax
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    with pytest.raises(RuntimeError, match="one process per chip"):
-        bench._run_child([sys.executable, "-c", "pass"], dict(os.environ),
-                         10)

@@ -1,7 +1,7 @@
 """Compile-layer + per-request observability (ISSUE 10 tentpole):
 compile telemetry with the retrace sentinel, request-scoped serving
-traces, the roofline join, and their satellites (bench regression
-gate, idempotent telemetry snapshots).
+traces, the roofline join, and their satellite (idempotent telemetry
+snapshots).
 
 Acceptance anchors:
 - the retrace sentinel fires (with an old-vs-new signature diff) on a
@@ -14,9 +14,6 @@ Acceptance anchors:
   counts are identical with compile telemetry + tracing on vs off.
 """
 import json
-import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -33,8 +30,6 @@ from paddle_tpu.inference.serving import ServingEngine
 from paddle_tpu.models import GPTForPretraining, gpt3_tiny
 
 pytestmark = pytest.mark.obs
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -451,104 +446,6 @@ class TestRoofline:
 
 
 # -- satellites ------------------------------------------------------------
-
-def _bench_rec(value=100.0, mfu=0.5, useful=50.0, valid=True):
-    return {"metric": "gpt", "value": value,
-            "extra": {"mfu": mfu, "configs": {
-                "serving": {"useful_tokens_per_sec": useful,
-                            "valid": valid}}}}
-
-
-class TestBenchCompare:
-    def test_compare_flags_regressions_and_validity(self):
-        from paddle_tpu.analysis import bench_gate
-        rows = bench_gate.compare(_bench_rec(), _bench_rec(
-            value=90.0, useful=49.0, valid=False), threshold=0.05)
-        by = {r["key"]: r for r in rows}
-        assert by["gpt"]["regressed"]                 # -10% > 5%
-        assert not by["configs.serving.useful_tokens_per_sec"][
-            "regressed"]                              # -2% within
-        assert by["configs.serving.valid"]["regressed"]
-        assert not any(r["regressed"] for r in bench_gate.compare(
-            _bench_rec(), _bench_rec(value=99.0), threshold=0.05))
-
-    def test_disappeared_config_and_metric_regress(self):
-        from paddle_tpu.analysis import bench_gate
-        old = _bench_rec()
-        # whole config vanishes from the newer artifact -> regression
-        gone = {"metric": "gpt", "value": 100.0, "extra": {"mfu": 0.5,
-                                                          "configs": {}}}
-        rows = bench_gate.compare(old, gone, threshold=0.05)
-        assert any(r["regressed"] and "disappeared" in r["why"]
-                   for r in rows)
-        # ...but a config that newly reports skipped/error is flagged
-        # ONCE (unavailable), not once per vanished numeric field
-        skipped = {"metric": "gpt", "value": 100.0,
-                   "extra": {"mfu": 0.5, "configs": {
-                       "serving": {"skipped": "budget"}}}}
-        rows = bench_gate.compare(old, skipped, threshold=0.05)
-        bad = [r for r in rows if r["regressed"]]
-        assert len(bad) == 1 and bad[0]["key"].endswith(".unavailable")
-
-    def test_driver_wrapped_and_threshold_env(self, monkeypatch):
-        from paddle_tpu.analysis import bench_gate
-        monkeypatch.setenv(bench_gate.THRESHOLD_ENV, "0.5")
-        rows = bench_gate.compare({"parsed": _bench_rec()}["parsed"],
-                                  _bench_rec(value=60.0))
-        assert not any(r["regressed"] for r in rows)  # -40% < 50%
-
-    def test_opt_in_pass_and_cli(self, tmp_path):
-        from paddle_tpu.analysis import bench_gate, runner
-        # the bench pass never joins the default sweep
-        assert "bench" not in runner._passes()
-        assert "bench" in runner._optional_passes()
-        old = tmp_path / "BENCH_r01.json"
-        new = tmp_path / "BENCH_r02.json"
-        old.write_text(json.dumps(_bench_rec()))
-        new.write_text(json.dumps(_bench_rec(value=50.0)))
-
-        class Ctx:
-            root = str(tmp_path)
-        findings = bench_gate.BenchComparePass().run(Ctx())
-        # the synthetic artifacts lack the required long-context config
-        # (ISSUE 15), the quant artifact (ISSUE 19) AND the memory.json
-        # companion (ISSUE 20), so all three presence gates fire
-        # alongside the regression
-        assert sorted(f.code for f in findings) == \
-            ["bench-coverage", "bench-coverage", "bench-coverage",
-             "bench-regression"]
-        out = subprocess.run(
-            [sys.executable, os.path.join(REPO, "tools",
-                                          "bench_compare.py"),
-             str(old), str(new), "--json"],
-            capture_output=True, text=True,
-            env={**os.environ, "JAX_PLATFORMS": "cpu"})
-        assert out.returncode == 1
-        assert json.loads(out.stdout)["regressions"] == 1
-
-    def test_repo_bench_trajectory_gate_passes(self):
-        """The committed BENCH history must pass its own gate at the
-        default threshold (r4 -> r5 is flat), INCLUDING the required-
-        MFU presence gate (r5 carries gpt125m_s4096.mfu)."""
-        from paddle_tpu.analysis import runner
-        findings = runner.run_passes(passes=["bench"])
-        assert [f for f in findings
-                if f.code in ("bench-regression", "bench-coverage")] == []
-
-    def test_required_mfu_presence_gate(self):
-        """ISSUE 15: the long-context target must carry a numeric MFU
-        in the newest artifact — error/skip/absence all trip."""
-        from paddle_tpu.analysis import bench_gate
-        ok = {"extra": {"configs": {"gpt125m_s4096": {"mfu": 0.47}}}}
-        assert bench_gate.missing_required_mfu(ok) == []
-        for cfgs in ({}, {"gpt125m_s4096": {"error": "boom"}},
-                     {"gpt125m_s4096": {"skipped": "budget"}},
-                     {"gpt125m_s4096": {"mfu": None}},
-                     {"gpt125m_s4096": {"mfu": True}}):
-            rec = {"extra": {"configs": cfgs}}
-            assert bench_gate.missing_required_mfu(rec) == \
-                ["gpt125m_s4096"], cfgs
-
 
 class TestSnapshotIdempotency:
     def test_write_jsonl_replace_run_is_idempotent(self, tmp_path):

@@ -473,13 +473,18 @@ class TestChaosBundles:
                                      cooldown_s=300.0))
         root = str(tmp_path / "guard_ckpts")
         model = _reg_model()
+        # loss_spike off: the random regression targets trip the spike
+        # detector too, and this scenario is the nonfinite ladder alone
         cfg = guardian.GuardianConfig(skip_limit=2, skip_window=2,
                                       ckpt_every=5, ckpt_root=root,
-                                      spike_warmup=5)
+                                      loss_spike=False)
         model.fit(_batches(30), epochs=1, verbose=0, guardian=cfg,
                   callbacks=[_ArmAt()])
         (rb,) = guardian.events("rollback")
         assert rb["rollbacks"] == 1
+        before = [e for e in guardian.events("skip_step")
+                  if e["step"] <= rb["step"]]
+        assert before and all(e["reason"] == "nonfinite" for e in before)
         names = _bundles(d)
         assert len(names) == 1                      # exactly ONE bundle
         bdir = os.path.join(d, names[0])
@@ -498,9 +503,16 @@ class TestChaosBundles:
 
 # -- doctor ----------------------------------------------------------------
 
+def _healthy_fit_prom(tmp_path):
+    """Today's exporter over a healthy few-step fit: the sink the
+    doctor reads from another process."""
+    _reg_model().fit(_batches(4), epochs=1, verbose=0)
+    return export.write_prometheus(str(tmp_path / "train.prom"))
+
+
 class TestDoctor:
-    def test_healthy_committed_telemetry_is_no_alerts(self, capsys):
-        prom = os.path.join(REPO, "telemetry", "train.prom")
+    def test_healthy_fit_telemetry_is_no_alerts(self, tmp_path, capsys):
+        prom = _healthy_fit_prom(tmp_path)
         assert report.main(["doctor", "--prom", prom]) == 0
         out = capsys.readouterr().out
         assert "verdict: no alerts" in out
@@ -547,8 +559,8 @@ class TestDoctor:
         assert report.main(["doctor", "/nonexistent/bundle"]) == 1
         assert "error" in capsys.readouterr().err
 
-    def test_report_doctor_flag(self, capsys):
-        prom = os.path.join(REPO, "telemetry", "train.prom")
+    def test_report_doctor_flag(self, tmp_path, capsys):
+        prom = _healthy_fit_prom(tmp_path)
         assert report.main(["report", "--prom", prom, "--doctor"]) == 0
         out = capsys.readouterr().out
         assert "paddle_tpu doctor" in out

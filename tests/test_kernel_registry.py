@@ -1,8 +1,9 @@
 """Kernel registry + fused-kernel dispatch (ISSUE 15).
 
 Covers the `ops/registry.py` policy layer (platform selection, env /
-``sdp_kernel`` overrides, interpret mode, the block-size autotune
-table + cached micro-sweep), the attention dispatch ladder (padding so
+``sdp_kernel`` overrides, interpret mode, the block-size rule and the
+environment variables the kernels may read), the attention dispatch
+ladder (padding so
 S need not be a multiple of 512, the key-bias mask path, constraint
 fallbacks), compilestats tracking of standalone kernel dispatches, and
 the dense-vs-flash TRAIN-STEP gradient parity suite (GPT causal /
@@ -13,7 +14,8 @@ flash vs the XLA dense path — forward within atol/rtol 2e-3, gradients
 within 5e-3 relative-max; the XLA fallback paths are the dense math
 itself and therefore bitwise.
 """
-import json
+import ast
+import glob
 import os
 
 import numpy as np
@@ -27,12 +29,9 @@ from paddle_tpu.ops import registry as kreg
 
 
 @pytest.fixture(autouse=True)
-def _clean_registry(monkeypatch, tmp_path):
-    monkeypatch.setenv("PADDLE_TPU_AUTOTUNE_CACHE",
-                       str(tmp_path / "autotune.json"))
-    for var in ("PADDLE_TPU_ATTN_IMPL", "PADDLE_TPU_KERNEL_INTERPRET",
-                "PADDLE_TPU_KERNEL_ATTENTION", "PADDLE_TPU_KERNEL_XENT",
-                "PADDLE_TPU_FLASH_BLOCKS"):
+def _clean_registry(monkeypatch):
+    for var in ("PADDLE_TPU_KERNEL_INTERPRET",
+                "PADDLE_TPU_KERNEL_ATTENTION", "PADDLE_TPU_KERNEL_XENT"):
         monkeypatch.delenv(var, raising=False)
     kreg._reset_for_tests()
     yield
@@ -57,18 +56,6 @@ class TestChoose:
         monkeypatch.setenv("PADDLE_TPU_KERNEL_INTERPRET", "1")
         sel = kreg.choose("attention")
         assert sel.impl == "pallas" and sel.interpret and not sel.forced
-
-    def test_legacy_attn_env_spellings(self, monkeypatch):
-        monkeypatch.setenv("PADDLE_TPU_ATTN_IMPL", "dense")
-        assert kreg.choose("attention").impl == "xla"
-        monkeypatch.setenv("PADDLE_TPU_ATTN_IMPL", "flash")
-        # forcing the off-platform Pallas impl without interpret mode
-        # would dispatch an uncompilable kernel: platform default wins
-        sel = kreg.choose("attention")
-        assert sel.impl == "xla" and not sel.forced
-        monkeypatch.setenv("PADDLE_TPU_KERNEL_INTERPRET", "1")
-        sel = kreg.choose("attention")
-        assert sel.impl == "pallas" and sel.forced and sel.interpret
 
     def test_generic_kernel_env(self, monkeypatch):
         monkeypatch.setenv("PADDLE_TPU_KERNEL_INTERPRET", "1")
@@ -118,61 +105,102 @@ class TestChoose:
 
 
 # ---------------------------------------------------------------------------
-# autotune table
+# flash block sizes, and the knobs a kernel may read
 # ---------------------------------------------------------------------------
 
-class TestAutotune:
-    def test_builtin_measured_entries(self):
-        assert kreg.flash_blocks(4096, 64) == (512, 512)
-        assert kreg.flash_blocks(1024, 64) == (256, 256)
-        # heuristic fallback for shapes the table does not cover
-        assert kreg.flash_blocks(2560, 96) == (256, 256)
+_KERNEL_ENV_PREFIX = "PADDLE_TPU_KERNEL_"
+_OPS_DIR = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "paddle_tpu", "ops")
 
-    def test_env_override_and_divisibility_guard(self, monkeypatch):
-        monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCKS", "128,128")
-        assert kreg.flash_blocks(1024, 64) == (128, 128)
-        monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCKS", "768,768")
-        with pytest.warns(RuntimeWarning):
-            bq, bk = kreg.flash_blocks(1024, 64)
-        assert (bq, bk) == (256, 256)   # table answer, override ignored
 
-    def test_micro_sweep_populates_and_persists(self, tmp_path):
-        res = kreg.autotune_flash(256, 32, heads=2, batch=1,
-                                  candidates=((128, 128), (256, 256)),
-                                  iters=1, interpret=True)
-        assert res["best"]["block_q"] in (128, 256)
-        assert set(res["candidates"]) == {"128,128", "256,256"}
-        # the sweep's winner now answers flash_blocks for that key
-        assert kreg.flash_blocks(256, 32, 2) == (
-            res["best"]["block_q"], res["best"]["block_k"])
-        # ... and survives a fresh process (simulated by dropping the
-        # in-memory table): the JSON cache is the durable copy
-        cache = json.load(open(kreg.autotune_cache_path()))
-        assert "256,32,2" in cache["entries"]
-        kreg._reset_for_tests()
-        assert kreg.flash_blocks(256, 32, 2) == (
-            res["best"]["block_q"], res["best"]["block_k"])
+def _module_strings(tree):
+    """Module-level ``NAME = "literal"`` assignments."""
+    return {t.id: node.value.value for node in tree.body
+            if isinstance(node, ast.Assign)
+            and isinstance(node.value, ast.Constant)
+            and isinstance(node.value.value, str)
+            for t in node.targets if isinstance(t, ast.Name)}
 
-    def test_sweep_key_folds_batch_into_heads(self):
-        # dispatch looks blocks up at the FOLDED head count
-        # (_fwd_blocks(S, D, B*H)); a batch>1 sweep must land its
-        # winner on that key, not on the unfolded ``heads``
-        res = kreg.autotune_flash(256, 32, heads=2, batch=2,
-                                  candidates=((128, 128),),
-                                  iters=1, interpret=True)
-        assert tuple(res["key"]) == (256, 32, 4)
-        assert kreg.flash_blocks(256, 32, 4) == (128, 128)
-        # the unfolded key stays unpopulated (heuristic answers)
-        assert kreg.flash_blocks(256, 32, 2) == (256, 256)
 
-    def test_blocks_always_divide_s(self):
-        # the must-divide-S contract covers the LAST-resort fallback
-        # too: direct callers (incubate flash_attention gates on
-        # S % 128 == 0 only) can present S = 640, and a non-dividing
-        # answer makes the kernel silently skip the key tail
-        for S in (640, 384, 1152, 100):
-            bq, bk = kreg.flash_blocks(S, 64)
-            assert S % bq == 0 and S % bk == 0, (S, bq, bk)
+def _env_reads(tree):
+    """(lineno, key expression or None) of every use of ``os.environ`` /
+    ``os.getenv`` in ``tree``; None = a use that is no keyed read."""
+    parent = {c: n for n in ast.walk(tree) for c in ast.iter_child_nodes(n)}
+    for node in ast.walk(tree):
+        if isinstance(node, ast.ImportFrom) and node.module == "os" and any(
+                a.name in ("environ", "getenv") for a in node.names):
+            yield node.lineno, None
+        if not (isinstance(node, ast.Attribute)
+                and isinstance(node.value, ast.Name)
+                and node.value.id == "os"
+                and node.attr in ("environ", "getenv")):
+            continue
+        up = parent[node]
+        key = None
+        if node.attr == "getenv":
+            if isinstance(up, ast.Call) and up.func is node and up.args:
+                key = up.args[0]
+        elif isinstance(up, ast.Subscript) and up.value is node:
+            key = up.slice
+        elif isinstance(up, ast.Attribute) and up.attr == "get":
+            call = parent[up]
+            if isinstance(call, ast.Call) and call.func is up and call.args:
+                key = call.args[0]
+        yield node.lineno, key
+
+
+def _allowed_env_key(key, strings):
+    name = strings.get(key.id) if isinstance(key, ast.Name) \
+        else getattr(key, "value", None)
+    if name == _KERNEL_ENV_PREFIX + "INTERPRET":
+        return True
+    # the per-kernel override: f"PADDLE_TPU_KERNEL_{kernel.upper()}"
+    return (isinstance(key, ast.JoinedStr) and len(key.values) == 2
+            and isinstance(key.values[0], ast.Constant)
+            and key.values[0].value == _KERNEL_ENV_PREFIX
+            and isinstance(key.values[1], ast.FormattedValue))
+
+
+class TestFlashBlocks:
+    @pytest.mark.parametrize("S, D, expected", [
+        (4096, 64, (512, 512)),      # S >= 4096 and S % 512 == 0
+        (2048, 64, (256, 256)),      # the fit cell
+        (1024, 64, (256, 256)),
+        (2560, 96, (256, 256)),
+        (2048, 128, (256, 256)),     # the chat cell's prefill head size
+        # the must-divide-S contract covers the last resort too: direct
+        # callers (incubate flash_attention gates on S % 128 == 0 only)
+        # can present S = 640, and a non-dividing answer makes the
+        # kernel silently skip the key tail
+        (640, 64, (128, 128)),
+        (384, 64, (128, 128)),
+        (1152, 64, (128, 128)),
+        (100, 64, (100, 100)),       # unaligned: one whole-sequence block
+    ])
+    def test_rule_pairs_divide_s(self, S, D, expected):
+        for heads in (None, 16):
+            assert kreg.flash_blocks(S, D, heads) == expected
+        assert S % expected[0] == 0 and S % expected[1] == 0
+
+    def test_kernels_read_no_environment_but_the_registry_knobs(self):
+        """A kernel's implementation and block sizes follow from platform
+        and shape.  ``paddle_tpu/ops`` reads PADDLE_TPU_KERNEL_INTERPRET
+        and the PADDLE_TPU_KERNEL_<K> override, nothing else: the next
+        knob is a diff to this test."""
+        files = glob.glob(os.path.join(_OPS_DIR, "**", "*.py"),
+                          recursive=True)
+        assert files
+        bad, seen = [], 0
+        for path in sorted(files):
+            with open(path, encoding="utf-8") as f:
+                tree = ast.parse(f.read(), path)
+            strings = _module_strings(tree)
+            for lineno, key in _env_reads(tree):
+                seen += 1
+                if key is None or not _allowed_env_key(key, strings):
+                    bad.append(f"{os.path.relpath(path, _OPS_DIR)}:{lineno}")
+        assert not bad, bad
+        assert seen >= 2          # the walk still finds the two it allows
 
     def test_s640_kernel_matches_dense(self):
         # the S=640 shape that used to get (512,512): rows 512+ were
@@ -190,16 +218,6 @@ class TestAutotune:
         ref = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
         np.testing.assert_allclose(np.asarray(o), np.asarray(ref),
                                    rtol=2e-3, atol=2e-3)
-
-    def test_zero_block_override_warns_not_crashes(self, monkeypatch):
-        monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCKS", "256,0")
-        with pytest.warns(RuntimeWarning):
-            assert kreg.flash_blocks(1024, 64) == (256, 256)
-
-    def test_torn_cache_is_skipped(self, tmp_path):
-        with open(kreg.autotune_cache_path(), "w") as f:
-            f.write("{not json")
-        assert kreg.flash_blocks(1024, 64) == (256, 256)
 
 
 # ---------------------------------------------------------------------------
@@ -260,7 +278,7 @@ class TestDispatch:
         q, k, v = self._qkv(S=300)
         ref = F.scaled_dot_product_attention(q, k, v, is_causal=False)
         monkeypatch.setenv("PADDLE_TPU_KERNEL_INTERPRET", "1")
-        monkeypatch.setenv("PADDLE_TPU_ATTN_IMPL", "flash")
+        monkeypatch.setenv("PADDLE_TPU_KERNEL_ATTENTION", "pallas")
         out = F.scaled_dot_product_attention(q, k, v, is_causal=False)
         np.testing.assert_allclose(np.asarray(out._value),
                                    np.asarray(ref._value),
@@ -274,7 +292,7 @@ class TestDispatch:
         m = paddle.to_tensor(mnp)
         ref = F.scaled_dot_product_attention(q, k, v, attn_mask=m)
         monkeypatch.setenv("PADDLE_TPU_KERNEL_INTERPRET", "1")
-        monkeypatch.setenv("PADDLE_TPU_ATTN_IMPL", "flash")
+        monkeypatch.setenv("PADDLE_TPU_KERNEL_ATTENTION", "pallas")
         out = F.scaled_dot_product_attention(q, k, v, attn_mask=m)
         np.testing.assert_allclose(np.asarray(out._value),
                                    np.asarray(ref._value),
@@ -283,7 +301,7 @@ class TestDispatch:
     def test_per_query_mask_falls_back(self, monkeypatch):
         from paddle_tpu.nn.functional.attention import _select_flash
         monkeypatch.setenv("PADDLE_TPU_KERNEL_INTERPRET", "1")
-        monkeypatch.setenv("PADDLE_TPU_ATTN_IMPL", "flash")
+        monkeypatch.setenv("PADDLE_TPU_KERNEL_ATTENTION", "pallas")
         reg = paddle.observability.get_registry()
         m0 = reg.get("pt_kernel_fallbacks_total")
         base = m0.value(kernel="attention", reason="mask") if m0 else 0
@@ -323,7 +341,7 @@ class TestDispatch:
                              mask_is_keybias=False, scale=None,
                              heads=(8, 8))
         assert not auto.use                       # S < 1024, not forced
-        monkeypatch.setenv("PADDLE_TPU_ATTN_IMPL", "flash")
+        monkeypatch.setenv("PADDLE_TPU_KERNEL_ATTENTION", "pallas")
         forced = _select_flash(256, 256, 64, causal=True, has_mask=False,
                                mask_is_keybias=False, scale=None,
                                heads=(8, 8))
@@ -380,7 +398,7 @@ class TestXentDispatch:
 # ---------------------------------------------------------------------------
 
 _FLASH_ENV = {"PADDLE_TPU_KERNEL_INTERPRET": "1",
-              "PADDLE_TPU_ATTN_IMPL": "flash"}
+              "PADDLE_TPU_KERNEL_ATTENTION": "pallas"}
 
 
 def _grad_rel_max(ga, gb):

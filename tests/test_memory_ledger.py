@@ -17,8 +17,7 @@ Acceptance anchors:
   counter, the trace metadata and ``report --requests``; the timeline
   guardian clock offset is minted once with no capture; two
   near-simultaneous watchdog trips coalesce into ONE bundle and
-  retention never deletes a mid-write dot-tmp dir; the bench gate
-  requires ``telemetry/memory.json`` next to committed ``BENCH_*``.
+  retention never deletes a mid-write dot-tmp dir.
 """
 import collections
 import json
@@ -39,8 +38,6 @@ from paddle_tpu.observability import (compilestats, doctor, export,
                                       timeline, tracing, watch)
 
 pytestmark = pytest.mark.obs
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -509,44 +506,6 @@ class TestGuardianClockOffset:
         names = {e["name"] for e in evs if e.get("cat") == "memory"}
         assert "pt_memory_live_bytes{pool=kv_pages}" in names
         assert "pt_memory_kv_occupancy" in names
-
-
-# -- satellite 5: bench gate requires memory.json ---------------------------
-
-class TestBenchGateMemoryArtifact:
-    def test_required_next_to_bench_artifacts(self, tmp_path):
-        from paddle_tpu.analysis import bench_gate
-        root = str(tmp_path)
-        assert bench_gate.missing_memory_artifact(root) == []
-        with open(os.path.join(root, "BENCH_r01.json"), "w") as f:
-            json.dump({"metric": "tokens_per_sec", "value": 1.0}, f)
-        rows = bench_gate.missing_memory_artifact(root)
-        assert rows and rows[0][0] == bench_gate.MEMORY_ARTIFACT
-        # a full snapshot (placeholder rows included) satisfies it
-        memory.write_memory_json(
-            os.path.join(root, "telemetry", "memory.json"))
-        assert bench_gate.missing_memory_artifact(root) == []
-
-    def test_flags_each_missing_surface(self, tmp_path):
-        from paddle_tpu.analysis import bench_gate
-        root = str(tmp_path)
-        with open(os.path.join(root, "BENCH_r01.json"), "w") as f:
-            json.dump({"metric": "tokens_per_sec", "value": 1.0}, f)
-        path = memory.write_memory_json(
-            os.path.join(root, "telemetry", "memory.json"))
-        with open(path, encoding="utf-8") as f:
-            doc = json.load(f)
-        del doc["surfaces"]["generation.decode"]
-        with open(path, "w", encoding="utf-8") as f:
-            json.dump(doc, f)
-        rows = bench_gate.missing_memory_artifact(root)
-        assert [(r[1]) for r in rows] == ["generation.decode"]
-
-    def test_committed_artifact_is_valid(self):
-        """The repo's own committed telemetry/memory.json must satisfy
-        the gate it ships (every registry surface has a static row)."""
-        from paddle_tpu.analysis import bench_gate
-        assert bench_gate.missing_memory_artifact(REPO) == []
 
 
 # -- report --memory --------------------------------------------------------

@@ -284,13 +284,19 @@ class TestFitEscalationLadder:
         # poisoned window, and complete training — fully automatic
         root = str(tmp_path / "guard_ckpts")
         model = _reg_model()
+        # loss_spike off: the random regression targets trip the spike
+        # detector too, and this scenario is the nonfinite ladder alone
         cfg = guardian.GuardianConfig(skip_limit=2, skip_window=2,
                                       ckpt_every=5, ckpt_root=root,
-                                      spike_warmup=5)
+                                      loss_spike=False)
         model.fit(_batches(30), epochs=1, verbose=0, guardian=cfg,
                   callbacks=[_ArmAt(9, "guardian.poison_batch", "skip*5")])
         (rb,) = guardian.events("rollback")
         assert rb["restored_step"] > 0 and rb["rollbacks"] == 1
+        before = [e for e in guardian.events("skip_step")
+                  if e["step"] <= rb["step"]]
+        assert len(before) == 3       # skip, skip, the trip that rolls back
+        assert all(e["reason"] == "nonfinite" for e in before)
         assert ckpt.latest_checkpoint(root) is not None   # COMMITTED dirs
         # training completed past the poison with finite state
         res = model.train_batch([_batches(1)[0][0]], [_batches(1)[0][1]])

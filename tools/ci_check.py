@@ -1,23 +1,13 @@
 #!/usr/bin/env python
-"""Single CI entry point: lint sweep -> tier-1 tests -> opt-in bench
-gate, in that order, stopping at the first failing stage.
+"""Single CI entry point: lint sweep -> opt-in suites -> tier-1 tests,
+in that order, stopping at the first failing stage.
 
-The three gates existed separately (`tools/lint.py`, the tier-1 pytest
-invocation from ROADMAP.md, `tools/bench_compare.py`); nothing ran them
-as one pipeline, so "is this tree green" was three commands and a
-README lookup.  This wires them into one:
+`tools/lint.py` and the tier-1 pytest invocation from ROADMAP.md as one
+pipeline, so "is this tree green" is one command:
 
     python tools/ci_check.py                  # lint + tests
     python tools/ci_check.py --changed-only   # git-diff-scoped lint,
                                               # then tests
-    python tools/ci_check.py --bench-gate     # + BENCH_r* trajectory
-                                              # diff (opt-in: bench
-                                              # numbers move with
-                                              # machine load)
-    python tools/ci_check.py --doctor         # + doctor smoke over the
-                                              # committed telemetry/
-                                              # snapshots (healthy ->
-                                              # 'no alerts', exit 0)
     python tools/ci_check.py --chaos          # + the chaos-marked
                                               # elastic-resume + PD-
                                               # handoff suites (opt-in:
@@ -33,25 +23,25 @@ README lookup.  This wires them into one:
                                               # suites (HBM memory
                                               # ledger, tracing, flight
                                               # recorder / watchdog)
-    python tools/ci_check.py --skip-tests     # lint (+gate) only
+    python tools/ci_check.py --skip-tests     # lint (+ opt-in
+                                              # suites) only
     python tools/ci_check.py --lint-only      # lint sweep alone: the
                                               # pre-commit fast path
                                               # (<10s, no pytest, no
-                                              # opt-in gates)
+                                              # opt-in suites)
 
 Stages:
 
 1. **lint** — the full static-analysis suite (`python -m
    paddle_tpu.analysis`, baseline-suppressed).  `--changed-only`
    passes through to the runner's git-diff scoping.
-2. **tests** — tier-1: ``pytest tests/ -m 'not slow'`` on the CPU
+2. **opt-in suites** (``--obs``, ``--chaos``, ``--kernels``) — each a
+   pytest subset described above.
+3. **tests** — tier-1: ``pytest tests/ -m 'not slow'`` on the CPU
    backend (the ROADMAP.md verify command without the log plumbing).
    ``--pytest-args "..."`` appends extra flags (e.g. ``-x -k serving``).
-3. **bench gate** (``--bench-gate``) — diff the newest two committed
-   ``BENCH_r*.json`` via the `bench` pass (threshold:
-   ``PADDLE_BENCH_THRESHOLD``, default 5%).
 
-Exit code: the first failing stage's (lint/bench: 1; tests: pytest's).
+Exit code: the first failing stage's (lint: 1; tests: pytest's).
 """
 import argparse
 import os
@@ -89,45 +79,6 @@ def run_tests(extra):
     print("$", " ".join(shlex.quote(c) for c in cmd), flush=True)
     rc = subprocess.call(cmd, cwd=REPO)
     print(f"tests: {'OK' if rc == 0 else f'FAIL (rc={rc})'} "
-          f"({time.perf_counter() - t0:.1f}s)")
-    return rc
-
-
-def run_doctor():
-    """Doctor smoke over the committed telemetry/ snapshots: every
-    artifact must parse clean and yield the 'no alerts' verdict, so
-    the committed files and the doctor/report parsers can never drift
-    apart (the ISSUE 13 CI satellite; opt-in like the bench gate)."""
-    import glob
-    from paddle_tpu.observability import doctor
-    t0 = _stage("doctor smoke over committed telemetry/ (opt-in)")
-    tdir = os.path.join(REPO, "telemetry")
-    proms = sorted(glob.glob(os.path.join(tdir, "*.prom")))
-    if not proms:
-        print("doctor: no committed telemetry snapshots found")
-        return 1
-    rc = 0
-    for prom in proms:
-        tag = os.path.splitext(os.path.basename(prom))[0]
-        jsonl = os.path.join(tdir, tag + ".jsonl")
-        trace = os.path.join(tdir, tag + "_requests.trace.json")
-        ev = doctor.evidence_from_sinks(
-            prom=prom,
-            jsonl=jsonl if os.path.exists(jsonl) else None,
-            trace=trace if os.path.exists(trace) else None)
-        result = doctor.diagnose(ev)
-        healthy = result["verdict"] == "no alerts"
-        print(f"  {tag}: verdict={result['verdict']!r} "
-              f"({len(result['sources'])} sink(s), "
-              f"{len(result['diagnoses'])} signal(s))")
-        for note in result["notes"]:
-            print(f"    note: {note}")
-        if not healthy:
-            for d in result["diagnoses"][:3]:
-                for e in d["evidence"]:
-                    print(f"    [{d['cause']}] {e}")
-            rc = 1
-    print(f"doctor: {'OK' if rc == 0 else 'FAIL'} "
           f"({time.perf_counter() - t0:.1f}s)")
     return rc
 
@@ -194,31 +145,12 @@ def run_obs():
     return rc
 
 
-def run_bench_gate():
-    from paddle_tpu.analysis import runner
-    t0 = _stage("bench trajectory gate (opt-in)")
-    findings = runner.run_passes(passes=["bench"])
-    for f in findings:
-        print(f"  [{f.code}] {f.message}")
-    rc = 1 if any(f.code in ("bench-regression", "bench-coverage")
-                  for f in findings) else 0
-    print(f"bench gate: {'OK' if rc == 0 else 'FAIL'} "
-          f"({time.perf_counter() - t0:.1f}s)")
-    return rc
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser(
-        description="lint sweep -> tier-1 pytest -> opt-in bench gate")
+        description="lint sweep -> opt-in suites -> tier-1 pytest")
     ap.add_argument("--changed-only", action="store_true",
                     help="scope the lint sweep to the git diff "
                          "(tests still run in full)")
-    ap.add_argument("--bench-gate", action="store_true",
-                    help="also diff the newest two BENCH_r*.json")
-    ap.add_argument("--doctor", action="store_true",
-                    help="also run the doctor smoke over the committed "
-                         "telemetry/ snapshots (healthy artifacts must "
-                         "parse clean with a 'no alerts' verdict)")
     ap.add_argument("--chaos", action="store_true",
                     help="also run the chaos-marked elastic-resume "
                          "tests (8-device CPU-proxy mesh) and the "
@@ -232,11 +164,11 @@ def main(argv=None):
                          "memory ledger, tracing, flight recorder / "
                          "watchdog)")
     ap.add_argument("--skip-tests", action="store_true",
-                    help="lint (and gate) only")
+                    help="lint (and opt-in suites) only")
     ap.add_argument("--lint-only", action="store_true",
                     help="run the lint sweep alone and stop — the "
                          "pre-commit fast path (no pytest, no opt-in "
-                         "gates; combine with --changed-only for the "
+                         "suites; combine with --changed-only for the "
                          "inner loop)")
     ap.add_argument("--pytest-args", default="",
                     help="extra pytest flags, quoted (e.g. '-x -k "
@@ -247,17 +179,9 @@ def main(argv=None):
     if rc != 0:
         return rc
     if args.lint_only:
-        print("\nci_check: LINT GREEN (--lint-only: tests and gates "
+        print("\nci_check: LINT GREEN (--lint-only: tests and suites "
               "skipped)")
         return 0
-    if args.doctor:
-        rc = run_doctor()
-        if rc != 0:
-            return rc
-    if args.bench_gate:
-        rc = run_bench_gate()
-        if rc != 0:
-            return rc
     if args.obs:
         rc = run_obs()
         if rc != 0:
