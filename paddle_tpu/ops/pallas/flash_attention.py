@@ -17,6 +17,21 @@ contraction of 64 costs, and the products land lane-dense.  Shapes the
 lane tiles cannot address (:func:`lane_tiled` false: D=96, an odd head
 count at D=64, ...) run the same kernels over a transposed
 (B*H, S, D) copy, one head a tile.
+
+The backward's block visit.  Every rung (head-folded, q-grid one-pass,
+two-pass) visits a (q block, k block) pair through one function,
+:func:`_bwd_step`, which computes the scores TRANSPOSED, sᵀ = k·qᵀ as
+(keys, queries): lse and Δ = rowsum(dO ∘ O) broadcast from the (1,
+queries) lane rows lse is stored as, dV = pᵀ·dO and dK = dsᵀ·q are plain
+matmuls, and only dQ = (dsᵀ)ᵀ·k contracts a transposed operand.  Which
+pairs are visited, and how, is a static schedule, :func:`_pair_visits`:
+a pair wholly under the causal diagonal is one unmasked visit (the
+q-grid kernels walk those with a dynamic trip count), a pair the
+diagonal crosses is visited by halves of its keys — each against the
+queries from its own half of the q block on, so the quarter wholly
+above the diagonal (probabilities that are exact zeros) is never
+computed, and masked only where the diagonal crosses the visit — and a
+pair wholly above it is never visited.
 """
 import functools
 import math
@@ -38,8 +53,13 @@ _FUSED_BWD_MAX_SD = 8192 * 64
 # head-folded kernels fully unroll the q/k block loops, and Mosaic does
 # NOT reuse stack slots across unrolled bodies — past these S*D caps the
 # s/p temporaries overflow the scoped VMEM (fwd S=4096 measured 41MB).
-# Measured crossover: mh bwd beats grid-fused only at S<=1024 (6.1 vs
-# 5.5ms at S=2048).
+# The backward's cap predates the pair tiles (it was set where the head-
+# folded backward lost to the q-grid one at S=2048, 6.1 vs 5.5 ms, one
+# head a program).  Re-measured in PR 33 at B=4, S=2048, H=16, D=64
+# (PERF.md §6): head-folded 1.115 ms a call against q-grid fused 1.308
+# (the parent's kernels: 1.237 against 1.584), and both fit their VMEM.
+# The cap stands until a PR moves it with the masked routing that reads
+# it (nn/functional/attention.py) and the D=128 tile, which has 16 MB.
 _MH_FWD_MAX_SD = 2048 * 64
 _MH_BWD_MAX_SD = 1024 * 64
 
@@ -51,6 +71,18 @@ def _fwd_blocks(S, D=64, heads=None):
     silently skip the last 512 keys) — and the rule guarantees that."""
     from ..registry import flash_blocks
     return flash_blocks(S, D, heads)
+
+
+def _bwd_blocks(S):
+    """The backward's block (q and k alike): 512, or S whole under that,
+    where it divides S; else the largest of 256 / 128 that does.  S
+    arrives padded to the 256 granule, and the kernels size their loops
+    as S // block: at S=768 a block of 512 dropped the last 256 keys and
+    left their dq rows unwritten."""
+    for block in (min(DEFAULT_BLOCK_Q, S), 256, 128):
+        if S % block == 0:
+            return block
+    return S
 
 
 def lane_tiled(H, D):
@@ -111,26 +143,135 @@ def _softmax_step(s, v, acc, m, l):
     return acc, m_new, l
 
 
-def _bwd_step(q, k, v, do, lse, delta, s):
-    """The backward of one (q, k) block from its masked scores ``s``:
-    (dV, dK, dQ) contributions.  ``q`` is pre-scaled, so dsᵀ·q is dK.
-    Matmul operands stay in the input dtype (bf16 on the fast path) with
-    fp32 MXU accumulation — casting them to fp32 would fall off the
-    native MXU path (measured ~2x slower)."""
-    p = jnp.exp(s - lse)                              # softmax via saved lse
-    pb = p.astype(do.dtype)
-    dv = jnp.dot(pb.T, do, preferred_element_type=jnp.float32)
-    dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-    ds = (p * (dp - delta)).astype(q.dtype)
-    dk = jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
-    dq = jnp.dot(ds, k, preferred_element_type=jnp.float32)
+def _causal_mask_t(st, rel):
+    """The causal mask over transposed scores (keys down the sublanes,
+    queries along the lanes); ``rel`` = first key - first query."""
+    k_idx = rel + jax.lax.broadcasted_iota(jnp.int32, (st.shape[0], 1), 0)
+    q_idx = jax.lax.broadcasted_iota(jnp.int32, (1, st.shape[1]), 1)
+    return jnp.where(q_idx >= k_idx, st, -1e30)
+
+
+_NT = (((1,), (1,)), ((), ()))      # a · bᵀ
+_TN = (((0,), (0,)), ((), ()))      # aᵀ · b
+
+
+def _bwd_step(q, k, v, do, lse, delta, rel=None, bias=None):
+    """The backward of one (q, k) block pair: (dV, dK, dQ) contributions.
+
+    The scores are computed TRANSPOSED, sᵀ = k·qᵀ as (keys, queries), so
+    that dV = pᵀ·dO and dK = dsᵀ·q are plain matmuls and only dQ =
+    (dsᵀ)ᵀ·k takes a transposed operand (the splash-attention dkv
+    kernel's orientation).  ``lse`` and ``delta`` are the (1, queries)
+    lane rows they are stored as, broadcast down the sublanes.  ``q`` is
+    pre-scaled, so dsᵀ·q is dK.  ``rel`` (first key - first query) asks
+    for the causal mask: only a pair the diagonal crosses passes it.
+    ``bias`` is a (keys, 1) column.  Matmul operands stay in the input
+    dtype (bf16 on the fast path) with fp32 MXU accumulation — casting
+    them to fp32 would fall off the native MXU path (measured ~2x
+    slower); exp runs in fp32."""
+    st = jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32)
+    if bias is not None:
+        st = st + bias
+    if rel is not None:
+        st = _causal_mask_t(st, rel)
+    pt = jnp.exp(st - lse)                            # softmax via saved lse
+    dv = jnp.dot(pt.astype(do.dtype), do, preferred_element_type=jnp.float32)
+    dpt = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
+    dst = (pt * (dpt - delta)).astype(q.dtype)
+    dk = jnp.dot(dst, q, preferred_element_type=jnp.float32)
+    dq = jax.lax.dot_general(dst, k, _TN, preferred_element_type=jnp.float32)
     return dv, dk, dq
 
 
-def _delta(do, o):
-    """rowsum(dO ∘ O); ``do`` already holds one head's lanes only."""
-    return jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1,
-                   keepdims=True)
+# -- the backward's visit schedule (pure: no kernel needed to test it) ------
+
+def _sub(block):
+    """Side of the sub-blocks a block on the diagonal is visited by:
+    half the block where the halves are whole lane tiles, else the block."""
+    return block // 2 if block % (2 * _LANES) == 0 else block
+
+
+def _whole_visit(block_q, block_k, masked=False):
+    """The schedule of a pair visited as it is, in one piece."""
+    return [(0, block_q, 0, block_k, masked)]
+
+
+def _pair_visits(rel, block_q, block_k, causal):
+    """How the backward visits one (q block, k block) pair whose first
+    key lies ``rel`` positions after its first query: a list of
+    ``(q_off, rows, k_off, cols, masked)`` rectangles.  A pair wholly
+    under the diagonal (or any pair when not causal) is one unmasked
+    visit; a pair wholly above it is not visited; a pair the diagonal
+    crosses is visited by halves of its keys, each against the queries
+    from the half of the q block its first key falls in to the block's
+    end: the sub-blocks wholly above the diagonal are skipped (their
+    probabilities are exact zeros) and a visit is masked only where the
+    diagonal crosses it.  One visit a key half and not one a sub-block:
+    the longer run of queries measured 3% faster at the fit cell's
+    shape (PERF.md §6, PR 33), the same work."""
+    if not causal or rel + block_k - 1 <= 0:
+        return _whole_visit(block_q, block_k)
+    sq, sk = _sub(block_q), _sub(block_k)
+    visits = []
+    for ko in range(0, block_k, sk):
+        qo = max(0, (rel + ko) // sq * sq)
+        if qo < block_q:
+            visits.append((qo, block_q - qo, ko, sk, rel + ko + sk - 1 > qo))
+    return visits
+
+
+def _add_rows(parts, off, x, sub):
+    """``x``, the rows from ``off`` on, into the ``sub``-row pieces."""
+    for j in range(0, x.shape[0], sub):
+        piece = x if x.shape[0] == sub else x[j:j + sub]
+        parts[off + j] = piece if off + j not in parts \
+            else parts[off + j] + piece
+
+
+def _join_rows(parts, n):
+    """The n-row block whose equal pieces are ``parts`` ({row offset:
+    piece}); rows no visit touched are zeros."""
+    piece = next(iter(parts.values()))
+    if piece.shape[0] == n:
+        return piece
+    return jnp.concatenate([parts.get(off, jnp.zeros_like(piece))
+                            for off in range(0, n, piece.shape[0])], 0)
+
+
+def _q_rows(do, o, lse_at, *visit_lists):
+    """What a q block's visits read per query, as the (1, rows) lane rows
+    they broadcast from: ``{(q_off, rows): (lse, Δ)}`` with Δ = rowsum(dO
+    ∘ O) (``do`` already holds one head's lanes only).  lse is stored as
+    such rows and ``lse_at(q_off, rows)`` loads one; Δ's column is turned
+    once per range (a lane slice of a row is no operand Mosaic takes)."""
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1,
+                    keepdims=True)
+    out = {}
+    for visits in visit_lists:
+        for qo, rows, *_ in visits:
+            if (qo, rows) not in out:
+                out[qo, rows] = (lse_at(qo, rows),
+                                 delta[qo:qo + rows, 0][None, :])
+    return out
+
+
+def _bwd_pair(q, k, v, do, q_rows, rel, visits, bias=None):
+    """(dV, dK, dQ) of one (q block, k block) pair, summed over its
+    ``visits`` (:func:`_pair_visits`).  ``rel`` = the pair's first key -
+    its first query (traced where the pair's place moves with the grid)."""
+    sq = min(rows for _, rows, *_ in visits)
+    sk = min(cols for *_, cols, _ in visits)
+    dvs, dks, dqs = {}, {}, {}
+    for qo, rows, ko, cols, masked in visits:
+        qs, ks = slice(qo, qo + rows), slice(ko, ko + cols)
+        dv, dk, dq = _bwd_step(q[qs], k[ks], v[ks], do[qs], *q_rows[qo, rows],
+                               rel + ko - qo if masked else None,
+                               None if bias is None else bias[ks])
+        _add_rows(dvs, ko, dv, sk)
+        _add_rows(dks, ko, dk, sk)
+        _add_rows(dqs, qo, dq, sq)
+    return (_join_rows(dvs, k.shape[0]), _join_rows(dks, k.shape[0]),
+            _join_rows(dqs, q.shape[0]))
 
 
 # -- q-grid kernels: one q (or kv) block a program --------------------------
@@ -174,44 +315,55 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
 
 def _dq_block(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dk_acc, dv_acc, *,
               scale, causal, block_k, head_dim):
-    """dQ of one q block against every k block: dS = P ∘ (dO·Vᵀ − Δ),
-    dQ = scale · dS·K, with Δ = rowsum(dO ∘ O) computed here.  With
+    """dQ of one q block against every k block: dSᵀ = Pᵀ ∘ (V·dOᵀ − Δ),
+    dQ = scale · (dSᵀ)ᵀ·K, with Δ = rowsum(dO ∘ O) computed here.  With
     ``dk_acc``/``dv_acc`` ((S, W) fp32 scratch) the same visit of each
     (q, k) block pair also accumulates dV = Pᵀ·dO and dK = dSᵀ·Q."""
     block_q, W = q_ref.shape
-    qi = pl.program_id(2)
+    q_lo = pl.program_id(2) * block_q
     q2 = q_ref[:] * scale
     do2 = do_ref[:]
     o2 = o_ref[:]
-    nkb = k_ref.shape[0] // block_k
-    if causal:
-        # only k blocks at or below this q block's diagonal contribute
-        nkb = jnp.minimum(
-            (qi * block_q + block_q + block_k - 1) // block_k, nkb)
+    whole = _whole_visit(block_q, block_k)
+    if not causal:
+        n_under, crossed = k_ref.shape[0] // block_k, []
+    else:
+        # the k blocks wholly under this q block's diagonal: a dynamic
+        # count, no mask; the blocks above it are never visited
+        n_under = q_lo // block_k
+        if block_q % block_k == 0:
+            # the diagonal crosses block_q // block_k blocks, each at a
+            # fixed place against the q block: the static schedule
+            crossed = [(rel, _pair_visits(rel, block_q, block_k, True))
+                       for rel in range(0, block_q, block_k)]
+        else:
+            # a k block wider than the q block: where the diagonal
+            # crosses it moves with the grid, so it is masked whole
+            crossed = [(n_under * block_k - q_lo,
+                        _whole_visit(block_q, block_k, masked=True))]
 
     def head(g, lanes, dq2):
         # the other heads' lanes of q and dO are zero, so their lanes of
         # dK and dV get exact zeros and the scratch sums over heads
         q = _keep(lanes, q2)
         do = _keep(lanes, do2)
-        delta = _delta(do, o2)
-        lse = lse_ref[g, 0, :][:, None]
+        q_rows = _q_rows(do, o2, lambda qo, rows: lse_ref[g, :, pl.ds(qo, rows)],
+                         whole, *(visits for _, visits in crossed))
 
-        def body(i, dq):
-            kb = pl.ds(i * block_k, block_k)
-            k = k_ref[kb, :]
-            v = v_ref[kb, :]
-            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-            if causal:
-                s = _causal_mask(s, qi * block_q, i * block_k)
-            dv, dk, dq_i = _bwd_step(q, k, v, do, lse, delta, s)
+        def pair(k_lo, rel, visits):
+            kb = pl.ds(k_lo, block_k)
+            dv, dk, dq = _bwd_pair(q, k_ref[kb, :], v_ref[kb, :], do, q_rows,
+                                   rel, visits)
             if dk_acc is not None:
                 dv_acc[kb, :] += dv
                 dk_acc[kb, :] += dk
-            return dq + dq_i
+            return dq
 
-        dq = jax.lax.fori_loop(0, nkb, body,
-                               jnp.zeros((block_q, W), jnp.float32))
+        dq = jax.lax.fori_loop(
+            0, n_under, lambda i, dq: dq + pair(i * block_k, None, whole),
+            jnp.zeros((block_q, W), jnp.float32))
+        for rel, visits in crossed:
+            dq = dq + pair(q_lo + rel, rel, visits)
         return _keep(lanes, dq, dq2)
 
     dq = _over_heads(block_q, W, head_dim, head,
@@ -256,32 +408,46 @@ def _flash_dkv_kernel(k_ref, v_ref, q_ref, do_ref, o_ref, lse_ref, dk_ref,
                       dv_ref, *, scale, causal, block_q, head_dim):
     """dK/dV for one kv block: dV = Pᵀ·dO;  dK = scale · dSᵀ·Q.
     k_ref/v_ref: (block_k, W); q_ref/do_ref/o_ref: (S, W); lse_ref:
-    (G, 1, S)."""
+    (G, 1, S).  The mirror of :func:`_dq_block`: the q blocks the
+    diagonal crosses first (by the static schedule where their place
+    against the kv block is fixed), then those wholly under it."""
     block_k, W = k_ref.shape
-    ki = pl.program_id(2)
+    k_lo = pl.program_id(2) * block_k
     k = k_ref[:]
     v = v_ref[:]
     nqb = q_ref.shape[0] // block_q
-    # causal: only q blocks at or after this kv block contribute
-    first = (ki * block_k) // block_q if causal else 0
+    whole = _whole_visit(block_q, block_k)
+    if not causal:
+        first_under, crossed = 0, []
+    elif block_k % block_q == 0:
+        crossed = [(-r, _pair_visits(-r, block_q, block_k, True))
+                   for r in range(0, block_k, block_q)]
+        first_under = (k_lo + block_k) // block_q
+    else:       # a q block taller than the kv block: masked whole
+        first = k_lo // block_q
+        crossed = [(k_lo - first * block_q,
+                    _whole_visit(block_q, block_k, masked=True))]
+        first_under = first + 1
 
     def head(g, lanes, carry):
-        def body(i, carry):
-            dk, dv = carry
-            qb = pl.ds(i * block_q, block_q)
+        def pair(q_lo, rel, visits, carry):
+            qb = pl.ds(q_lo, block_q)
             q = _keep(lanes, q_ref[qb, :] * scale)
             do = _keep(lanes, do_ref[qb, :])
-            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-            if causal:
-                s = _causal_mask(s, i * block_q, ki * block_k)
-            dv_i, dk_i, _ = _bwd_step(
-                q, k, v, do, lse_ref[g, 0, qb][:, None],
-                _delta(do, o_ref[qb, :]), s)
-            return dk + dk_i, dv + dv_i
-        return jax.lax.fori_loop(first, nqb, body, carry)
+            q_rows = _q_rows(
+                do, o_ref[qb, :],
+                lambda qo, rows: lse_ref[g, :, pl.ds(q_lo + qo, rows)], visits)
+            dv, dk, _ = _bwd_pair(q, k, v, do, q_rows, rel, visits)
+            return carry[0] + dk, carry[1] + dv
+
+        for rel, visits in crossed:
+            carry = pair(k_lo - rel, rel, visits, carry)
+        return jax.lax.fori_loop(
+            first_under, nqb,
+            lambda i, carry: pair(i * block_q, None, whole, carry), carry)
 
     zero = jnp.zeros((block_k, W), jnp.float32)
-    dk, dv = _over_heads(block_k, W, head_dim, head, (zero, zero))
+    dk, dv = _over_heads(block_q, W, head_dim, head, (zero, zero))
     dk_ref[:] = dk.astype(dk_ref.dtype)
     dv_ref[:] = dv.astype(dv_ref.dtype)
 
@@ -359,29 +525,29 @@ def _flash_bwd_mh_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
     dv_acc[:] = jnp.zeros_like(dv_acc)
 
     def head(g, lanes, _):
-        for qi in range(S // block_q):
-            q_lo = qi * block_q
+        for q_lo in range(0, S, block_q):
             qb = pl.ds(q_lo, block_q)
             # the other heads' lanes of q and dO are zero, so their
             # lanes of dK and dV get exact zeros: the scratch sums heads
             q = _keep(lanes, q_ref[qb, :] * scale)
             do = _keep(lanes, do_ref[qb, :])
-            delta = _delta(do, o_ref[qb, :])
-            lse = lse_ref[g, 0, qb][:, None]
+            # a pair wholly above the diagonal has no visits
+            pairs = [(k_lo, _pair_visits(k_lo - q_lo, block_q, block_k,
+                                         causal))
+                     for k_lo in range(0, S, block_k)]
+            q_rows = _q_rows(
+                do, o_ref[qb, :],
+                lambda qo, rows: lse_ref[g, :, pl.ds(q_lo + qo, rows)],
+                *(visits for _, visits in pairs))
             dq = jnp.zeros((block_q, W), jnp.float32)
-            for ki in range(S // block_k):
-                k_lo = ki * block_k
-                if causal and k_lo > q_lo + block_q - 1:
+            for k_lo, visits in pairs:
+                if not visits:
                     continue
                 kb = pl.ds(k_lo, block_k)
-                k = k_ref[kb, :]
-                s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-                if bias_ref is not None:
-                    s = s + bias_ref[0, kb][None, :]
-                if causal and k_lo + block_k - 1 > q_lo:
-                    s = _causal_mask(s, q_lo, k_lo)
-                dv, dk, dq_i = _bwd_step(q, k, v_ref[kb, :], do, lse,
-                                         delta, s)
+                dv, dk, dq_i = _bwd_pair(
+                    q, k_ref[kb, :], v_ref[kb, :], do, q_rows,
+                    k_lo - q_lo, visits,
+                    None if bias_ref is None else bias_ref[0, kb][:, None])
                 dv_acc[kb, :] += dv
                 dk_acc[kb, :] += dk
                 dq = dq + dq_i
@@ -696,7 +862,9 @@ def flash_attention_bwd(q, k, v, o, lse, do, bias=None, causal=False,
     pack, unpack = _packing(B, H, D)
     _check_bias_cap(bias, S, D, _MH_BWD_MAX_SD, "backward")
     ops = (pack(q), pack(k), pack(v), pack(o), lse, pack(do))
-    kw = dict(head_dim=D, causal=causal, interpret=interpret)
+    block = _bwd_blocks(S)
+    kw = dict(head_dim=D, causal=causal, block_q=block, block_k=block,
+              interpret=interpret)
     # ladder: head-folded one-pass (smallest grids, whole tile resident)
     # -> q-grid one-pass (cross-step dk/dv scratch) -> two-pass
     if S * D <= _MH_BWD_MAX_SD:

@@ -128,10 +128,23 @@ def test_q_grid_forward_parity(H, D, causal):
     np.testing.assert_array_equal(o2, o)
 
 
-@pytest.mark.parametrize("impl", [fa._flash_bhsd_bwd_mh,
-                                  fa._flash_bhsd_bwd_fused,
-                                  fa._flash_bhsd_bwd],
-                         ids=["head_folded", "fused", "two_pass"])
+def _bwd_of(impl, q, k, v, g, causal, bias=None, **blocks):
+    B, _, H, D = q.shape
+    pack, unpack = fa._packing(B, H, D)
+    o, lse = fa.flash_attention_fwd_lse(q, k, v, bias, causal=causal,
+                                        interpret=True)
+    extra = {} if bias is None else {"bias": bias}
+    got = impl(pack(q), pack(k), pack(v), pack(o), lse, pack(g), head_dim=D,
+               causal=causal, interpret=True, **extra, **blocks)
+    return [unpack(x) for x in got]
+
+
+_RUNGS = pytest.mark.parametrize(
+    "impl", [fa._flash_bhsd_bwd_mh, fa._flash_bhsd_bwd_fused,
+             fa._flash_bhsd_bwd], ids=["head_folded", "fused", "two_pass"])
+
+
+@_RUNGS
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("H,D", [(2, 64), (4, 64), (2, 128), (1, 64)])
 def test_bwd_impls_multiblock_parity(impl, causal, H, D):
@@ -140,15 +153,95 @@ def test_bwd_impls_multiblock_parity(impl, causal, H, D):
     two-pass kernels that the S*D routing otherwise hides from CI), in
     place and transposed, must match the dense vjp."""
     q, k, v, g = _qkvg(H, H, D, seed=2)
-    B = q.shape[0]
-    pack, unpack = fa._packing(B, H, D)
-    o, lse = fa.flash_attention_fwd_lse(q, k, v, causal=causal,
-                                        interpret=True)
-    got = impl(pack(q), pack(k), pack(v), pack(o), lse, pack(g),
-               head_dim=D, causal=causal, block_q=128, block_k=128,
-               interpret=True)
-    _assert_grads([unpack(x) for x in got],
-                  _dense_grads(q, k, v, g, causal))
+    got = _bwd_of(impl, q, k, v, g, causal, block_q=128, block_k=128)
+    _assert_grads(got, _dense_grads(q, k, v, g, causal))
+
+
+# -- the backward's block visit ---------------------------------------------
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("block_q,block_k,S", [
+    (256, 256, 512), (256, 256, 1024), (256, 128, 512), (256, 128, 1024),
+    (128, 256, 512), (128, 256, 1024), (512, 512, 2048)])
+def test_visit_schedule(block_q, block_k, S, causal):
+    """The schedule alone, no kernel: over every (q block, k block) pair
+    the visited sub-blocks cover what may be attended exactly once, visit
+    nothing wholly above the diagonal, and mask only what it crosses."""
+    live = np.tril(np.ones((S, S), bool)) if causal else np.ones((S, S), bool)
+    seen = np.zeros((S, S), int)
+    for q_lo in range(0, S, block_q):
+        for k_lo in range(0, S, block_k):
+            for qo, rows, ko, cols, masked in fa._pair_visits(
+                    k_lo - q_lo, block_q, block_k, causal):
+                assert rows % 128 == 0 and cols % 128 == 0
+                assert qo + rows <= block_q and ko + cols <= block_k
+                at = (slice(q_lo + qo, q_lo + qo + rows),
+                      slice(k_lo + ko, k_lo + ko + cols))
+                seen[at] += 1
+                assert masked == (not live[at].all())
+                sq, sk = fa._sub(block_q), fa._sub(block_k)
+                for r in range(at[0].start, at[0].stop, sq):
+                    for c in range(at[1].start, at[1].stop, sk):
+                        assert live[r:r + sq, c:c + sk].any(), \
+                            "visits a sub-block of exact zeros"
+    assert (seen[live] == 1).all() and seen.max() == 1
+    if (block_q, block_k, S, causal) == (512, 512, 2048, True):
+        # the fit cell: 6 whole pairs and 4 diagonal ones at 3 quarters
+        assert seen.sum() == 9 * 512 * 512
+
+
+@_RUNGS
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("H,D", [(2, 64), (2, 128), (1, 64)])
+def test_bwd_sub_block_parity(impl, causal, H, D):
+    """Every backward rung where the diagonal blocks are visited by
+    halves (S=512, blocks of 256, sub-blocks of 128): pair tiles, one
+    head a tile, and the transposed single head."""
+    q, k, v, g = _qkvg(H, H, D, S=512, seed=7)
+    got = _bwd_of(impl, q, k, v, g, causal, block_q=256, block_k=256)
+    _assert_grads(got, _dense_grads(q, k, v, g, causal))
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("H,D", [(2, 64), (2, 128), (1, 64)])
+def test_bwd_sub_block_key_bias_parity(H, D, causal):
+    """The key bias (a column of the transposed scores) under the same
+    sub-blocking, head-folded rung."""
+    B = 2
+    q, k, v, g = _qkvg(H, H, D, S=512, B=B, seed=8)
+    bias = _key_bias(B, 512)
+    got = _bwd_of(fa._flash_bhsd_bwd_mh, q, k, v, g, causal, bias,
+                  block_q=256, block_k=256)
+    _assert_grads(got, _dense_grads(q, k, v, g, causal, bias))
+
+
+@_RUNGS
+@pytest.mark.parametrize("block_q,block_k", [(256, 128), (128, 256)])
+def test_bwd_uneven_blocks_parity(impl, block_q, block_k):
+    """block_q != block_k, causal: the q-grid kernels keep the static
+    schedule where the diagonal's place in a block is fixed and mask the
+    one block whole where it moves with the grid."""
+    q, k, v, g = _qkvg(2, 2, 64, S=512, seed=9)
+    got = _bwd_of(impl, q, k, v, g, True, block_q=block_q, block_k=block_k)
+    _assert_grads(got, _dense_grads(q, k, v, g, True))
+
+
+@pytest.mark.parametrize("S,block", [(256, 256), (384, 384), (512, 512),
+                                     (768, 256), (1024, 512), (1280, 256),
+                                     (640, 128), (2048, 512), (200, 200)])
+def test_bwd_block_divides_S(S, block):
+    assert fa._bwd_blocks(S) == block and S % block == 0
+
+
+def test_bwd_at_a_length_512_does_not_divide():
+    """S=768 (any length that pads to an odd multiple of 256): the
+    (B, S, H, D) entry's backward with a block of 512 left dq rows
+    unwritten (NaN) and dropped a third of the keys."""
+    q, k, v, g = _qkvg(2, 2, 64, S=768, seed=10)
+    o, lse = fa.flash_attention_fwd_lse(q, k, v, causal=True, interpret=True)
+    got = fa.flash_attention_bwd(q, k, v, o, lse, g, causal=True,
+                                 interpret=True)
+    _assert_grads(got, _dense_grads(q, k, v, g, True))
 
 
 @pytest.mark.parametrize("causal", [False, True])

@@ -7,6 +7,7 @@ that asks for more scoped VMEM than it may have — the pair tiles hold
 two heads' k/v and dk/dv where one head a program held one.  Nothing
 runs, so this says nothing about results or times.
 """
+import functools
 import os
 
 import pytest
@@ -29,6 +30,10 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _sds(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
 @pytest.mark.parametrize("B,S,H,D,causal,masked", [
     (4, 2048, 16, 64, True, False),     # gpt3-medium: head-folded fwd, fused bwd, pair tiles
     (2, 2048, 16, 128, True, False),    # gpt3-xl: q-grid fwd, fused bwd, one head a tile
@@ -39,14 +44,16 @@ def one_chip():
     (2, 4096, 16, 64, True, False),
     (2, 8192, 16, 64, True, False),     # the fused backward at its S*D cap
     (1, 16384, 2, 64, True, False),     # two-pass backward: a grid of one tile, lowering only
+    (2, 16384, 16, 64, True, False),    # two-pass backward, pair tiles, blocks double-buffered
+    (2, 768, 12, 64, True, False),      # the backward's 256 block: 512 does not divide S
     (2, 2048, 8, 32, True, False),      # four heads a tile
     (2, 2048, 12, 96, True, False),     # transposed: D=96
     (2, 2048, 1, 64, True, False),      # transposed: a single head of 64
 ], ids=["medium", "xl", "gpt125m", "bert_mask", "s4096", "fused_cap",
-        "two_pass", "d32", "d96_transposed", "h1_transposed"])
+        "two_pass", "two_pass_pairs", "s768", "d32", "d96_transposed",
+        "h1_transposed"])
 def test_kernels_compile_for_v5e(one_chip, B, S, H, D, causal, masked):
-    def sds(shape, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    sds = functools.partial(_sds, one_chip)
     x = sds((B, S, H, D))
     bias = (sds((B, S), jnp.float32),) if masked else ()
     # conftest asks for "highest" everywhere; the chip's programs run at
@@ -59,3 +66,33 @@ def test_kernels_compile_for_v5e(one_chip, B, S, H, D, causal, masked):
                 x, x, x, x, sds((B, H, S), jnp.float32), x, *bias).compile()
     assert "tpu_custom_call" in fwd.as_text()
     assert "tpu_custom_call" in bwd.as_text()
+
+
+@pytest.mark.parametrize("block_q,block_k", [(256, 256), (512, 256),
+                                             (256, 512), (1024, 512)])
+@pytest.mark.parametrize("entry", ["_flash_bhsd_bwd_fused", "_flash_bhsd_bwd"])
+def test_backward_blocks_compile_for_v5e(one_chip, entry, block_q, block_k):
+    """The q-grid backward rungs at the fit cell's shape with the blocks a
+    sweep passes: the static schedule (blocks that nest on the diagonal)
+    and the block masked whole where the diagonal's place moves with the
+    grid (a traced offset in the mask) both lower."""
+    B, S, H, D = 4, 2048, 16, 64
+    sds = functools.partial(_sds, one_chip)
+    x = sds((B, S, H * D))
+    with jax.default_matmul_precision(None):
+        bwd = jax.jit(lambda q, k, v, o, lse, g: getattr(fa, entry)(
+            q, k, v, o, lse, g, head_dim=D, causal=True, block_q=block_q,
+            block_k=block_k)).lower(
+                x, x, x, x, sds((B, H, S), jnp.float32), x).compile()
+    assert "tpu_custom_call" in bwd.as_text()
+
+
+def test_the_kernels_ask_for_no_more_vmem():
+    """``_params`` is the parent's: 16 MB of scoped VMEM a head of the
+    tile (at most six), the compiler's default for a tile of one head."""
+    mb = 1024 * 1024
+    assert fa._params(128, 64).vmem_limit_bytes == 32 * mb
+    assert fa._params(128, 32).vmem_limit_bytes == 64 * mb
+    assert fa._params(128, 16).vmem_limit_bytes == 96 * mb
+    assert fa._params(128, 128).vmem_limit_bytes is None
+    assert fa._params(64, 64).vmem_limit_bytes is None
