@@ -13,8 +13,7 @@ import time
 
 import numpy as np
 
-from benchmark import compare, harness, weights
-from benchmark.drivers import gpt_program
+from benchmark import compare, harness
 
 
 # Of the gaps that ``gaps_between`` reads, those that ``correct`` holds
@@ -83,12 +82,12 @@ def _norms(leaves, start=None):
         for k, a in leaves.items()}
 
 
-def leaf_norms(named_arrays, start_named=None):
+def leaf_norms(family, named_arrays, start_named=None):
     """{benchmark leaf: float} of {program name: array}, one device call;
     with ``start_named``, of the change from those arrays."""
     import jax
-    leaves = gpt_program.split_leaves(named_arrays)
-    start = gpt_program.split_leaves(start_named) if start_named else None
+    leaves = family.split_leaves(named_arrays)
+    start = family.split_leaves(start_named) if start_named else None
     return {k: float(v) for k, v in
             jax.device_get(jax.jit(_norms)(leaves, start)).items()}
 
@@ -97,14 +96,13 @@ def build(run):
     """The program's side: network with the seeded weights, optimizer,
     ``paddle.Model`` prepared as the mix says."""
     import paddle_tpu as paddle
-    from paddle_tpu.models import GPTPretrainingCriterion
     t = run.traffic
-    net = gpt_program.build_network(run.model, run.seed)
+    net = run.family.build_network(run.model, run.seed)
     opt_spec = dict(t["optimizer"])
     opt_cls = getattr(paddle.optimizer, opt_spec.pop("name"))
     opt = opt_cls(parameters=net.parameters(), **opt_spec)
     model = paddle.Model(net)
-    model.prepare(opt, GPTPretrainingCriterion(), amp_configs=dict(t["amp"]))
+    model.prepare(opt, run.family.build_loss(), amp_configs=dict(t["amp"]))
     return model
 
 
@@ -125,22 +123,24 @@ def check_steps(run, model, ids):
             seen["loss"].append(float(logs["loss"]))
             if step == 0:
                 opt = model.train_state_dict()["opt"]
-                m1 = leaf_norms({k: v["moment1"] for k, v in opt.items()})
+                m1 = leaf_norms(run.family,
+                                {k: v["moment1"] for k, v in opt.items()})
                 seen["grad1_norm"] = {k: v / (1 - beta1)
                                       for k, v in m1.items()}
 
     model.fit(TimedLoader(_loader(ids[:B * n], B)), epochs=1, verbose=0,
               callbacks=[Probe()])
     named = {k: p._value for k, p in model.network.named_parameters()}
-    start = weights.make_per_layer(run.model, run.seed)
+    start = run.family.make_per_layer(run.model, run.seed)
     seen["delta_norm"] = leaf_norms(
-        named, {k: start[gpt_program.leaf_name(k)] for k in named})
+        run.family, named, {k: start[run.family.leaf_name(k)] for k in named})
     return seen
 
 
 def window(run, model, ids):
     """The measured window: one ``Model.fit`` call that ends at the first
-    step boundary at or after ``--seconds``."""
+    step boundary at or after ``--seconds`` of ``run.tracer.clock()`` (the
+    host's clock, less what a traced run's trace write held)."""
     import paddle_tpu as paddle
     t = run.traffic
     B, S = t["batch"], t["seq_len"]
@@ -154,7 +154,7 @@ def window(run, model, ids):
 
         def on_train_batch_end(self, step, logs=None):
             state["span"].__exit__(None, None, None)
-            now = time.perf_counter()
+            now = run.tracer.clock()
             state["steps"] += 1
             state["ends"].append(now)
             if run.tracer.active:
@@ -163,7 +163,7 @@ def window(run, model, ids):
             if now - state["t0"] >= run.seconds:
                 model.stop_training = True
 
-    state["t0"] = time.perf_counter()
+    state["t0"] = run.tracer.clock()
     model.fit(loader, epochs=1_000_000, verbose=0, callbacks=[Window()])
     run.tracer.stop()
     elapsed = state["ends"][-1] - state["t0"]
@@ -178,11 +178,10 @@ def window(run, model, ids):
 
 def reference_steps(run, ids, precision="highest", fault=None):
     """The plain reference over the same first steps."""
-    from benchmark.reference import gpt as reference
     t = run.traffic
     B, n = t["batch"], t["check_steps"]
-    return reference.train_steps(
-        run.model, weights.make_stacked(run.model, run.seed),
+    return run.reference.train_steps(
+        run.model, run.family.make_stacked(run.model, run.seed),
         ids[:B * n].reshape(n, B, -1), precision=precision,
         lr=t["optimizer"]["learning_rate"],
         wd=t["optimizer"]["weight_decay"], fault=fault)

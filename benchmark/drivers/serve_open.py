@@ -6,14 +6,19 @@ due, then calls ``step()``; it times every request from when it was DUE
 and reports how late the generator ran.  Arrivals stop at ``--seconds``;
 the engine then drains so that every due request gets its latencies, but
 tokens and time after the window count for nothing.
+
+Every time of the window is read from ``run.tracer.clock()``, which
+stands still while the profiler's stop writes a traced run's trace from
+inside the loop: that time is the benchmark's own and no request's, so
+the requests not yet due still arrive at their own spacing, and the close
+and the drain move with them.
 """
 import gc
 import time
 
 import numpy as np
 
-from benchmark import arrivals, harness, weights
-from benchmark.drivers import gpt_program
+from benchmark import arrivals, harness
 
 
 class Client:
@@ -33,9 +38,9 @@ def build(run, net=None):
     ``ServingEngine`` built as the mix says."""
     from paddle_tpu.inference import ServingEngine
     if net is None:
-        net = gpt_program.build_network(run.model, run.seed)
+        net = run.family.build_network(run.model, run.seed)
     else:
-        gpt_program.put_weights(net, run.model, run.seed)
+        run.family.put_weights(net, run.model, run.seed)
     net.eval()
     return net, ServingEngine(net, **run.traffic["engine"])
 
@@ -58,12 +63,13 @@ def warm_up(engine, schedule):
 def window(run, engine, schedule):
     clients = [Client(r) for r in schedule]
     traced = {"chunks": 0, "prompt_lens": [], "positions": []}
-    state = {"t0": None, "close": None, "done": 0}
+    state = {"close": None, "done": 0}
     backlog = []       # (elapsed, requests submitted and not yet finished)
+    clock = run.tracer.clock
 
     def on_token(client):
         def callback(req, token, last):
-            now = time.perf_counter()
+            now = clock()
             if client.first is None:
                 client.first = now
                 if run.tracer.active:
@@ -81,16 +87,16 @@ def window(run, engine, schedule):
         return callback
 
     late, nxt, n = [], 0, len(clients)
-    state["t0"] = t0 = time.perf_counter()
+    t0 = clock()
     state["close"] = t0 + run.seconds
     give_up = state["close"] + run.traffic["drain_s"]
     while True:
-        now = time.perf_counter()
+        now = clock()
         run.tracer.tick(now - t0)
         with harness.span("bench.arrivals"):
             while nxt < n and t0 + clients[nxt].request.due_s <= now:
                 c = clients[nxt]
-                late.append(time.perf_counter() - (t0 + c.request.due_s))
+                late.append(clock() - (t0 + c.request.due_s))
                 c.handle = engine.submit(c.request.prompt,
                                          c.request.max_new_tokens,
                                          callback=on_token(c))
@@ -101,13 +107,13 @@ def window(run, engine, schedule):
                 engine.step()
             if run.tracer.active:
                 traced["chunks"] += engine.stats["chunks"] - before
-            backlog.append((time.perf_counter() - t0, nxt - state["done"]))
+            backlog.append((clock() - t0, nxt - state["done"]))
         elif nxt == n:
             break
         else:
             time.sleep(max(0.0, min(
-                t0 + clients[nxt].request.due_s - time.perf_counter(), 0.05)))
-        if time.perf_counter() > give_up:
+                t0 + clients[nxt].request.due_s - clock(), 0.05)))
+        if clock() > give_up:
             break
     run.tracer.stop()
     for name, at in (("backlog_mid", run.seconds / 2),
@@ -137,8 +143,9 @@ def summarise(run, engine, clients, traced, late, t0):
         traced_decode_steps=decode_steps,
         traced_prompt_lens=traced["prompt_lens"],
         traced_decode_positions=traced["positions"],
-        engine_stats={k: v for k, v in engine.stats.items()
-                      if isinstance(v, (int, float))})
+        # the engine's counters: a ``ratio`` metric reads obs.engine.<key>
+        **{f"engine.{k}": v for k, v in engine.stats.items()
+           if isinstance(v, (int, float))})
     if decode_steps:
         run.obs["traced_live_kv_tokens_mean"] = \
             sum(traced["positions"]) / decode_steps
@@ -183,8 +190,8 @@ def compare_with_reference(run, samples, control=None):
     mean (what ``correct`` holds to a limit) and the widest.  With
     ``control`` (a lower precision) the tokens judged are those that
     precision puts first at the same positions, in the program's place."""
-    from benchmark.reference import gpt as reference
-    w = weights.make_stacked(run.model, run.seed)
+    reference = run.reference
+    w = run.family.make_stacked(run.model, run.seed)
     T = padded_length(run.traffic)
     gaps = []
     for prompt, served in samples:

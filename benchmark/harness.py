@@ -6,6 +6,9 @@ Everything that belongs to one configuration, one traffic mix or one
 per-layer metric is a file found by the name ``BENCHMARK.json`` gives:
 
     configs/<config>.json   traffic/<mix>.json   limits/<cell>.json
+    families/<family>.py    (the configuration's "family": its program,
+                            weights and counts) and its plain reference
+                            (the configuration's "reference", a path)
     drivers/<kind>.py       (the mix's "kind")
     metrics/<metric>.json   readers/<reader>.py  (the metric's "reader")
 
@@ -110,13 +113,24 @@ class CompileMeter:
 class Tracer:
     """Profiles the part [start_s, end_s) of the window; drivers call
     ``tick(elapsed)`` at step boundaries.  The host span ``bench.window``
-    marks the traced part for the reducer."""
+    marks the traced part for the reducer.
+
+    The profiler's stop writes the trace and holds its caller for as long
+    as that takes (tens of seconds at a serving cell's size).  That time
+    is the benchmark's own: ``stop`` books it in ``held_s`` and prints it,
+    and a driver reads the window's time from ``clock()``, which stands
+    still across it."""
 
     def __init__(self, enabled, directory, span):
         self.directory = directory
         self.start_s, self.end_s = span
         self.state = "armed" if enabled else "off"
+        self.held_s = 0.0
         self._window = None
+
+    def clock(self):
+        """The window's clock: the host's, less what ``stop`` held."""
+        return time.perf_counter() - self.held_s
 
     @property
     def active(self):
@@ -143,9 +157,12 @@ class Tracer:
     def stop(self):
         import jax
         if self.state == "on":
+            t0 = time.perf_counter()
             self._window.__exit__(None, None, None)
             jax.profiler.stop_trace()
             self.state = "done"
+            self.held_s += time.perf_counter() - t0
+            say("trace_written", seconds=round(self.held_s, 3))
 
 
 def span(name):
@@ -185,8 +202,14 @@ class Run:
                              f"BENCHMARK.json (has {sorted(cells)})")
         self.cell = cells[args.workload]
         configs = {c["name"]: c for c in manifest["configs"]}
-        self.config = load_json(root, configs[self.cell["config"]]["file"])
+        config_file = configs[self.cell["config"]]["file"]
+        self.config = load_json(root, config_file)
+        for key in ("family", "reference"):
+            if key not in self.config:
+                raise KeyError(f"benchmark: {config_file} names no {key!r}")
         self.model = self.config["model"]
+        self.family = load_module(root, "families", self.config["family"])
+        self.reference = load_module_file(root, self.config["reference"])
         self.traffic = load_json(root, "benchmark", "traffic",
                                  self.cell["traffic"] + ".json")
         self.limits = load_json(root, "benchmark", "limits",
@@ -219,16 +242,24 @@ class Run:
             memory_peak_bytes=self.memory_peak_bytes)
 
 
-def load_module(root, kind, name):
-    """``<root>/benchmark/<kind>/<name>.py``, found by the name a data
-    file gives (a driver by a mix's "kind", a reader by a metric's
-    "reader")."""
-    path = os.path.join(root, "benchmark", kind, name + ".py")
+def load_module_file(root, relative):
+    """The module in ``<root>/<relative>``; a file that is not there is
+    an error that names it."""
+    path = os.path.join(root, relative)
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"benchmark: no file {relative} in {root}")
     spec = importlib.util.spec_from_file_location(
-        f"benchmark.{kind}.{name}", path)
+        os.path.splitext(relative)[0].replace("/", "."), path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+def load_module(root, kind, name):
+    """``<root>/benchmark/<kind>/<name>.py``, found by the name a data
+    file gives (a family by a configuration's "family", a driver by a
+    mix's "kind", a reader by a metric's "reader")."""
+    return load_module_file(root, f"benchmark/{kind}/{name}.py")
 
 
 def read_metric(run, name):

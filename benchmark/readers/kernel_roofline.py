@@ -1,10 +1,9 @@
 """A kernel's share of its roofline: the operations (or bytes) the
 algorithm needs in the traced steps, over the peak times the summed
 device time of the kernel's events.  ``patterns`` are substrings of the
-device operations' names; ``needed`` names the function of
-``benchmark/flops.py`` that counts one step's need; ``bound`` says which
-roof applies."""
-from benchmark import flops
+device operations' names; ``needed`` names the function of the
+configuration's family that counts one step's need from ``run.model``
+and ``run.obs``; ``bound`` says which roof applies."""
 
 
 def read(run, params):
@@ -14,7 +13,6 @@ def read(run, params):
                   if any(p in name for p in params["patterns"]))
     if not seconds:
         return None
-    need = getattr(flops, params["needed"])(
-        run.model, run.obs["batch"], run.obs["seq_len"])
+    need = getattr(run.family, params["needed"])(run.model, run.obs)
     need *= run.obs["traced_steps"]
     return 100 * need / (run.peaks[params["peak"]] * seconds * run.chips)

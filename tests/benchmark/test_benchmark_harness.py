@@ -4,12 +4,14 @@ rehearsals of the whole command at a tiny size on the CPU, where the
 harness's look for a chip is stepped over here, in the test, and not by
 an option of the program.  Nothing in this file loads libtpu.
 """
+import filecmp
 import json
 import os
 import re
 import shutil
 import subprocess
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -29,6 +31,9 @@ TINY = {"num_hidden_layers": 2, "hidden_size": 64, "num_attention_heads": 4,
         "hidden_dropout_prob": 0.0, "attention_probs_dropout_prob": 0.0}
 
 
+CHAT = harness.load_json(ROOT, "benchmark", "traffic", "chat-open-0p8.json")
+
+
 @pytest.fixture(scope="module")
 def manifest():
     return harness.load_manifest(ROOT)
@@ -36,7 +41,28 @@ def manifest():
 
 # -- the manifest -----------------------------------------------------------
 
-def test_manifest_names_units_and_files(manifest):
+def check_configuration(root, entry):
+    """The rule for one entry of ``configs``: its file gives the family,
+    the reference, the sizes and the deployment they are a share of, and
+    states every cut it makes (the ``model-configs`` guide's section 4)."""
+    config = harness.load_json(root, entry["file"])
+    assert os.path.isfile(os.path.join(
+        root, "benchmark", "families", config["family"] + ".py"))
+    assert os.path.isfile(os.path.join(root, config["reference"]))
+    deployment = config.get("deployment")     # one shape, cut or not
+    assert isinstance(deployment, dict), deployment
+    assert deployment["chips_sharing_a_layer"] >= 1
+    assert deployment["how"].strip()
+    reduced = config["reduced"]
+    assert isinstance(reduced, list) and reduced == entry["reduced"]
+    assert all(isinstance(key, str) for key in reduced)
+    for key in reduced:                        # what was cut from what
+        assert key in config["model"], key
+        assert key in config.get("published", {}), key
+        assert config["published"][key] != config["model"][key], key
+
+
+def check_manifest(root, manifest):
     assert set(manifest) == {"command", "paths", "run_seconds", "configs",
                              "workloads", "end_to_end", "per_layer"}
     assert 1 <= manifest["run_seconds"] <= 51
@@ -54,19 +80,29 @@ def test_manifest_names_units_and_files(manifest):
     under = tuple(p + "/" for p in manifest["paths"])
     for c in manifest["configs"]:
         assert c["file"].startswith(under)
-        config = harness.load_json(ROOT, c["file"])
-        assert config["reduced"] == c["reduced"] == []
-        assert os.path.isfile(os.path.join(ROOT, config["reference"]))
+        check_configuration(root, c)
     configs = {c["name"] for c in manifest["configs"]}
     for w in manifest["workloads"]:
         assert w["config"] in configs and w["chips"] in (1, 4)
         assert len(w["why"]) <= 200
-        mix = harness.load_json(ROOT, "benchmark", "traffic",
+        mix = harness.load_json(root, "benchmark", "traffic",
                                 w["traffic"] + ".json")
         assert os.path.isfile(os.path.join(
-            ROOT, "benchmark", "drivers", mix["kind"] + ".py"))
+            root, "benchmark", "drivers", mix["kind"] + ".py"))
         assert os.path.isfile(os.path.join(
-            ROOT, "benchmark", "limits", w["name"] + ".json"))
+            root, "benchmark", "limits", w["name"] + ".json"))
+
+
+def check_the_benchmarks_manifest(root, manifest):
+    """All that ``test_manifest_names_units_and_files`` holds the real
+    manifest to; whatever a later PR adds to it has to pass this too."""
+    check_manifest(root, manifest)
+    reduced = {c["name"]: c["reduced"] for c in manifest["configs"]}
+    assert reduced["gpt3-medium"] == reduced["gpt3-xl"] == []   # both whole
+
+
+def test_manifest_names_units_and_files(manifest):
+    check_the_benchmarks_manifest(ROOT, manifest)
 
 
 def test_every_cell_reports_what_the_contract_asks(manifest):
@@ -93,12 +129,35 @@ def test_every_cell_reports_what_the_contract_asks(manifest):
                        for m in layer), k["name"]
 
 
+KNOBS = ("num_slots", "chunk", "page_size", "num_pages", "prefill_buckets")
+
+
+def pinned_knobs(mix):
+    """The engine knobs among a mix's keys, at any depth.  What a ``why``
+    says is prose and pins nothing."""
+    found = set()
+    if isinstance(mix, dict):
+        found = {k for k in mix if k in KNOBS}
+        mix = list(mix.values())
+    if isinstance(mix, list):
+        for inner in mix:
+            found.update(pinned_knobs(inner))
+    return sorted(found)
+
+
 def test_no_engine_knob_is_pinned():
     for name in os.listdir(os.path.join(ROOT, "benchmark", "traffic")):
-        text = open(os.path.join(ROOT, "benchmark", "traffic", name)).read()
-        for knob in ("num_slots", "chunk", "page_size", "num_pages",
-                     "prefill_buckets"):
-            assert knob not in text, (name, knob)
+        mix = harness.load_json(ROOT, "benchmark", "traffic", name)
+        assert pinned_knobs(mix) == [], name
+
+
+@pytest.mark.parametrize("change, pinned", [
+    ({"why": "chunked prefill over a 16-token page_size"}, []),
+    ({"engine": {"kv_mode": "paged", "chunk": 16}}, ["chunk"]),
+    ({"phases": [{"engine": {"num_pages": 512}}], "num_slots": 4},
+     ["num_pages", "num_slots"])])
+def test_a_knob_is_a_key_and_not_a_word(change, pinned):
+    assert pinned_knobs(dict(CHAT, **change)) == pinned
 
 
 # -- flops.py against hand-worked numbers ------------------------------------
@@ -132,9 +191,6 @@ def test_flops_hand_worked():
 
 
 # -- the arrival generator ----------------------------------------------------
-
-CHAT = harness.load_json(ROOT, "benchmark", "traffic", "chat-open-0p8.json")
-
 
 def test_arrivals_same_seed_same_schedule_and_clips():
     a = arrivals.schedule(CHAT, 2_500_000_123, 40.0, 50304)
@@ -267,54 +323,110 @@ def test_run_exits_nonzero_without_a_tpu():
     assert '"metrics"' not in p.stdout
 
 
+MARK, MARKED_FLOPS = 0.125, 4.2e9
+MARKED_FAMILY = f"""\
+# a second family: leans on the GPT one and counts serving its own way
+from benchmark.families.gpt import *  # noqa: F401,F403
+
+
+def serve_flops(model, obs):
+    return {MARKED_FLOPS}
+"""
+MARKED_REFERENCE = f"""\
+# the GPT reference with every gap it reads raised by {MARK}
+from benchmark.reference import gpt
+from benchmark.reference.gpt import *  # noqa: F401,F403
+
+
+def next_token_gaps(model, w, ids, targets):
+    return gpt.next_token_gaps(model, w, ids, targets) + {MARK}
+"""
+CUT = {"name": "tiny-cut", "source": "test", "family": "marked",
+       "reference": "benchmark/reference/marked.py", "model": TINY,
+       "reduced": ["num_hidden_layers", "vocab_size"],
+       "published": {"num_hidden_layers": 24, "vocab_size": 4096},
+       "deployment": {"chips_sharing_a_layer": 4,
+                      "how": "a quarter of the vocabulary's rows here"}}
+
+
+def _write(root, rel, obj):
+    """A JSON file (or, of a string, a text file) of the copy."""
+    with open(os.path.join(root, "benchmark", rel), "w") as f:
+        f.write(obj) if isinstance(obj, str) else json.dump(obj, f)
+
+
 @pytest.fixture()
 def tiny_root(tmp_path, manifest, monkeypatch):
-    """A copy of the benchmark with a configuration, two mixes, a metric
+    """A copy of the benchmark with two configurations (one of them cut,
+    of a family and with a reference of its own), two mixes, two metrics
     and a reader ADDED as new files plus one entry each: no file that is
-    there is edited."""
+    there is edited, and the fixture checks that."""
     import jax
     root = str(tmp_path / "checkout")
     shutil.copytree(os.path.join(ROOT, "benchmark"),
-                    os.path.join(root, "benchmark"))
+                    os.path.join(root, "benchmark"),
+                    ignore=shutil.ignore_patterns("__pycache__"))
     bench = os.path.join(root, "benchmark")
     m = json.loads(json.dumps(manifest))
 
-    def write(rel, obj):
-        with open(os.path.join(bench, rel), "w") as f:
-            json.dump(obj, f)
-
-    write("configs/tiny.json", {"name": "tiny", "source": "test",
-                                "model": TINY, "reduced": []})
+    _write(root, "configs/tiny.json", {
+        "name": "tiny", "source": "test", "family": "gpt",
+        "reference": "benchmark/reference/gpt.py", "model": TINY,
+        "reduced": [],
+        "deployment": {"chips_sharing_a_layer": 1, "how": "whole"}})
     m["configs"].append({"name": "tiny", "source": "test", "reduced": [],
                          "file": "benchmark/configs/tiny.json", "why": "t"})
+    _write(root, "families/marked.py", MARKED_FAMILY)
+    _write(root, "reference/marked.py", MARKED_REFERENCE)
+    _write(root, "configs/tiny-cut.json", CUT)
+    m["configs"].append({"name": "tiny-cut", "source": "test",
+                         "reduced": CUT["reduced"], "why": "t",
+                         "file": "benchmark/configs/tiny-cut.json"})
     fit = harness.load_json(bench, "traffic", "fit-s2048-b4.json")
-    write("traffic/fit-tiny.json", dict(fit, seq_len=128, rows_per_second=400,
-                                        trace_window_s=[0.2, 0.8]))
-    write("traffic/chat-tiny.json", dict(
+    _write(root, "traffic/fit-tiny.json",
+           dict(fit, seq_len=128, rows_per_second=400,
+                trace_window_s=[0.2, 0.8]))
+    _write(root, "traffic/chat-tiny.json", dict(
         CHAT, rate_rps=6, trace_window_s=[0.3, 2.0],
         engine=dict(CHAT["engine"], max_seq_len=256),
         prompt_len=dict(CHAT["prompt_len"], median=40, min=4, max=120),
         output_len=dict(CHAT["output_len"], median=16, min=2, max=64)))
-    for cell, like in (("fit-tiny", "fit-gpt3-medium"),
-                       ("chat-tiny", "chat-gpt3-xl")):
-        m["workloads"].append({"name": cell, "config": "tiny", "chips": 1,
-                               "traffic": cell, "why": "t"})
+    for cell, config, traffic, like in (
+            ("fit-tiny", "tiny", "fit-tiny", "fit-gpt3-medium"),
+            ("chat-tiny", "tiny", "chat-tiny", "chat-gpt3-xl"),
+            ("chat-cut", "tiny-cut", "chat-tiny", "chat-gpt3-xl")):
+        m["workloads"].append({"name": cell, "config": config, "chips": 1,
+                               "traffic": traffic, "why": "t"})
         shutil.copy(os.path.join(bench, "limits", like + ".json"),
                     os.path.join(bench, "limits", cell + ".json"))
         for e in m["end_to_end"] + m["per_layer"]:
             if like in e.get("workloads", []):
                 e["workloads"].append(cell)
-    with open(os.path.join(bench, "readers", "steps_counted.py"), "w") as f:
-        f.write("def read(run, params):\n"
-                "    return run.obs.get(params['key'])\n")
-    write("metrics/fit.steps.json", {"reader": "steps_counted",
-                                     "params": {"key": "steps"}})
-    m["per_layer"].append({
-        "name": "fit.steps", "unit": "steps", "better": "higher",
-        "source": "program_counter", "layer": "user loop",
-        "moves": "train_tokens_per_s", "workloads": ["fit-tiny"]})
+    chat_limit = harness.load_json(bench, "limits", "chat-gpt3-xl.json")
+    _write(root, "limits/chat-cut.json",
+           {"token_gap_mean": MARK + chat_limit["token_gap_mean"]})
+    _write(root, "readers/steps_counted.py",
+           "def read(run, params):\n"
+           "    return run.obs.get(params['key'])\n")
+    _write(root, "metrics/fit.steps.json",
+           {"reader": "steps_counted", "params": {"key": "steps"}})
+    # a counter of the engine's, read by a data file alone
+    _write(root, "metrics/serve.chunks.json",
+           {"reader": "ratio", "params": {"num": "obs.engine.chunks"}})
+    for name, unit, layer, moves, cells in (
+            ("fit.steps", "steps", "user loop", "train_tokens_per_s",
+             ["fit-tiny"]),
+            ("serve.chunks", "chunks", "serving engine",
+             "serve_tokens_per_s", ["chat-tiny", "chat-cut"])):
+        m["per_layer"].append({
+            "name": name, "unit": unit, "better": "higher",
+            "source": "program_counter", "layer": layer, "moves": moves,
+            "workloads": cells})
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(m, f)
+    same = filecmp.dircmp(os.path.join(ROOT, "benchmark"), bench,
+                          ignore=["__pycache__"])
+    _assert_nothing_edited(same)
     # the look for a chip is stepped over here, in the test
     monkeypatch.setattr(harness, "require_chip",
                         lambda chips: jax.devices()[:chips])
@@ -323,6 +435,24 @@ def tiny_root(tmp_path, manifest, monkeypatch):
     monkeypatch.setattr(harness, "enable_compile_cache", lambda: None)
     _cpu_planes(monkeypatch)
     return root
+
+
+def _assert_nothing_edited(same):
+    """Every file of the benchmark is in the copy, byte for byte."""
+    assert not same.left_only and not same.diff_files and not same.funny_files
+    for sub in same.subdirs.values():
+        _assert_nothing_edited(sub)
+
+
+def _marked_mfu(result):
+    """``serve.mfu`` as the marked family's count gives it (the fixture's
+    peak is 1e12 FLOP/s)."""
+    return 100 * MARKED_FLOPS / (result["device"]["window_s"] * 1e12)
+
+
+def _cut(**change):
+    """The cut configuration's file with ``change`` (None: key left out)."""
+    return {k: v for k, v in dict(CUT, **change).items() if v is not None}
 
 
 def _drive(root, capsys, cell, trace, seconds="1.5", seed="3000000019"):
@@ -348,8 +478,10 @@ def test_fit_cell_rehearsal(tiny_root, capsys, trace):
         assert "kernel.fit_attention_roofline" not in result["metrics"]
         assert result["device"]["busy_s"] > 0
         assert result["breakdown"]["device_ops"]
+        assert [e["event"] for e in earlier].count("trace_written") == 1
     else:
         assert set(result["metrics"]) == {"train_tokens_per_s", "setup_s"}
+        assert "trace_written" not in [e["event"] for e in earlier]
     setup = next(e for e in earlier if e["event"] == "setup")
     assert {"weights_s", "first_steps_s", "compiles"} <= set(setup)
     assert result["compared"]["compiles_in_window"] == [0, 0]
@@ -363,12 +495,203 @@ def test_chat_cell_rehearsal(tiny_root, capsys, trace):
     generator = next(e for e in earlier if e["event"] == "generator")
     assert generator["late_ms_max"] >= generator["late_ms_mean"] >= 0
     if trace:
-        assert {"serve.mfu", "device.serve_idle_share"} <= \
+        assert {"serve.mfu", "device.serve_idle_share", "serve.chunks"} <= \
             set(result["metrics"])
+        window = next(e for e in earlier if e["event"] == "window")
+        assert result["metrics"]["serve.chunks"]["value"] == \
+            window["engine.chunks"] > 0
+        assert "engine_stats" not in window            # one form, the flat
+        # the GPT family's count and reference, not the marked ones beside it
+        assert result["compared"]["token_gap_mean"][0] < MARK
+        assert result["metrics"]["serve.mfu"]["value"] != pytest.approx(
+            _marked_mfu(result))
     else:
         assert set(result["metrics"]) == {
             "serve_tokens_per_s", "ttft_p90_ms", "tpot_p90_ms", "setup_s"}
         assert result["metrics"]["serve_tokens_per_s"]["value"] > 0
+
+
+# -- a cut configuration of another family, by new files alone ----------------
+
+def test_second_family_serves_and_counts_its_own_way(tiny_root, capsys):
+    """``chat-cut`` is ``chat-tiny`` under a configuration whose family
+    and reference are files of the copy: the rehearsal is correct against
+    THAT reference (every gap it reads is raised by MARK, and so is the
+    limit), and ``serve.mfu`` is THAT family's count over the window."""
+    result, _, err = _drive(tiny_root, capsys, "chat-cut", 1, "3")
+    assert result["correct"] is True, err
+    gap, limit = result["compared"]["token_gap_mean"]
+    assert MARK <= gap <= limit < MARK + 2e-3
+    assert result["compared"]["requests_unanswered"] == [0, 0]
+    assert result["metrics"]["serve.mfu"]["value"] == pytest.approx(
+        _marked_mfu(result))
+
+
+def test_added_configurations_pass_the_manifest_test(tiny_root, manifest):
+    """The real manifest with three configurations more, one of them cut,
+    passes all that its own test holds it to: that test counts no
+    entries and asks of no added one that it be whole."""
+    grown = harness.load_manifest(tiny_root)
+    assert [c["name"] for c in grown["configs"]] == \
+        [c["name"] for c in manifest["configs"]] + ["tiny", "tiny-cut"]
+    assert grown["configs"][-1]["reduced"]
+    check_the_benchmarks_manifest(tiny_root, grown)
+
+
+@pytest.mark.parametrize("fault, listed", [
+    ({"published": None}, None),                        # cut from what?
+    ({"published": {"num_hidden_layers": 24}}, None),   # ... and the other?
+    ({"published": {"num_hidden_layers": 24,            # 1024 is what is run
+                    "vocab_size": TINY["vocab_size"]}}, None),
+    ({"deployment": None}, None),
+    ({"deployment": "a quarter of a layer"}, None),     # over how many chips?
+    ({"reduced": ["num_hidden_layers"]}, None),         # the manifest says two
+    ({"reduced": ["num_hidden_layers", "depth"],        # no key of ``model``
+      "published": {"num_hidden_layers": 24, "depth": 24}},
+     ["num_hidden_layers", "depth"]),
+    ({"family": "nowhere"}, None),
+    ({"reference": "benchmark/reference/nowhere.py"}, None)])
+def test_a_cut_that_is_not_stated_fails_the_manifest_test(tiny_root, fault,
+                                                          listed):
+    _write(tiny_root, "configs/tiny-cut.json", _cut(**fault))
+    manifest = harness.load_manifest(tiny_root)
+    if listed:
+        manifest["configs"][-1]["reduced"] = listed
+    with pytest.raises(AssertionError):
+        check_manifest(tiny_root, manifest)
+
+
+@pytest.mark.parametrize("change, error, named", [
+    ({"family": "nowhere"}, FileNotFoundError,
+     "benchmark/families/nowhere.py"),
+    ({"reference": "benchmark/reference/nowhere.py"}, FileNotFoundError,
+     "benchmark/reference/nowhere.py"),
+    ({"family": None}, KeyError, "benchmark/configs/tiny-cut.json"),
+    ({"reference": None}, KeyError, "benchmark/configs/tiny-cut.json")])
+def test_a_run_loads_what_the_configuration_names(tiny_root, change, error,
+                                                  named):
+    run = _tiny_run(tiny_root, "chat-cut", 1)
+    assert run.family.__file__ == os.path.join(
+        tiny_root, "benchmark", "families", "marked.py")
+    assert run.reference.__file__ == os.path.join(
+        tiny_root, "benchmark", "reference", "marked.py")
+    _write(tiny_root, "configs/tiny-cut.json", _cut(**change))
+    with pytest.raises(error, match=re.escape(named)):
+        _tiny_run(tiny_root, "chat-cut", 1)
+
+
+def names_a_family(text, harness_itself=False):
+    """What a file of the harness, a driver or a reader may not do:
+    import a family, a reference, the weights or the program's models by
+    name, or call ``flops.py`` for more than the chip's peaks (the
+    harness alone imports it, for those).  Prose names what it likes."""
+    imported = set(re.findall(
+        r"^\s*(?:from|import) benchmark\.(families|reference|weights|flops)\b",
+        text, re.M)) | set(re.findall(
+            r"^\s*from benchmark import .*\b(families|reference|weights|flops)\b",
+            text, re.M)) | set(re.findall(
+                r"^\s*(?:from|import) (paddle_tpu\.models)\b", text, re.M))
+    called = set(re.findall(r"\bflops\.(\w+)", text)) - {"peaks"}
+    return sorted((imported - {"flops"} if harness_itself else imported)
+                  | {"flops." + name for name in called})
+
+
+def test_harness_drivers_and_readers_name_no_family():
+    """What belongs to a family is reached as ``run.family`` and
+    ``run.reference``; ``flops.py`` is called for the chip's peaks alone."""
+    bench = os.path.join(ROOT, "benchmark")
+    files = [os.path.join(bench, "harness.py")] + [
+        os.path.join(bench, kind, name) for kind in ("drivers", "readers")
+        for name in sorted(os.listdir(os.path.join(bench, kind)))
+        if name.endswith(".py")]
+    assert len(files) >= 10
+    for path in files:
+        assert names_a_family(
+            open(path).read(), path.endswith("harness.py")) == [], path
+
+
+@pytest.mark.parametrize("text, found", [
+    ('"""Unlike GPT\'s cache of 2H a token, the latent one (gpt.py has the\n'
+     'other) is 576 wide."""\ndef read(run, params):\n'
+     '    return run.family.decode_step_min_bytes(run.model, run.obs)\n', []),
+    ("from benchmark import flops\npeak = flops.peaks(kind)\n", ["flops"]),
+    ("    from benchmark.families import gpt\n", ["families"]),
+    ("from benchmark.reference import gpt as reference\n", ["reference"]),
+    ("from benchmark import arrivals, weights\n", ["weights"]),
+    ("from paddle_tpu.models import gpt\n", ["paddle_tpu.models"]),
+    ("need = flops.serve_flops(run.model, [], [])\n", ["flops.serve_flops"])])
+def test_naming_a_family_is_structure_and_not_prose(text, found):
+    """A later PR's reader may SAY GPT; it may not import or count it."""
+    assert names_a_family(text) == found
+
+
+def test_the_family_counts_what_flops_counts():
+    from benchmark.families import gpt
+    medium = harness.load_json(ROOT, "benchmark", "configs",
+                               "gpt3-medium.json")["model"]
+    xl = harness.load_json(ROOT, "benchmark", "configs",
+                           "gpt3-xl.json")["model"]
+    obs = {"batch": 4, "seq_len": 2048, "traced_prompt_lens": [3],
+           "traced_decode_positions": [4],
+           "traced_live_kv_tokens_mean": 1000, "engine.chunks": 7}
+    assert gpt.train_flops_per_token(medium, obs) == \
+        flops.train_flops_per_token(medium, 2048)
+    assert gpt.attention_train_flops(medium, obs) == \
+        flops.attention_train_flops(medium, 4, 2048)
+    assert gpt.serve_flops(xl, obs) == flops.serve_flops(xl, [3], [4])
+    assert gpt.decode_step_min_bytes(xl, obs) == \
+        2 * 1_310_982_144 + 1000 * 196_608
+    assert gpt.leaf_name("gpt.layers.3.attn.qkv_proj.weight") == \
+        "h.3.attn.qkv.weight"
+    with pytest.raises(KeyError):
+        gpt.leaf_name("bert.pooler.weight")
+
+
+# -- the profiler's stop holds the loop: no request pays for it ---------------
+
+def test_a_slow_trace_write_costs_no_request(tiny_root, capsys, monkeypatch):
+    """The profiler's stop, from inside the serving loop, outlasts the
+    drain: the window's clock stands still across it, every request
+    still arrives at its own time and is answered."""
+    mix = harness.load_json(tiny_root, "benchmark", "traffic",
+                            "chat-tiny.json")
+    _write(tiny_root, "traffic/chat-tiny.json", dict(mix, drain_s=2))
+    import jax
+    sound = jax.profiler.stop_trace
+
+    def slow():
+        time.sleep(4)
+        sound()
+
+    monkeypatch.setattr(jax.profiler, "stop_trace", slow)
+    result, earlier, err = _drive(tiny_root, capsys, "chat-tiny", 1, "3")
+    written = [e for e in earlier if e["event"] == "trace_written"]
+    assert len(written) == 1 and written[0]["seconds"] >= 4
+    assert result["compared"]["requests_unanswered"] == [0, 0]
+    assert result["correct"] is True, err
+    assert result["attempted"] == 18 and result["failed"] == 0
+    late = next(e for e in earlier if e["event"] == "generator")
+    assert late["late_ms_max"] < 2000       # nobody waited out the write
+
+
+def test_the_tracers_clock_stands_still_across_its_stop(tmp_path, capsys,
+                                                        monkeypatch):
+    """``Tracer.stop`` owns the hold: it books it, prints it, and
+    ``clock()``, the window's time in every driver, leaves it out."""
+    import jax
+    sound = jax.profiler.stop_trace
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: (time.sleep(0.5), sound()))
+    tracer = harness.Tracer(True, str(tmp_path / "trace"), [0.0, 0.0])
+    assert tracer.tick(0.0) == "started" and tracer.held_s == 0.0
+    before, real = tracer.clock(), time.perf_counter()
+    assert tracer.tick(0.1) == "stopped"
+    assert time.perf_counter() - real >= 0.5 > 0.1 > tracer.clock() - before
+    tracer.stop()                           # stopped already: nothing more
+    said = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
+    assert said == [{"event": "trace_written",
+                     "seconds": round(tracer.held_s, 3)}]
+    assert tracer.held_s >= 0.5
 
 
 # -- a broken timed path has to come out as not correct -----------------------
