@@ -43,6 +43,17 @@ formulation:
   ``k``, attending the cached pages through the same gather.  Causal
   attention is position-wise, so chunked prefill is bitwise-identical
   to cold prefill (same masked ``MAX``-wide reduction).
+- **Layer kinds** — ``kv_cache_spec()`` describes a layer by kind.  A
+  ``(nH, D)`` pair is the K pool and V pool above.  A
+  ``generation.LatentCacheSpec(width)`` is a latent-attention layer:
+  ONE pool ``(num_pages, page_size, width)`` holding, a token, the normed
+  latent and the rotated key positions side by side, with no head axis
+  and no V plane (:class:`LatentCacheView`, :func:`gather_latent`,
+  :func:`scatter_latent`).  A page is a page: the allocator, the tables,
+  the prefix cache, ``plan`` / ``bind`` / ``release`` and the handoff's
+  export/import with its CRCs do not know the kind; the pools, the page
+  bytes and the views follow it.  int8 KV is refused with a latent
+  layer (its scale group is one token's ``nH x D`` block).
 - **int8 KV** (opt-in, the serving sibling of
   ``quantization.weight_only_quantize``) — pool pages store int8 with
   one fp32 absmax scale per token row (chunkwise absmax over that
@@ -77,9 +88,12 @@ import jax.numpy as jnp
 
 from ..analysis import register_jit_surface
 from .. import observability as _obs
+from ..models.generation import cache_kind
+from ..observability import devcounters as _devc
 from ..observability.tracing import scope as _scope
 
-__all__ = ["KVBundleError", "PagedCacheView", "PagedKVManager",
+__all__ = ["KVBundleError", "PagedCacheView", "LatentCacheView",
+           "PagedKVManager",
            "quantize_kv", "dequantize_kv",
            "chained_page_digests", "prefix_affinity_key"]
 
@@ -157,6 +171,15 @@ class PagedCacheView(NamedTuple):
     v_pages: Any
     k_scales: Any
     v_scales: Any
+    table: Any
+
+
+class LatentCacheView(NamedTuple):
+    """One latent layer's paged cache as it travels through the model:
+    the single pool ``(num_pages, page_size, width)`` and the per-slot
+    page table.  What the model does with it is
+    ``models/mla_moe.py``'s; the pool's bookkeeping is the manager's."""
+    pages: Any
     table: Any
 
 
@@ -294,18 +317,42 @@ def scatter_pages_q(kp, vp, ks, vs, k_new, v_new, table, pos):
     return kp, vp, ks, vs
 
 
+def gather_latent(pages, table):
+    """A latent layer's ``(B, MAX, width)`` working buffer from its pool
+    (``table`` is ``(B, n_pages)``); unmapped entries read the trash
+    page, finite values that the attention mask weighs 0."""
+    with _scope("kv.gather"):
+        return pages[table].reshape(table.shape[0], -1, pages.shape[2])
+
+
+def scatter_latent(pages, new, table, pos):
+    """Persist this step's latent rows ``new`` (B, S, width) at
+    positions ``pos..pos+S-1``, as :func:`scatter_pages` does."""
+    with _scope("kv.scatter"):
+        phys, off = _scatter_coords(table, pos, new.shape[1],
+                                    pages.shape[1])
+        return pages.at[phys, off].set(new.astype(pages.dtype))
+
+
 def _layer_views(pools, table, quant):
-    if quant:
-        return [PagedCacheView(kp, vp, ks, vs, table)
-                for kp, vp, ks, vs in pools]
-    return [PagedCacheView(kp, vp, None, None, table) for kp, vp in pools]
+    """One view a layer from the pools, by the layer's kind: a latent
+    layer's pool is one plane, a K/V layer's two (four with int8
+    scales)."""
+    views = []
+    for planes in pools:
+        if len(planes) == 1:
+            views.append(LatentCacheView(planes[0], table))
+        elif quant:
+            views.append(PagedCacheView(*planes, table))
+        else:
+            views.append(PagedCacheView(*planes, None, None, table))
+    return views
 
 
 def _layer_pools(views, quant):
-    if quant:
-        return [(c.k_pages, c.v_pages, c.k_scales, c.v_scales)
-                for c in views]
-    return [(c.k_pages, c.v_pages) for c in views]
+    return [(c.pages,) if isinstance(c, LatentCacheView)
+            else (c.k_pages, c.v_pages, c.k_scales, c.v_scales) if quant
+            else (c.k_pages, c.v_pages) for c in views]
 
 
 # -- compiled bodies -------------------------------------------------------
@@ -317,15 +364,26 @@ def _build_paged_prefill(apply, pick, eos, quant):
     hit), attending any shared prefix pages through the paged gather,
     pick the first generated token at the last *real* suffix position,
     and arm the slot's decode state.  KV lands in the slot's pages via
-    the in-attention scatter — nothing here touches a dense slot row."""
+    the in-attention scatter — nothing here touches a dense slot row.
+    The last output is what the model's layers counted on the device
+    (``observability.devcounters``; empty for a model that counts
+    nothing, and then no operation of the program)."""
     def paged_prefill(pv, ids, start, length, slot, budget,
                       tokens, pos, active, remaining, pools, table):
         row = jax.lax.dynamic_slice_in_dim(table, slot, 1, axis=0)
         caches = _layer_views(pools, row, quant)
-        logits, new = apply(pv, ids, caches, start)
+        real = (jnp.arange(ids.shape[1]) < length)[None, :]
+        with _devc.collect("prefill", rows=real) as bag:
+            if apply.takes_last:
+                # the model applies its head to the one position picked
+                logits, new = apply(pv, ids, caches, start,
+                                    last=length - 1)
+                last = logits[:, 0]                         # (1, V)
+            else:
+                logits, new = apply(pv, ids, caches, start)
+                last = jax.lax.dynamic_slice_in_dim(
+                    logits, length - 1, 1, axis=1)[:, 0]    # (1, V)
         pools = _layer_pools(new, quant)
-        last = jax.lax.dynamic_slice_in_dim(
-            logits, length - 1, 1, axis=1)[:, 0]            # (1, V)
         with _scope("sample"):
             t0, _ = pick(last, jax.random.key(0))           # (1,)
         t0 = t0[0]
@@ -335,7 +393,8 @@ def _build_paged_prefill(apply, pick, eos, quant):
         pos = pos.at[slot].set(start + length)
         active = active.at[slot].set(~fin0)
         remaining = remaining.at[slot].set(budget - 1)
-        return t0, fin0, tokens, pos, active, remaining, pools
+        return (t0, fin0, tokens, pos, active, remaining, pools,
+                bag.totals())
     return paged_prefill
 
 
@@ -352,7 +411,8 @@ def _build_paged_decode_chunk(apply, pick, chunk, eos, pad, quant):
             tokens, pos, active, remaining, pools = carry
             safe = jnp.where(active[:, None], table, 0)
             caches = _layer_views(pools, safe, quant)
-            logits, new = apply(pv, tokens[:, None], caches, pos)
+            with _devc.collect("decode", rows=active[:, None]) as bag:
+                logits, new = apply(pv, tokens[:, None], caches, pos)
             pools = _layer_pools(new, quant)
             with _scope("sample"):
                 nxt, _ = pick(logits[:, 0, :], jax.random.key(0))
@@ -366,11 +426,15 @@ def _build_paged_decode_chunk(apply, pick, chunk, eos, pad, quant):
             done = active & (hit_eos | (remaining <= 0))
             tokens = jnp.where(active, nxt, tokens)
             active = active & ~done
-            return (tokens, pos, active, remaining, pools), (nxt, emitted)
+            return (tokens, pos, active, remaining, pools), \
+                (nxt, emitted, bag.totals())
         carry = (tokens, pos, active, remaining, pools)
-        (tokens, pos, active, remaining, pools), (toks, valid) = \
+        (tokens, pos, active, remaining, pools), (toks, valid, counts) = \
             jax.lax.scan(body, carry, None, length=chunk)
-        return tokens, pos, active, remaining, pools, toks, valid
+        # the steps' device counters, folded over the chunk
+        counts = {"sum": {k: v.sum() for k, v in counts["sum"].items()},
+                  "max": {k: v.max() for k, v in counts["max"].items()}}
+        return tokens, pos, active, remaining, pools, toks, valid, counts
     return paged_decode_chunk
 
 
@@ -405,6 +469,12 @@ class PagedKVManager:
             raise ValueError(f"unsupported kv_dtype {kv_dtype!r} "
                              "(int8 or None)")
         self.spec = list(spec)
+        self.kinds = [cache_kind(layer) for layer in self.spec]
+        if kv_dtype == "int8" and "latent" in self.kinds:
+            raise ValueError(
+                "kv_dtype='int8' is not supported with a latent cache "
+                "layer (the int8 scale group is one token's nH x D "
+                "block of a K/V layer)")
         self.num_slots = int(num_slots)
         self.MAX = int(max_seq_len)
         self.page_size = int(page_size)
@@ -421,10 +491,12 @@ class PagedKVManager:
         self.quant = kv_dtype == "int8"
         self.prefix_enabled = bool(prefix_cache)
         self.max_prefix_entries = int(max_prefix_entries)
-        # per-token bytes across all layers (K+V [+ scales]) for the
-        # resident-bytes gauge
+        # per-token bytes across all layers (K+V [+ scales], or the one
+        # latent plane) for the resident-bytes gauge
         elt = jnp.dtype("int8" if self.quant else cache_dtype).itemsize
-        per_tok = sum(2 * nh * d * elt for nh, d in self.spec)
+        per_tok = sum(layer.width * elt if kind == "latent"
+                      else 2 * layer[0] * layer[1] * elt
+                      for layer, kind in zip(self.spec, self.kinds))
         if self.quant:
             per_tok += 2 * 4 * len(self.spec)        # fp32 scale per row
         self.page_bytes = per_tok * self.page_size
@@ -452,9 +524,11 @@ class PagedKVManager:
                 for nh, d in self.spec]
         else:
             self._pools = [
-                (jnp.zeros((N, P, nh, d), self.cache_dtype),
-                 jnp.zeros((N, P, nh, d), self.cache_dtype))
-                for nh, d in self.spec]
+                (jnp.zeros((N, P, layer.width), self.cache_dtype),)
+                if kind == "latent" else
+                (jnp.zeros((N, P) + tuple(layer), self.cache_dtype),
+                 jnp.zeros((N, P) + tuple(layer), self.cache_dtype))
+                for layer, kind in zip(self.spec, self.kinds)]
         self.table = np.zeros((self.num_slots, self.pages_per_slot),
                               np.int32)
         self._free = list(range(self.num_pages - 1, 0, -1))
@@ -732,9 +806,11 @@ class PagedKVManager:
         later) is the only thing left to swap.
 
         Returns ``{"logical": [logical pages, ascending], "layers":
-        [per-layer tuples of (k, page_size, nH, D) page stacks],
-        "quant": bool, "manifest": {...}}``.  The manifest carries the
-        page count/size, dtype, layer spec and a per-page CRC32 chain
+        [per-layer tuples of (k, page_size, nH, D) page stacks, a
+        latent layer's tuple holding its one (k, page_size, width)
+        stack], "quant": bool, "manifest": {...}}``.  The manifest carries
+        the page count/size, dtype, layer count and kinds and a per-page
+        CRC32 chain
         over every buffer (scales included in int8 mode) —
         :meth:`import_pages` refuses the payload whole on any mismatch.
         """
@@ -748,6 +824,7 @@ class PagedKVManager:
             "page_size": self.page_size,
             "dtype": "int8" if self.quant else str(self.cache_dtype),
             "layers": len(self.spec),
+            "kinds": list(self.kinds),
             "positions": [int(j) for j in order],
             "crc32": _page_crcs(layers),
         }
@@ -784,6 +861,12 @@ class PagedKVManager:
                 f"{man.get('page_size')}/{man.get('layers')} layer(s) "
                 f"vs pool page_size={self.page_size}/"
                 f"{len(self.spec)} layer(s)")
+        if man.get("kinds", ["heads"] * len(self.spec)) != self.kinds \
+                or any(len(got) != len(pool)
+                       for got, pool in zip(layers, self._pools)):
+            raise KVBundleError(
+                f"KV bundle layer kinds {man.get('kinds')} != pool "
+                f"layer kinds {self.kinds}")
         if man.get("dtype") != want_dtype:
             raise KVBundleError(
                 f"KV bundle dtype {man.get('dtype')!r} != pool dtype "

@@ -45,8 +45,9 @@ from .. import observability as _obs
 from ..observability import tracing as _tracing
 from ..analysis import register_jit_surface
 from ..framework import guardian
-from ..models.generation import (build_apply, build_pick, cast_weights,
-                                 dominant_float_dtype, quantize_weights)
+from ..models.generation import (build_apply, build_pick, cache_kind,
+                                 cast_weights, dominant_float_dtype,
+                                 quantize_weights)
 from .scheduler import FCFSScheduler, Request
 
 __all__ = ["ServingEngine", "Request", "FCFSScheduler"]
@@ -56,6 +57,17 @@ __all__ = ["ServingEngine", "Request", "FCFSScheduler"]
 # in paddle_tpu/analysis/allowlist.py)
 for _qual in ("_build_prefill.prefill", "_build_decode_chunk.decode_chunk"):
     register_jit_surface(__name__, _qual)
+
+
+# the Prometheus twin of each device counter a model may keep
+# (``Layer.device_counters``; observability/catalog.py declares them)
+_DEVICE_COUNTER_METRICS = {
+    "moe_pairs_routed": "pt_serving_moe_pairs_routed_total",
+    "moe_pairs_here": "pt_serving_moe_pairs_here_total",
+    "moe_experts_touched": "pt_serving_moe_experts_touched_total",
+    "moe_decode_layer_steps": "pt_serving_moe_decode_layer_steps_total",
+    "moe_max_expert_rows": "pt_serving_moe_max_expert_rows",
+}
 
 
 def _build_prefill(apply, pick, spec, cache_dtype, MAX, eos):
@@ -233,6 +245,8 @@ class ServingEngine:
                 f"max_seq_len {self.MAX})")
         self._params = [p for _, p in model.named_parameters()]
         self._kvspec = model.kv_cache_spec()
+        self._refuse_unserved_kinds(kv_mode, kv_dtype, quant_mode,
+                                    spec_decode)
         self._pvals = [p._value for p in self._params]
         self.cache_dtype = dominant_float_dtype(self._pvals)
         self._cast_override = dtype is not None
@@ -357,6 +371,27 @@ class ServingEngine:
         self.stats = None
         self._init_state()
 
+    def _refuse_unserved_kinds(self, kv_mode, kv_dtype, quant_mode,
+                               spec_decode):
+        """A cache layer that is not keys and values by head (a latent
+        layer) is served by the paged engine in full precision only:
+        every other mode raises here, naming the mode and the kind — no
+        fallback that hides what ran."""
+        kinds = sorted({cache_kind(s) for s in self._kvspec} - {"heads"})
+        if not kinds:
+            return
+        for mode, on in ((f"kv_mode={kv_mode!r}", kv_mode != "paged"),
+                         (f"kv_dtype={kv_dtype!r}", kv_dtype is not None),
+                         (f"quant_mode={quant_mode!r}",
+                          quant_mode is not None),
+                         ("spec_decode", spec_decode is not None)):
+            if on:
+                raise ValueError(
+                    f"ServingEngine: {mode} is not supported with a "
+                    f"{kinds[0]} cache layer; this model is served with "
+                    "kv_mode='paged' and none of kv_dtype, quant_mode, "
+                    "spec_decode")
+
     # -- state -------------------------------------------------------------
     def _init_state(self):
         self._init_device_state()
@@ -367,6 +402,11 @@ class ServingEngine:
                           "max_concurrent": 0, "page_evictions": 0,
                           "spec_proposed": 0, "spec_accepted": 0,
                           "spec_verify_steps": 0, "spec_chunks": 0}
+            # what the model's layers count on the device
+            # (observability.devcounters), e.g. the expert layer's
+            # moe_* keys: a model that names none adds none
+            for key in getattr(self.model, "device_counters", ()):
+                self.stats[key] = 0
 
     def _init_device_state(self):
         S = self.num_slots
@@ -377,6 +417,8 @@ class ServingEngine:
         self._trace = _tracing.mint("engine")
         self._cycle = 0
         self._sync_end_ns = None
+        # device counters of the cycle's programs, read at its sync
+        self._counts = []
         self._tokens = jnp.full((S,), self.pad, jnp.int32)
         self._pos = jnp.zeros((S,), jnp.int32)
         self._active = jnp.zeros((S,), bool)
@@ -629,12 +671,13 @@ class ServingEngine:
             _obs.inc("pt_serving_spec_draft_chunks_total")
         elif self._paged:
             (self._tokens, self._pos, self._active,
-             self._remaining, self._pools, toks, valid) = \
+             self._remaining, self._pools, toks, valid, counts) = \
                 self._decode_jit(
                     self._pvals, self._tokens, self._pos,
                     self._active, self._remaining,
                     self._pools, jnp.asarray(self._kv.table))
             self._kv.set_pools(self._pools)
+            self._counts.append(counts)
         else:
             (self._tokens, self._pos, self._active,
              self._remaining, self._caches, toks, valid) = \
@@ -966,7 +1009,7 @@ class ServingEngine:
                                 jnp.asarray(self._kv.table))
                     else:
                         (t0, fin0, self._tokens, self._pos, self._active,
-                         self._remaining, self._pools) = \
+                         self._remaining, self._pools, counts) = \
                             self._prefill_jit[bucket](
                                 self._pvals, jnp.asarray(ids),
                                 jnp.asarray(k, jnp.int32),
@@ -976,6 +1019,7 @@ class ServingEngine:
                                 self._tokens, self._pos, self._active,
                                 self._remaining, self._pools,
                                 jnp.asarray(self._kv.table))
+                        self._counts.append(counts)
                 self._kv.set_pools(self._pools)
                 if k:
                     guardian.emit("serving_prefix_hit", req_id=req.req_id,
@@ -1055,16 +1099,33 @@ class ServingEngine:
         Returns (finished requests, end of the delivery)."""
         with _tracing.region(self._trace, cycle, "serving.sync",
                              parent=parent, start_ns=start_ns) as sy:
+            counts, self._counts = self._counts, []
             bundle = jax.device_get(
                 ([(t0, fin0) for _, _, t0, fin0 in pending],
-                 toks, valid, self._active))
+                 toks, valid, self._active, counts))
         with _tracing.region(self._trace, cycle, "serving.deliver",
                              parent=parent, start_ns=sy.end_ns) as dl:
-            finished = self._deliver(pending, bundle, parent)
+            self._book_device_counters(bundle[4])
+            finished = self._deliver(pending, bundle[:4], parent)
         # the host gap to the next chunk's dispatch counts only while
         # requests stay in flight: an idle engine's wait is no host work
         self._sync_end_ns = sy.end_ns if self.scheduler.active else None
         return finished, dl.end_ns
+
+    def _book_device_counters(self, counts):
+        """Fold what the cycle's programs counted on the device (host
+        values of the sync's one readback) into ``stats`` and the
+        ``pt_serving_<key>`` metrics of the same names."""
+        for part in counts:
+            for key, value in part["sum"].items():
+                with self._lock:
+                    self.stats[key] += int(value)
+                _obs.inc(_DEVICE_COUNTER_METRICS[key], int(value))
+            for key, value in part["max"].items():
+                with self._lock:
+                    most = self.stats[key] = max(self.stats[key],
+                                                 int(value))
+                _obs.set_gauge(_DEVICE_COUNTER_METRICS[key], most)
 
     def _deliver(self, pending, bundle, parent):
         """Everything after the readback, all on host values it brought:

@@ -14,4 +14,6 @@ from .llama import (LlamaConfig, LlamaModel, LlamaForCausalLM,  # noqa: F401
 from .gpt_moe import (GPTMoEConfig, GPTMoEModel,  # noqa: F401
                       GPTMoEForPretraining, GPTMoEPretrainingCriterion,
                       gpt_moe_tiny, gpt_moe_small)
+from .mla_moe import (MLAMoEConfig, MLAMoEModel,  # noqa: F401
+                      MLAMoEForCausalLM, mla_moe_tiny)
 from .generation import generate  # noqa: F401

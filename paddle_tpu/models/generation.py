@@ -11,6 +11,7 @@ finished rows emit ``pad_token_id`` (scan has no early exit — the
 standard masked-finish formulation).
 """
 import threading
+from typing import NamedTuple
 
 import numpy as np
 import jax
@@ -29,6 +30,31 @@ from ..framework.random import rng_scope
 for _qual in ("generate.run", "generate.beam_run", "generate.prefill",
               "build_apply.apply", "build_pick.pick"):
     register_jit_surface(__name__, _qual)
+
+
+class LatentCacheSpec(NamedTuple):
+    """A layer of ``kv_cache_spec()`` whose cache is ONE plane of
+    ``width`` values a token (latent attention: the normed latent and
+    the rotated key positions side by side) with no head axis and no V
+    plane.  A layer that keeps keys and values by head stays the plain
+    ``(num_kv_heads, head_dim)`` pair it always was."""
+    width: int
+
+
+def cache_kind(layer_spec):
+    """"latent" or "heads": the kind of one entry of ``kv_cache_spec()``."""
+    return "latent" if isinstance(layer_spec, LatentCacheSpec) else "heads"
+
+
+def require_head_caches(spec, what):
+    """Raise where ``what`` (a mode that preallocates dense
+    ``(B, MAX, nH, D)`` K/V buffers) meets a layer kind it cannot hold."""
+    kinds = sorted({cache_kind(s) for s in spec} - {"heads"})
+    if kinds:
+        raise ValueError(
+            f"{what} holds keys and values by head and cannot hold a "
+            f"{kinds[0]} cache layer: serve this model through "
+            "ServingEngine(kv_mode='paged')")
 
 
 class _GenCaches(dict):
@@ -92,8 +118,11 @@ def cast_weights(model, pvals, cache_dtype):
             and all(a is b for a, b in zip(cast[1], pvals))):
         return cast[2]
     originals = pvals
+    # a value already in ``cache_dtype`` is kept, never copied: a model
+    # built in the serving dtype holds ONE copy of its weights
     out = [v.astype(cache_dtype)
-           if jnp.issubdtype(v.dtype, jnp.floating) else v
+           if jnp.issubdtype(v.dtype, jnp.floating)
+           and v.dtype != cache_dtype else v
            for v in pvals]
     caches["cast"] = (str(cache_dtype), originals, out)
     return out
@@ -179,7 +208,13 @@ def build_apply(model, params):
     (uniform batch) or a per-row (B,) vector (the engine's per-slot
     offsets); ``attn_mask`` is an optional additive (B, MAX) key mask.
     Thread-safe across models sharing parameters (the fleet's replicas):
-    the swap-restore window is serialized by ``_APPLY_LOCK``."""
+    the swap-restore window is serialized by ``_APPLY_LOCK``.
+
+    A model whose class sets ``forward_takes_last`` takes ``last=`` (a
+    traced position): its cached forward then applies the output head to
+    that one position and returns ``(B, 1, V)`` logits, so a prefill
+    over a long bucket never forms the bucket's logits
+    (``apply.takes_last`` tells the caller)."""
     def _wrap(c):
         # dense (k, v) pair or a paged cache view (a NamedTuple whose
         # optional scale fields may be None) — wrap leaves, keep shape
@@ -194,7 +229,7 @@ def build_apply(model, params):
                              for x in c))
         return tuple(x._value for x in c)
 
-    def apply(pv, ids, caches, pos, attn_mask=None):
+    def apply(pv, ids, caches, pos, attn_mask=None, last=None):
         with _APPLY_LOCK:
             olds = [p._value for p in params]
             for p, v in zip(params, pv):
@@ -203,6 +238,8 @@ def build_apply(model, params):
                 kw = {}
                 if attn_mask is not None:
                     kw["attn_mask"] = Tensor(attn_mask)
+                if last is not None:
+                    kw["last"] = Tensor(last)
                 with _ag.suspend_tape(), rng_scope(jax.random.key(0)):
                     logits, new_caches = model(
                         Tensor(ids),
@@ -212,6 +249,7 @@ def build_apply(model, params):
             finally:
                 for p, v in zip(params, olds):
                     p._value = v
+    apply.takes_last = bool(getattr(model, "forward_takes_last", False))
     return apply
 
 
@@ -246,8 +284,12 @@ class GenerationMixin:
         return cfg
 
     def kv_cache_spec(self):
-        """Per-layer (num_kv_heads, head_dim) for generation's
-        preallocated cache buffers."""
+        """One description a layer of what its cache holds: a
+        ``(num_kv_heads, head_dim)`` pair where keys and values are kept
+        by head (generation's preallocated buffers, the engine's dense
+        rows and K/V page pools), a :class:`LatentCacheSpec` where the
+        layer keeps one latent plane (:func:`cache_kind` tells them
+        apart)."""
         c = self._gen_config()
         kv = getattr(c, "num_key_value_heads", 0) or c.num_attention_heads
         return [(kv, c.hidden_size // c.num_attention_heads)] * \
@@ -371,6 +413,7 @@ def generate(model, input_ids, max_new_tokens=32,
             f"prompt_len + max_new_tokens = {MAX} exceeds the model's "
             f"max_position_embeddings = {limit}")
     spec = model.kv_cache_spec()
+    require_head_caches(spec, "generate()")
     params = [p for _, p in model.named_parameters()]
     pvals = [p._value for p in params]
     # KV caches follow the model's dominant floating dtype unless

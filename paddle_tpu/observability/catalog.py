@@ -81,6 +81,29 @@ METRICS = {
     "pt_serving_chunks_total": {
         "type": _C, "labels": (),
         "help": "compiled decode-chunk dispatches"},
+    "pt_serving_moe_pairs_routed_total": {
+        "type": _C, "labels": (),
+        "help": "token-expert pairs the expert layers' routers chose "
+                "(real tokens x experts per token x expert layers), "
+                "summed on the device and read at the chunk's sync"},
+    "pt_serving_moe_pairs_here_total": {
+        "type": _C, "labels": (),
+        "help": "of those pairs, the ones routed to an expert this "
+                "chip holds (experts_held): the rows the grouped "
+                "matmul computed"},
+    "pt_serving_moe_experts_touched_total": {
+        "type": _C, "labels": (),
+        "help": "held experts with at least one row, summed over "
+                "decode steps and expert layers (the weights a "
+                "bandwidth-bound step had to read)"},
+    "pt_serving_moe_decode_layer_steps_total": {
+        "type": _C, "labels": (),
+        "help": "decode steps x expert layers counted (the "
+                "denominator of experts touched per step)"},
+    "pt_serving_moe_max_expert_rows": {
+        "type": _G, "labels": (),
+        "help": "most rows one held expert got in one layer of one "
+                "program (the skew a dropless layer absorbs)"},
     "pt_serving_prefills_total": {
         "type": _C, "labels": ("bucket",),
         "help": "compiled bucket prefill dispatches by bucket length"},

@@ -64,7 +64,14 @@ __all__ = ["mint", "span", "instant", "region", "finish", "spans", "reset",
 # ``jvp(<scope>)`` and backward ``transpose(jvp(<scope>))`` for free
 SCOPES = ("embed", "norm", "attention.qkv", "attention.core",
           "attention.out", "kv.gather", "kv.scatter", "mlp", "lm_head",
-          "xent", "sample", "amp_cast", "optimizer", "guard")
+          "xent", "sample", "amp_cast", "optimizer", "guard",
+          # latent attention (models/mla_moe.py): the latent projection,
+          # its norm, rotation and expansion; the absorbed form's two
+          # products with W_kvb
+          "attention.latent_kv", "attention.absorb",
+          # the dropless expert layer (incubate/.../moe/dropless.py)
+          "moe.router", "moe.dispatch", "moe.experts", "moe.shared",
+          "moe.combine")
 
 _SPANS = collections.deque(maxlen=65536)
 _LOCK = threading.Lock()

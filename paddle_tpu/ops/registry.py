@@ -117,6 +117,8 @@ def _ensure_defaults(kernel):
         from .pallas import fused_xent         # noqa: F401 (registers)
     elif kernel == "quant_matmul":
         from . import quant_dispatch           # noqa: F401 (registers)
+    elif kernel == "grouped_matmul":
+        from . import grouped_matmul           # noqa: F401 (registers)
 
 
 def interpret_enabled():

@@ -96,3 +96,30 @@ def test_the_kernels_ask_for_no_more_vmem():
     assert fa._params(128, 16).vmem_limit_bytes == 96 * mb
     assert fa._params(128, 128).vmem_limit_bytes is None
     assert fa._params(64, 64).vmem_limit_bytes is None
+
+
+@pytest.mark.parametrize("S", [1024, 2048, 4096])
+def test_latent_prefill_attention_compiles_for_v5e(one_chip, S):
+    """The expanded latent attention's call: 64 heads, q/k of 192 and v of
+    128 zero-padded to 256 (models/mla_moe.py), one prompt, no LSE."""
+    x = _sds(one_chip, (1, S, 64, 256))
+    with jax.default_matmul_precision(None):
+        fwd = jax.jit(lambda q, k, v: fa.flash_attention_fwd(
+            q, k, v, causal=True)).lower(x, x, x).compile()
+    assert "tpu_custom_call" in fwd.as_text()
+
+
+@pytest.mark.parametrize("rows,k,n", [
+    (64, 4096, 4096), (64, 2048, 4096),            # a decode step: 8 x 8 pairs
+    (32768, 4096, 4096), (32768, 2048, 4096),      # a 4,096 bucket, worst case
+    (4096, 4096, 4096)])                           # a 512 bucket
+def test_grouped_matmul_compiles_for_v5e(one_chip, rows, k, n):
+    """The expert layer's grouped matmul (megablox) at the widths of
+    sarvam-105b: 32 held experts, gate+up 4096 -> 4096, down 2048 -> 4096."""
+    from paddle_tpu.ops import grouped_matmul as gm
+    lhs, rhs = _sds(one_chip, (rows, k)), _sds(one_chip, (32, k, n))
+    sizes = _sds(one_chip, (32,), jnp.int32)
+    with jax.default_matmul_precision(None):
+        out = jax.jit(lambda a, b, s: gm._expert_grouped_matmul(
+            a, b, s, impl="megablox")).lower(lhs, rhs, sizes).compile()
+    assert "tpu_custom_call" in out.as_text()

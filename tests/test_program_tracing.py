@@ -106,7 +106,9 @@ class TestScopes:
         assert tracing.SCOPES == (
             "embed", "norm", "attention.qkv", "attention.core",
             "attention.out", "kv.gather", "kv.scatter", "mlp", "lm_head",
-            "xent", "sample", "amp_cast", "optimizer", "guard")
+            "xent", "sample", "amp_cast", "optimizer", "guard",
+            "attention.latent_kv", "attention.absorb", "moe.router",
+            "moe.dispatch", "moe.experts", "moe.shared", "moe.combine")
         with pytest.raises(ValueError):
             tracing.scope("attention")
 
