@@ -684,11 +684,53 @@ def kernel_varlen(ck):
     return out
 
 
+def kernel_paged_attention(ck):
+    """paged decode attention at the chat cell's shape (8 slots, 16 heads
+    of 128, pages of 16, 128 table entries) and LLaMA's grouped shape,
+    bf16 and fp32 pools: lengths 0 .. MAX in one batch over a permuted
+    table vs the gathered, masked fp32 attention."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.inference import kvcache as kvc
+    from paddle_tpu.nn.functional.attention import _xla_attention
+    from paddle_tpu.ops.pallas.paged_attention import paged_attention
+
+    out = {}
+    P, n_pages, pool, D = 16, 128, 1025, 128
+    lens = np.asarray([1, 15, 16, 17, 220, n_pages * P, 0, 700], np.int32)
+    for tag, nH, nKV, dtype in (("chat_cell", 16, 16, jnp.bfloat16),
+                                ("grouped", 32, 8, jnp.bfloat16),
+                                ("fp32", 16, 16, jnp.float32)):
+        rng = np.random.RandomState(len(tag))
+        q = _randn(rng, (8, nH, D), dtype)
+        kp, vp = (_randn(rng, (pool, P, nKV, D), dtype) for _ in range(2))
+        table = np.zeros((8, n_pages), np.int32)
+        free = list(rng.permutation(np.arange(1, pool)))
+        for b, n in enumerate(lens):
+            for j in range(-(-int(n) // P)):
+                table[b, j] = free.pop()
+        table = jnp.asarray(table)
+        got = paged_attention(q, kp, vp, table, jnp.asarray(lens))
+        k, v = kvc.gather_pages(_f32(kp), _f32(vp), table)
+        dead = jnp.arange(n_pages * P)[None, :] >= lens[:, None]
+        with jax.default_matmul_precision("highest"):
+            ref = _xla_attention(_f32(q)[:, None], k, v, mask=jnp.where(
+                dead, -1e30, 0.0)[:, None, None, :])[:, 0]
+        ref = jnp.where((lens > 0)[:, None, None], ref, 0.0)
+        err = rel_max(got, ref)
+        ck.check(f"paged_attention_{tag}", err <= FWD_TOL
+                 and bool(jnp.all(got[6] == 0)), f"relative-max {err:.2e}")
+        out[tag] = float(f"{err:.2e}")
+    return out
+
+
 KERNEL_CASES = (kernel_flash_gpt125m, kernel_flash_bert_mask,
                 kernel_flash_padded, kernel_flash_s4096,
                 kernel_flash_gpt1p3b, kernel_flash_two_pass,
                 kernel_fused_xent, kernel_int8_matmul, kernel_fused_adamw,
-                kernel_fused_norm, kernel_conv1x1, kernel_varlen)
+                kernel_fused_norm, kernel_conv1x1, kernel_varlen,
+                kernel_paged_attention)
 
 
 def phase_kernels(device, meter):

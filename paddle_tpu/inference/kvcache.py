@@ -19,15 +19,23 @@ formulation:
   pages 1..num_pages-1 with per-page refcounts; per-slot page tables map
   logical pages (position // page_size) to physical pages and travel to
   device as one small int32 array per dispatch.
-- **Paged gather/scatter inside the cached-attention path** — when
+- **Paged attention inside the cached-attention path** — when
   ``gpt._cached_attention`` receives a :class:`PagedCacheView` instead
-  of a dense ``(k_buf, v_buf)`` pair, it gathers the slot's pages into
-  the same ``(B, MAX, nH, D)`` working buffer the dense path uses, runs
-  the *identical* write/mask/attention math, and scatters the newly
-  written positions back to the pool.  Identical math over identical
-  values is what keeps paged greedy decode **bitwise-identical** to the
-  dense engine and to ``generate()`` (tests/test_kvcache.py asserts
-  the full chain).
+  of a dense ``(k_buf, v_buf)`` pair, attention over it is the kernel
+  registry's ``"paged_attention"`` in one of two forms.  The plain form
+  (``"xla"``) gathers the slot's pages into the same ``(B, MAX, nH, D)``
+  working buffer the dense path uses, runs the *identical*
+  write/mask/attention math, and scatters the newly written positions
+  back to the pool.  Identical math over identical values is what keeps
+  that form's greedy decode **bitwise-identical** to the dense engine
+  and to ``generate()`` (tests/test_kvcache.py asserts the full chain);
+  it is what runs off the chip and in every prefill.  A decode step on a
+  TPU over a full-precision pool takes the Pallas form instead
+  (``ops/pallas/paged_attention.py``): the step's row is scattered into
+  the pool, then the query attends over the slot's live pages in place
+  through the block table (:func:`live_lengths` says how many keys are
+  live), within the tolerance docs/kernels.md states and not bit for
+  bit.
 - **Prefix cache** — page-aligned prompt prefixes are keyed by CHAINED
   per-page digests (``digest_j = sha256(digest_{j-1} || page_j)``), so
   building every prefix key of an n-token prompt is one O(n) pass
@@ -303,6 +311,18 @@ def scatter_pages(kp, vp, k_new, v_new, table, pos):
     return kp, vp
 
 
+def live_lengths(table, pos, page_size):
+    """The live keys of each slot at a decode step that has just written
+    position ``pos``: ``pos + 1``, and 0 for a slot whose table row
+    starts on the trash page.  Page 0 is never allocated, so a first
+    entry of 0 is a slot with no page: an inactive slot of the decode
+    chunk, whose stale ``pos`` counts nothing that is there."""
+    B = table.shape[0]
+    p = jnp.broadcast_to(pos.astype(jnp.int32), (B,))
+    n = jnp.minimum(p + 1, table.shape[1] * page_size)
+    return jnp.where(table[:, 0] == 0, 0, n)
+
+
 def scatter_pages_q(kp, vp, ks, vs, k_new, v_new, table, pos):
     """int8 variant: quantize each token row and store value + scale."""
     S = k_new.shape[1]
@@ -402,9 +422,11 @@ def _build_paged_decode_chunk(apply, pick, chunk, eos, pad, quant):
     """Compiled paged decode over ``chunk`` tokens for all S slots: the
     dense engine's masked-finish scan body verbatim, except each step's
     KV travels through the page pool (gather -> identical attention ->
-    scatter).  Inactive slots have their page-table row redirected to
+    scatter, or on a TPU scatter -> attention over the live pages in
+    place).  Inactive slots have their page-table row redirected to
     the trash page so a freed-and-reassigned page can never be
-    corrupted by a stale slot's ride-along writes."""
+    corrupted by a stale slot's ride-along writes (and the paged kernel
+    reads nothing for them)."""
     def paged_decode_chunk(pv, tokens, pos, active, remaining, pools,
                            table):
         def body(carry, _):

@@ -152,15 +152,34 @@ def _cached_attention(out_proj, q, k, v, cache, pos, B, S, H,
     left-padded ragged prompts. Returns (out, (k_buf, v_buf)).
 
     ``cache`` may instead be an ``inference.kvcache.PagedCacheView``
-    (block-paged serving): the slot's pages are gathered into the same
-    (B, MAX, nH, D) working buffers, the write/mask/attention math below
-    runs unchanged (bitwise-identical to the dense path), and the newly
-    written positions scatter back to the page pool (quantizing in int8
-    mode).  Returns (out, updated view) in that case."""
+    (block-paged serving).  Attention over a paged cache is kernel
+    ``"paged_attention"`` of ``ops/registry.py``, in two forms:
+
+    - ``"xla"``, the plain form and the one every backend has: the
+      slot's pages are gathered into the same (B, MAX, nH, D) working
+      buffers, the write/mask/attention math below runs unchanged
+      (bitwise-identical to the dense path: that contract is this
+      form's), and the newly written positions scatter back to the page
+      pool (quantizing in int8 mode);
+    - ``"pallas"`` (:func:`_paged_decode_attention`), taken on a TPU for
+      a decode step (``S == 1``) over a full-precision pool with no
+      extra mask: the step's row is written into the pool first and the
+      query attends over the slot's live pages in place, through the
+      block table.  It agrees with the plain form within the tolerance
+      docs/kernels.md states, not bit for bit.
+
+    Every other paged call keeps the plain form and says why in
+    ``pt_kernel_fallbacks_total{kernel="paged_attention"}``.  Returns
+    (out, updated view) for a paged cache."""
     from ..tensor.manipulation import reshape
     paged = hasattr(cache, "_fields")
     if paged:
         from ..inference import kvcache as _kvc
+        from ..ops.pallas import paged_attention as _pa
+        kernel = _pa.select(tuple(q.shape), cache, attn_mask is not None)
+        if kernel.use:
+            return _paged_decode_attention(out_proj, q, k, v, cache, pos,
+                                           B, H, kernel.interpret)
         if cache.k_scales is None:
             k_buf, v_buf = call_op(_kvc.gather_pages, cache.k_pages,
                                    cache.v_pages, cache.table)
@@ -215,6 +234,31 @@ def _cached_attention(out_proj, q, k, v, cache, pos, B, S, H,
                                        k_scales=ks, v_scales=vs)
         return out, new_cache
     return out, (k_buf, v_buf)
+
+
+def _paged_decode_attention(out_proj, q, k, v, cache, pos, B, H, interpret):
+    """One new token a slot over a paged cache with nothing of width
+    ``MAX`` built: the step's k/v row goes into the pool, then the query
+    attends over ``pos + 1`` keys of the slot's pages where they lie
+    (``ops/pallas/paged_attention.py``).  An inactive slot's table row
+    points at the trash page; it attends over nothing and gets zeros,
+    which the engine discards."""
+    from ..inference import kvcache as _kvc
+    from ..ops.pallas import paged_attention as _pa
+    from ..tensor.manipulation import reshape
+    kp, vp = call_op(_kvc.scatter_pages, cache.k_pages, cache.v_pages,
+                     k, v, cache.table, pos)
+
+    def attend(q_, kp_, vp_, table, p):
+        lengths = _kvc.live_lengths(table, p, kp_.shape[1])
+        return _pa.paged_attention(q_[:, 0], kp_, vp_, table, lengths,
+                                   interpret=interpret)
+    with _scope("attention.core"):
+        out = reshape(call_op(attend, q, kp, vp, cache.table, pos),
+                      [B, 1, H])
+    with _scope("attention.out"):
+        out = out_proj(out)
+    return out, cache._replace(k_pages=kp, v_pages=vp)
 
 
 def _cached_block(ln1, attn, ln2, ffn, x, cache, pos, attn_mask=None):

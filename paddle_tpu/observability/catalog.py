@@ -308,14 +308,22 @@ METRICS = {
                 "impl=pallas_transposed beside pallas for a call the "
                 "flash kernels take only through a transposed "
                 "(B*H, S, D) copy (head size and per-shard head count "
-                "outside the lane-tile rule, docs/kernels.md)"},
+                "outside the lane-tile rule, docs/kernels.md); "
+                "kernel=paged_attention books the form that RUNS, "
+                "once a layer of a compiled program: impl=pallas is "
+                "the paged decode kernel engaged, impl=xla the gather "
+                "path"},
     "pt_kernel_fallbacks_total": {
         "type": _C, "labels": ("kernel", "reason"),
         "help": "calls the platform policy routed to a Pallas impl but "
                 "a kernel contract sent to the XLA path instead: "
                 "mask | scale | dropout | cross-seq | short-seq | "
                 "pad-noncausal | mask-large | unaligned-vocab | "
-                "incubate-shape | head-dim (varlen_attention) | "
+                "incubate-shape | head-dim (varlen_attention, "
+                "paged_attention) | multi-token | int8-kv | "
+                "partitioned | cache-dtype | kv-heads "
+                "(paged_attention: a paged call that is not a decode "
+                "step over a full-precision pool the kernel tiles) | "
                 "fp8-unavailable (no float8_e4m3fn in this jax build; "
                 "weights degraded to int8) | fp8-weight-only (fp8 "
                 "always streams through the XLA weight-only path — "

@@ -119,6 +119,8 @@ def _ensure_defaults(kernel):
         from . import quant_dispatch           # noqa: F401 (registers)
     elif kernel == "grouped_matmul":
         from . import grouped_matmul           # noqa: F401 (registers)
+    elif kernel == "paged_attention":
+        from .pallas import paged_attention    # noqa: F401 (registers)
 
 
 def interpret_enabled():

@@ -9,6 +9,7 @@ runs, so this says nothing about results or times.
 """
 import functools
 import os
+import re
 
 import pytest
 import jax
@@ -123,3 +124,30 @@ def test_grouped_matmul_compiles_for_v5e(one_chip, rows, k, n):
         out = jax.jit(lambda a, b, s: gm._expert_grouped_matmul(
             a, b, s, impl="megablox")).lower(lhs, rhs, sizes).compile()
     assert "tpu_custom_call" in out.as_text()
+
+
+@pytest.mark.parametrize("nH,nKV,pool,dtype", [
+    (16, 16, 1025, jnp.bfloat16),       # chat-gpt3-xl: 8 slots of 2,048 in pages of 16
+    (32, 8, 1025, jnp.bfloat16),        # llama, grouped: four query heads a kv head
+    (16, 16, 1025, jnp.float32),        # an engine left at float32
+], ids=["chat_cell", "llama_grouped", "float32"])
+def test_paged_attention_compiles_for_v5e(one_chip, nH, nKV, pool, dtype):
+    """The paged decode kernel at the chat cell's real shape (8 slots, 128
+    table entries, heads of 128, the pools whole in HBM) within the
+    default scoped VMEM: it asks for no limit of its own."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    sds = functools.partial(_sds, one_chip)
+    pages = sds((pool, 16, nKV, 128), dtype)
+    with jax.default_matmul_precision(None):
+        out = jax.jit(pa.paged_attention).lower(
+            sds((8, nH, 128), dtype), pages, pages,
+            sds((8, 128), jnp.int32), sds((8,), jnp.int32)).compile()
+    text = out.as_text()
+    assert "tpu_custom_call" in text
+    scoped = [int(n) for n in re.findall(
+        r'scoped_memory_configs":\[\{"memory_space":"1","offset":"0",'
+        r'"size":"(\d+)"', text)]
+    assert scoped and max(scoped) < 4 * 1024 * 1024   # 16 MB is the default
+    # the pools go to the kernel where they lie: no copy of a pool
+    assert f"[{pool},16,{nKV},128]" not in "".join(
+        l for l in text.splitlines() if " copy(" in l)

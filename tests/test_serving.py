@@ -304,3 +304,46 @@ class TestAttentionMask:
         ids = np.zeros((2, 6), np.int32)
         with pytest.raises(ValueError, match="attention_mask"):
             _gen(gpt, ids, 4, attention_mask=np.ones((2, 5), np.int32))
+
+
+class TestOverdueBurst:
+    """70 requests handed in at once to an 8-slot paged engine: what a
+    driver's loop does after a stall (PERF.md, PR 27: 66 requests came
+    due behind a 47 s trace write, and the record could not tell whether
+    the stall alone left them unanswered).  Under the gather path and
+    under the paged-attention kernel, interpreted."""
+
+    @pytest.mark.parametrize("impl", ["xla", "pallas"])
+    def test_seventy_at_once_all_finish(self, monkeypatch, impl):
+        from paddle_tpu.models import GPTConfig
+        from paddle_tpu.ops import registry as kreg
+        monkeypatch.setenv("PADDLE_TPU_KERNEL_INTERPRET", "1")
+        paddle.seed(0)
+        # heads of 128, the width the kernel tiles; 8 of them in float32
+        net = GPTForPretraining(GPTConfig(
+            vocab_size=256, hidden_size=1024, num_hidden_layers=1,
+            num_attention_heads=8, max_position_embeddings=64))
+        rng = np.random.RandomState(7)
+        prompts = [rng.randint(0, 256, (int(n),)).astype("int32")
+                   for n in rng.randint(3, 25, 70)]
+        budgets = [int(b) for b in rng.randint(2, 12, 70)]
+        reg = paddle.observability.get_registry()
+
+        def engaged():
+            m = reg.get("pt_kernel_selects_total")
+            return m.value(kernel="paged_attention", impl="pallas") \
+                if m else 0
+        before = engaged()
+        with kreg.force("paged_attention", impl):
+            eng = ServingEngine(net, num_slots=8, chunk=4,
+                                kv_mode="paged", page_size=8,
+                                prefill_buckets=(8, 16, 32))
+            reqs = [eng.submit(p, b) for p, b in zip(prompts, budgets)]
+            done = eng.run()
+        assert engaged() - before == (1 if impl == "pallas" else 0)
+        assert len(done) == 70
+        unanswered = [r.req_id for r in reqs if not r.tokens]
+        assert unanswered == []
+        assert [len(r.tokens) for r in reqs] == budgets
+        assert all(r.finish_reason == "budget" for r in reqs)
+        assert eng._kv.check()

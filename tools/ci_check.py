@@ -116,6 +116,7 @@ def run_kernels():
            "tests/test_pallas_fused.py", "tests/test_quant_matmul.py",
            "tests/test_varlen_attention.py",
            "tests/test_kernel_registry.py", "tests/test_quant_paths.py",
+           "tests/test_paged_attention.py",
            "-q", "--continue-on-collection-errors",
            "-p", "no:cacheprovider"]
     env = {**os.environ, "PADDLE_TPU_KERNEL_INTERPRET": "1"}
