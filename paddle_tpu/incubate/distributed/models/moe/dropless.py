@@ -9,7 +9,9 @@ today's sparse models:
   bias of auxiliary-loss-free balancing: it picks and never weighs); the
   weights are ``g_i = scaling * s_i / sum_{j chosen} s_j``.
 - **gated experts**: ``E(h) = W_down (silu(W_gate h) * W_up h)``; gate and
-  up are stored side by side as one ``(H, 2I)`` matrix an expert.
+  up are stored side by side as one ``(H, 2I)`` matrix an expert.  With
+  ``swiglu_limit`` L the gate is clamped to at most L and the up
+  projection to [-L, L] before they meet (routed and shared experts).
 - **a shared expert** every token passes through, counted once.
 - **dropless**: the token-expert pairs are sorted by expert and the held
   experts' stacked matrices are applied by ONE grouped matmul
@@ -56,11 +58,21 @@ def route(h, router_w, router_b, top_k, scaling):
     return picks.astype(jnp.int32), gates
 
 
-def swiglu(h, gate_up, down):
-    """``(silu(h W_gate) * h W_up) W_down`` with gate and up side by side."""
-    gu = jnp.dot(h, gate_up)
+def gated(gu, limit=None):
+    """``silu(gate) * up`` of gate and up side by side along the last
+    axis; with ``limit`` the gate clamped to at most it and up to
+    [-limit, limit]."""
     half = gu.shape[-1] // 2
-    return jnp.dot(jax.nn.silu(gu[..., :half]) * gu[..., half:], down)
+    gate, up = gu[..., :half], gu[..., half:]
+    if limit is not None:
+        gate = jnp.minimum(gate, limit)
+        up = jnp.clip(up, -limit, limit)
+    return jax.nn.silu(gate) * up
+
+
+def swiglu(h, gate_up, down, limit=None):
+    """``(silu(h W_gate) * h W_up) W_down`` with gate and up side by side."""
+    return jnp.dot(gated(jnp.dot(h, gate_up), limit), down)
 
 
 def _count(bag, here, local, real, top_k, held):
@@ -76,7 +88,7 @@ def _count(bag, here, local, real, top_k, held):
 
 
 def _forward(x, router_w, router_b, gate_up, down, shared_gate_up,
-             shared_down, *, top_k, scaling, first_held):
+             shared_down, *, top_k, scaling, first_held, limit=None):
     B, S, H = x.shape
     T, held = B * S, gate_up.shape[0]
     h = x.reshape(T, H)
@@ -99,11 +111,10 @@ def _forward(x, router_w, router_b, gate_up, down, shared_gate_up,
             _count(bag, here, local, real, top_k, held)
     with _scope("moe.experts"):
         gu = grouped_matmul(rows, gate_up, group_sizes)
-        half = gu.shape[-1] // 2
-        y = grouped_matmul(jax.nn.silu(gu[:, :half]) * gu[:, half:], down,
+        y = grouped_matmul(gated(gu, limit), down,
                            group_sizes)                   # zeros past the groups
     with _scope("moe.shared"):
-        shared = swiglu(h, shared_gate_up, shared_down)
+        shared = swiglu(h, shared_gate_up, shared_down, limit)
     with _scope("moe.combine"):
         back = jnp.zeros_like(order).at[order].set(
             jnp.arange(order.shape[0], dtype=order.dtype))
@@ -117,13 +128,14 @@ def _forward(x, router_w, router_b, gate_up, down, shared_gate_up,
 
 class DroplessMoELayer(nn.Layer):
     """See the module docstring.  ``experts_held`` is a ``range`` of expert
-    ids (default: all of them); ``dtype`` the parameters' dtype."""
+    ids (default: all of them); ``dtype`` the parameters' dtype;
+    ``swiglu_limit`` the clamp of the experts' gate and up (None: none)."""
 
     device_counters = COUNTERS
 
     def __init__(self, d_model, d_expert, num_experts, top_k,
                  experts_held=None, d_shared=None, scaling=1.0,
-                 dtype=None, init_std=0.02):
+                 dtype=None, init_std=0.02, swiglu_limit=None):
         super().__init__()
         held = range(num_experts) if experts_held is None else experts_held
         if held.step != 1 or held.start < 0 or held.stop > num_experts \
@@ -135,6 +147,8 @@ class DroplessMoELayer(nn.Layer):
             raise ValueError("top_k exceeds num_experts")
         self.num_experts, self.top_k = int(num_experts), int(top_k)
         self.experts_held, self.scaling = held, float(scaling)
+        self.swiglu_limit = None if swiglu_limit is None \
+            else float(swiglu_limit)
         d_shared = d_expert if d_shared is None else d_shared
         G, init = len(held), Normal(0.0, init_std)
 
@@ -153,4 +167,4 @@ class DroplessMoELayer(nn.Layer):
             _forward, x, self.router_weight, self.router_bias,
             self.experts_gate_up, self.experts_down, self.shared_gate_up,
             self.shared_down, top_k=self.top_k, scaling=self.scaling,
-            first_held=self.experts_held.start)
+            first_held=self.experts_held.start, limit=self.swiglu_limit)

@@ -61,7 +61,18 @@ formulation:
   the prefix cache, ``plan`` / ``bind`` / ``release`` and the handoff's
   export/import with its CRCs do not know the kind; the pools, the page
   bytes and the views follow it.  int8 KV is refused with a latent
-  layer (its scale group is one token's ``nH x D`` block).
+  layer (its scale group is one token's ``nH x D`` block).  A
+  ``generation.StateCacheSpec`` is a layer with a recurrent state a SLOT
+  and no cache a token (linear attention): its "pools" entry is a
+  :class:`SlotState` of ``(num_slots, ...)`` arrays (the float32 state
+  and the short convolutions' tails), travelling through the compiled
+  prefill and decode beside the page pools, as :class:`StateCacheView`.
+  It is not paged, holds no page bytes, cannot be shared by prefix (a
+  pool with a state layer registers and hits no prefix:
+  ``stats["prefix_state_bypassed"]`` counts the admissions that skipped
+  it) and cannot be exported; a slot's state starts from zero where its
+  admission's prefill starts at position 0, and a decode step leaves an
+  inactive slot's state as it is.
 - **int8 KV** (opt-in, the serving sibling of
   ``quantization.weight_only_quantize``) — pool pages store int8 with
   one fp32 absmax scale per token row (chunkwise absmax over that
@@ -101,7 +112,7 @@ from ..observability import devcounters as _devc
 from ..observability.tracing import scope as _scope
 
 __all__ = ["KVBundleError", "PagedCacheView", "LatentCacheView",
-           "PagedKVManager",
+           "SlotState", "StateCacheView", "PagedKVManager",
            "quantize_kv", "dequantize_kv",
            "chained_page_digests", "prefix_affinity_key"]
 
@@ -189,6 +200,29 @@ class LatentCacheView(NamedTuple):
     ``models/mla_moe.py``'s; the pool's bookkeeping is the manager's."""
     pages: Any
     table: Any
+
+
+class SlotState(NamedTuple):
+    """A state layer's entry of the engine's pools: every slot's
+    recurrent state ``(num_slots, heads, key_dim, value_dim)`` float32
+    and its short convolutions' last inputs ``(num_slots, tail,
+    channels)``."""
+    state: Any
+    conv: Any
+
+
+class StateCacheView(NamedTuple):
+    """A state layer's cache as it travels through the model.  In a
+    prefill ``slot`` is the slot the call writes (a traced scalar) and
+    ``length`` the call's real tokens (the rest is padding that must not
+    move the state); in a decode step both are None and the call's rows
+    are the slots.  ``live`` (rows,) marks the rows whose state may
+    change: a decode step's inactive slots keep theirs."""
+    state: Any
+    conv: Any
+    slot: Any
+    live: Any
+    length: Any
 
 
 # -- prefix keys (host-side, shared with the fleet router) -----------------
@@ -354,13 +388,18 @@ def scatter_latent(pages, new, table, pos):
         return pages.at[phys, off].set(new.astype(pages.dtype))
 
 
-def _layer_views(pools, table, quant):
+def _layer_views(pools, table, quant, slot=None, length=None):
     """One view a layer from the pools, by the layer's kind: a latent
     layer's pool is one plane, a K/V layer's two (four with int8
-    scales)."""
+    scales), a state layer's a :class:`SlotState` (``slot`` and
+    ``length``: a prefill's slot and real tokens; a row whose table
+    starts on the trash page is not live)."""
     views = []
     for planes in pools:
-        if len(planes) == 1:
+        if isinstance(planes, SlotState):
+            views.append(StateCacheView(planes.state, planes.conv, slot,
+                                        table[:, 0] != 0, length))
+        elif len(planes) == 1:
             views.append(LatentCacheView(planes[0], table))
         elif quant:
             views.append(PagedCacheView(*planes, table))
@@ -370,7 +409,8 @@ def _layer_views(pools, table, quant):
 
 
 def _layer_pools(views, quant):
-    return [(c.pages,) if isinstance(c, LatentCacheView)
+    return [SlotState(c.state, c.conv) if isinstance(c, StateCacheView)
+            else (c.pages,) if isinstance(c, LatentCacheView)
             else (c.k_pages, c.v_pages, c.k_scales, c.v_scales) if quant
             else (c.k_pages, c.v_pages) for c in views]
 
@@ -391,7 +431,7 @@ def _build_paged_prefill(apply, pick, eos, quant):
     def paged_prefill(pv, ids, start, length, slot, budget,
                       tokens, pos, active, remaining, pools, table):
         row = jax.lax.dynamic_slice_in_dim(table, slot, 1, axis=0)
-        caches = _layer_views(pools, row, quant)
+        caches = _layer_views(pools, row, quant, slot=slot, length=length)
         real = (jnp.arange(ids.shape[1]) < length)[None, :]
         with _devc.collect("prefill", rows=real) as bag:
             if apply.takes_last:
@@ -492,11 +532,16 @@ class PagedKVManager:
                              "(int8 or None)")
         self.spec = list(spec)
         self.kinds = [cache_kind(layer) for layer in self.spec]
-        if kv_dtype == "int8" and "latent" in self.kinds:
+        if kv_dtype == "int8" and set(self.kinds) - {"heads"}:
             raise ValueError(
-                "kv_dtype='int8' is not supported with a latent cache "
+                "kv_dtype='int8' is not supported with a "
+                f"{'/'.join(sorted(set(self.kinds) - {'heads'}))} cache "
                 "layer (the int8 scale group is one token's nH x D "
                 "block of a K/V layer)")
+        # a recurrent state holds the whole prefix in one matrix: no
+        # page-aligned prefix of it can be shared, so a pool with a state
+        # layer neither registers nor hits a prefix
+        self.stateful = "state" in self.kinds
         self.num_slots = int(num_slots)
         self.MAX = int(max_seq_len)
         self.page_size = int(page_size)
@@ -511,14 +556,22 @@ class PagedKVManager:
                              "reserved trash page)")
         self.cache_dtype = cache_dtype
         self.quant = kv_dtype == "int8"
-        self.prefix_enabled = bool(prefix_cache)
+        self.prefix_enabled = bool(prefix_cache) and not self.stateful
         self.max_prefix_entries = int(max_prefix_entries)
         # per-token bytes across all layers (K+V [+ scales], or the one
         # latent plane) for the resident-bytes gauge
         elt = jnp.dtype("int8" if self.quant else cache_dtype).itemsize
         per_tok = sum(layer.width * elt if kind == "latent"
+                      else 0 if kind == "state"
                       else 2 * layer[0] * layer[1] * elt
                       for layer, kind in zip(self.spec, self.kinds))
+        # the state layers' per-slot bytes (not paged)
+        self.state_bytes = self.num_slots * sum(
+            layer.heads * layer.key_dim * layer.value_dim * 4
+            + layer.conv_tail * layer.conv_channels
+            * jnp.dtype(cache_dtype).itemsize
+            for layer, kind in zip(self.spec, self.kinds)
+            if kind == "state")
         if self.quant:
             per_tok += 2 * 4 * len(self.spec)        # fp32 scale per row
         self.page_bytes = per_tok * self.page_size
@@ -545,7 +598,14 @@ class PagedKVManager:
                  jnp.zeros((N, P), jnp.float32))
                 for nh, d in self.spec]
         else:
+            S = self.num_slots
             self._pools = [
+                SlotState(
+                    jnp.zeros((S, layer.heads, layer.key_dim,
+                               layer.value_dim), jnp.float32),
+                    jnp.zeros((S, layer.conv_tail, layer.conv_channels),
+                              self.cache_dtype))
+                if kind == "state" else
                 (jnp.zeros((N, P, layer.width), self.cache_dtype),)
                 if kind == "latent" else
                 (jnp.zeros((N, P) + tuple(layer), self.cache_dtype),
@@ -573,7 +633,8 @@ class PagedKVManager:
         self.stats = {"prefix_hits": 0, "prefix_misses": 0,
                       "prefix_saved_tokens": 0, "pages_evicted": 0,
                       "resident_high_water_bytes": 0,
-                      "prefix_key_bytes_hashed": 0}
+                      "prefix_key_bytes_hashed": 0,
+                      "prefix_state_bypassed": 0}
         self._gauges()
         # HBM ledger: the live-buffer census joins this pool's own
         # bookkeeping (weakref — a dropped engine unregisters itself)
@@ -757,6 +818,9 @@ class PagedKVManager:
                 self._reclaim_one()
         self.stats["prefix_hits" if k else "prefix_misses"] += 1
         self.stats["prefix_saved_tokens"] += k
+        if self.stateful:
+            self.stats["prefix_state_bypassed"] += 1
+            _obs.inc("pt_kvcache_prefix_state_bypassed_total")
         if _obs.enabled():
             _obs.inc("pt_kvcache_prefix_hits_total" if k
                      else "pt_kvcache_prefix_misses_total")
@@ -836,6 +900,7 @@ class PagedKVManager:
         over every buffer (scales included in int8 mode) —
         :meth:`import_pages` refuses the payload whole on any mismatch.
         """
+        self._refuse_state("export")
         mapping = self._slot_pages[slot]
         order = sorted(mapping)
         phys = np.asarray([mapping[j] for j in order], np.int32)
@@ -852,6 +917,12 @@ class PagedKVManager:
         }
         return {"logical": order, "layers": layers, "quant": self.quant,
                 "manifest": manifest}
+
+    def _refuse_state(self, what):
+        if self.stateful:
+            raise ValueError(
+                f"cannot {what} KV pages of a pool with a state layer: "
+                "its recurrent state lives a slot, not in pages")
 
     def _verify_payload(self, payload):
         """Integrity gate for :meth:`import_pages`: every structural
@@ -914,6 +985,7 @@ class PagedKVManager:
         Returns the number of pages imported; raises when the pool
         cannot hold them (the decode replica's admission gate decides
         before calling)."""
+        self._refuse_state("import")
         if bool(payload["quant"]) != self.quant:
             raise KVBundleError("exporter/importer kv quant modes differ")
         self._verify_payload(payload)

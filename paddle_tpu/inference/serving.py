@@ -67,6 +67,9 @@ _DEVICE_COUNTER_METRICS = {
     "moe_experts_touched": "pt_serving_moe_experts_touched_total",
     "moe_decode_layer_steps": "pt_serving_moe_decode_layer_steps_total",
     "moe_max_expert_rows": "pt_serving_moe_max_expert_rows",
+    "dsa_keys_attended": "pt_serving_dsa_keys_attended_total",
+    "dsa_keys_live": "pt_serving_dsa_keys_live_total",
+    "prefill_pieces": "pt_serving_prefill_pieces_total",
 }
 
 
@@ -243,6 +246,15 @@ class ServingEngine:
                 "largest prefill bucket must leave room for at least one "
                 f"generated token (bucket {self.buckets[-1]} >= "
                 f"max_seq_len {self.MAX})")
+        # a model that names the longest prefill it runs
+        # (``prefill_piece``) has a longer prompt prefilled in pieces of
+        # that many tokens, each padded to it (one program), carrying its
+        # state and attending what the earlier pieces cached
+        self._piece = getattr(model, "prefill_piece", None)
+        if self._piece is not None and self._piece not in self.buckets:
+            raise ValueError(
+                f"the model's prefill piece {self._piece} is not one of "
+                f"the prefill buckets {self.buckets}")
         self._params = [p for _, p in model.named_parameters()]
         self._kvspec = model.kv_cache_spec()
         self._refuse_unserved_kinds(kv_mode, kv_dtype, quant_mode,
@@ -374,9 +386,9 @@ class ServingEngine:
     def _refuse_unserved_kinds(self, kv_mode, kv_dtype, quant_mode,
                                spec_decode):
         """A cache layer that is not keys and values by head (a latent
-        layer) is served by the paged engine in full precision only:
-        every other mode raises here, naming the mode and the kind — no
-        fallback that hides what ran."""
+        layer, a state layer) is served by the paged engine in full
+        precision only: every other mode raises here, naming the mode
+        and the kinds — no fallback that hides what ran."""
         kinds = sorted({cache_kind(s) for s in self._kvspec} - {"heads"})
         if not kinds:
             return
@@ -388,7 +400,8 @@ class ServingEngine:
             if on:
                 raise ValueError(
                     f"ServingEngine: {mode} is not supported with a "
-                    f"{kinds[0]} cache layer; this model is served with "
+                    f"{'/'.join(kinds)} cache layer; this model is "
+                    "served with "
                     "kv_mode='paged' and none of kv_dtype, quant_mode, "
                     "spec_decode")
 
@@ -508,10 +521,10 @@ class ServingEngine:
         in-flight request's streamed tokens."""
         if prompt_len == 0:
             raise ValueError("empty prompt")
-        if prompt_len > self.buckets[-1]:
+        if prompt_len > self._longest_prompt:
             raise ValueError(
-                f"prompt length {prompt_len} exceeds the largest "
-                f"prefill bucket {self.buckets[-1]}")
+                f"prompt length {prompt_len} exceeds the largest prompt "
+                f"a prefill takes ({self._longest_prompt})")
         if total_extent > self.MAX:
             raise ValueError(
                 f"prompt_len + max_new_tokens = {total_extent} exceeds "
@@ -756,9 +769,15 @@ class ServingEngine:
                                           self._spec_steps * (g + 1))
         return self._kv.coverage_page(pos, left, self.chunk)
 
+    @property
+    def _longest_prompt(self):
+        """The largest prefill bucket, or any prompt short of
+        ``max_seq_len`` where the model prefills in pieces."""
+        return self.buckets[-1] if self._piece is None else self.MAX - 1
+
     def _resume_fits(self, req):
         n = req.prompt.size + len(req.tokens)
-        return n <= self.buckets[-1]
+        return n <= self._longest_prompt
 
     def _pick_victim(self, keep):
         """Youngest-admitted active request whose resume prompt still
@@ -811,8 +830,8 @@ class ServingEngine:
                             f"{req.req_id} can neither grow nor be "
                             f"evicted (resume length "
                             f"{req.prompt.size + len(req.tokens)} "
-                            f"exceeds the largest prefill bucket "
-                            f"{self.buckets[-1]})")
+                            f"exceeds the largest prompt a prefill takes, "
+                            f"{self._longest_prompt})")
                     victim = slot
                 self._evict(victim)
                 if victim == slot:
@@ -977,10 +996,16 @@ class ServingEngine:
                 rp, k = bound.pop(req.req_id)
                 n, m = int(rp.size), int(rp.size) - k
                 budget = req.max_new_tokens - len(req.tokens)
-                bucket = self._bucket_for(m)
+                # a suffix longer than the model's prefill piece goes in
+                # pieces of it; any other in one program of its bucket
+                bucket = self._piece if self._piece is not None and \
+                    m > self._piece else self._bucket_for(m)
+
+                def padded(at, count):
+                    ids = np.full((1, bucket), self.pad, np.int32)
+                    ids[0, :count] = rp[at:at + count]
+                    return jnp.asarray(ids)
                 req.prefix_cached = k
-                ids = np.full((1, bucket), self.pad, np.int32)
-                ids[0, :m] = rp[k:]
                 req.resume_len = n
                 req.emitted_since_admit = 0
                 with _tracing.region(self._trace, cycle, "serving.prefill",
@@ -998,7 +1023,7 @@ class ServingEngine:
                          self._draft_caches, self._history) = \
                             self._prefill_jit(
                                 self._pvals, self._draft_pvals,
-                                jnp.asarray(ids_f), jnp.asarray(ids),
+                                jnp.asarray(ids_f), padded(k, m),
                                 jnp.asarray(k, jnp.int32),
                                 jnp.asarray(m, jnp.int32),
                                 jnp.asarray(slot, jnp.int32),
@@ -1008,18 +1033,22 @@ class ServingEngine:
                                 self._draft_caches, self._history,
                                 jnp.asarray(self._kv.table))
                     else:
-                        (t0, fin0, self._tokens, self._pos, self._active,
-                         self._remaining, self._pools, counts) = \
-                            self._prefill_jit[bucket](
-                                self._pvals, jnp.asarray(ids),
-                                jnp.asarray(k, jnp.int32),
-                                jnp.asarray(m, jnp.int32),
+                        table = jnp.asarray(self._kv.table)
+                        # the last piece's first token and state are what
+                        # the decode goes on from
+                        for at in range(k, n, bucket):
+                            count = min(bucket, n - at)
+                            (t0, fin0, self._tokens, self._pos,
+                             self._active, self._remaining, self._pools,
+                             counts) = self._prefill_jit[bucket](
+                                self._pvals, padded(at, count),
+                                jnp.asarray(at, jnp.int32),
+                                jnp.asarray(count, jnp.int32),
                                 jnp.asarray(slot, jnp.int32),
                                 jnp.asarray(int(budget), jnp.int32),
                                 self._tokens, self._pos, self._active,
-                                self._remaining, self._pools,
-                                jnp.asarray(self._kv.table))
-                        self._counts.append(counts)
+                                self._remaining, self._pools, table)
+                            self._counts.append(counts)
                 self._kv.set_pools(self._pools)
                 if k:
                     guardian.emit("serving_prefix_hit", req_id=req.req_id,

@@ -16,4 +16,6 @@ from .gpt_moe import (GPTMoEConfig, GPTMoEModel,  # noqa: F401
                       gpt_moe_tiny, gpt_moe_small)
 from .mla_moe import (MLAMoEConfig, MLAMoEModel,  # noqa: F401
                       MLAMoEForCausalLM, mla_moe_tiny)
+from .glm5_next import (GLM5NextConfig, GLM5NextModel,  # noqa: F401
+                        GLM5NextForCausalLM, glm5_next_tiny)
 from .generation import generate  # noqa: F401

@@ -41,9 +41,25 @@ class LatentCacheSpec(NamedTuple):
     width: int
 
 
+class StateCacheSpec(NamedTuple):
+    """A layer of ``kv_cache_spec()`` that keeps a recurrent state a slot
+    and no cache a token (a linear-attention layer): ``heads`` matrices of
+    ``key_dim x value_dim`` in float32, and the last ``conv_tail`` inputs
+    of its short convolutions, ``conv_channels`` wide.  The state is not
+    paged and cannot be shared by prefix."""
+    heads: int
+    key_dim: int
+    value_dim: int
+    conv_channels: int
+    conv_tail: int
+
+
 def cache_kind(layer_spec):
-    """"latent" or "heads": the kind of one entry of ``kv_cache_spec()``."""
-    return "latent" if isinstance(layer_spec, LatentCacheSpec) else "heads"
+    """"latent", "state" or "heads": the kind of one entry of
+    ``kv_cache_spec()``."""
+    if isinstance(layer_spec, LatentCacheSpec):
+        return "latent"
+    return "state" if isinstance(layer_spec, StateCacheSpec) else "heads"
 
 
 def require_head_caches(spec, what):
@@ -288,7 +304,8 @@ class GenerationMixin:
         ``(num_kv_heads, head_dim)`` pair where keys and values are kept
         by head (generation's preallocated buffers, the engine's dense
         rows and K/V page pools), a :class:`LatentCacheSpec` where the
-        layer keeps one latent plane (:func:`cache_kind` tells them
+        layer keeps one latent plane, a :class:`StateCacheSpec` where it
+        keeps a recurrent state a slot (:func:`cache_kind` tells them
         apart)."""
         c = self._gen_config()
         kv = getattr(c, "num_key_value_heads", 0) or c.num_attention_heads

@@ -104,6 +104,18 @@ METRICS = {
         "type": _G, "labels": (),
         "help": "most rows one held expert got in one layer of one "
                 "program (the skew a dropless layer absorbs)"},
+    "pt_serving_dsa_keys_attended_total": {
+        "type": _C, "labels": (),
+        "help": "keys the sparse attention layers' real queries "
+                "attended (at most the indexer's top-k each)"},
+    "pt_serving_dsa_keys_live_total": {
+        "type": _C, "labels": (),
+        "help": "keys the same queries could have attended (their "
+                "positions + 1): the denominator of the kept share"},
+    "pt_serving_prefill_pieces_total": {
+        "type": _C, "labels": (),
+        "help": "prefill programs run for a model that prefills a long "
+                "prompt in pieces carrying its recurrent state"},
     "pt_serving_prefills_total": {
         "type": _C, "labels": ("bucket",),
         "help": "compiled bucket prefill dispatches by bucket length"},
@@ -235,6 +247,11 @@ METRICS = {
     "pt_kvcache_prefix_misses_total": {
         "type": _C, "labels": (),
         "help": "admissions that prefilled their whole prompt cold"},
+    "pt_kvcache_prefix_state_bypassed_total": {
+        "type": _C, "labels": (),
+        "help": "admissions into a pool with a recurrent-state layer, "
+                "which shares no prefix (a state summarises the whole "
+                "prefix and is not paged)"},
     "pt_kvcache_prefix_saved_tokens_total": {
         "type": _C, "labels": (),
         "help": "prompt tokens NOT re-prefilled thanks to prefix-cache "

@@ -71,7 +71,12 @@ SCOPES = ("embed", "norm", "attention.qkv", "attention.core",
           "attention.latent_kv", "attention.absorb",
           # the dropless expert layer (incubate/.../moe/dropless.py)
           "moe.router", "moe.dispatch", "moe.experts", "moe.shared",
-          "moe.combine")
+          "moe.combine",
+          # models/glm5_next.py: the delta rule of a linear attention
+          # layer, the sparse layer's indexer and its attention over the
+          # picked keys, and the hyper-connections' maps, reads and writes
+          "attention.kda", "attention.indexer", "attention.sparse",
+          "residual.hc")
 
 _SPANS = collections.deque(maxlen=65536)
 _LOCK = threading.Lock()

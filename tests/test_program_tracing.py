@@ -108,7 +108,9 @@ class TestScopes:
             "attention.out", "kv.gather", "kv.scatter", "mlp", "lm_head",
             "xent", "sample", "amp_cast", "optimizer", "guard",
             "attention.latent_kv", "attention.absorb", "moe.router",
-            "moe.dispatch", "moe.experts", "moe.shared", "moe.combine")
+            "moe.dispatch", "moe.experts", "moe.shared", "moe.combine",
+            "attention.kda", "attention.indexer", "attention.sparse",
+            "residual.hc")
         with pytest.raises(ValueError):
             tracing.scope("attention")
 
